@@ -327,6 +327,19 @@ def launch_command(args: argparse.Namespace) -> int:
         }
         return subprocess.call(cmd, env=env)
 
+    if base_env.get("JAX_PLATFORMS", "").strip().lower() != "cpu":
+        # A chip belongs to one process: N local ranks on an accelerator
+        # backend would each claim every chip and hang in start-up.
+        print(
+            f"[accelerate-tpu] refusing --num_processes {cfg.num_processes} "
+            "on one host without --cpu: every rank would claim all local "
+            "chips. One process drives all local chips — use "
+            "--num_processes 1 (or --cpu / --virtual_devices N for a CPU "
+            "gang).",
+            file=sys.stderr,
+        )
+        return 2
+
     # Local fan-out: all processes on this machine. The gang restarts
     # together under the failure-classifying supervisor (the reference
     # delegates this to torch elastic's max_restarts,

@@ -27,8 +27,8 @@ compile boundary a managed artifact, three ways:
      ``lower().compile()`` leaves ``_cache_size()`` at 0, so an AOT-only
      warmup still pays trace+dispatch insertion (and the recompile-watchdog
      count) on the first real batch. Each signature is executed
-     ``warmup_calls`` times (default 2) to also absorb the donated-buffer
-     layout specialization TPU backends do on the second call.
+     ``warmup_calls`` times (default 2) to also absorb the second-call
+     recompile on a mesh (the state returns in GSPMD's shardings once).
    - ``"aot"``: classic ``jit(...).lower(abstract).compile()``. Cheaper (no
      state copy, no step executed) and it primes the *persistent* cache, but
      the first real call per shape still re-traces.
@@ -253,14 +253,38 @@ def record_watchdog_signature(accelerator, batch, digest: str) -> None:
 # ---------------------------------------------------------------------------
 
 
+def place_compile_cache() -> str:
+    """Place JAX's persistent compilation cache for an entry script
+    (``chip_smoke.py``, ``bench.py``, ``benchmarks/``): call it first thing.
+
+    ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself and nothing here
+    sets another directory. Unset: ``<checkout>/.jax_cache`` — a fixed path,
+    never a temp dir, because a cache that moves between runs never hits.
+    Returns the directory in effect."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    path = os.path.join(checkout, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def configure_persistent_cache(jit_config) -> Optional[str]:
     """Validate ``JitConfig.persistent_cache_dir`` at Accelerator init:
     create it, check writability (``warning_once`` instead of silently
     handing a bad path to ``jax.config``), and wire the min-compile-time
-    knob. Returns the validated path, or ``None`` when unusable."""
+    knob. Returns the directory in effect, or ``None`` when unusable.
+
+    ``JAX_COMPILATION_CACHE_DIR`` outranks the config: whoever runs the
+    process places the cache, so with it set this returns that directory
+    and sets nothing."""
     path = jit_config.persistent_cache_dir
     if not path:
         return None
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
     path = os.path.abspath(os.path.expanduser(path))
     try:
         os.makedirs(path, exist_ok=True)
@@ -277,13 +301,10 @@ def configure_persistent_cache(jit_config) -> Optional[str]:
         )
         return None
     jax.config.update("jax_compilation_cache_dir", path)
-    try:
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs",
-            float(jit_config.persistent_cache_min_compile_time_secs),
-        )
-    except (AttributeError, ValueError):  # older jax without the knob
-        pass
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs",
+        float(jit_config.persistent_cache_min_compile_time_secs),
+    )
     return path
 
 

@@ -68,10 +68,8 @@ def _manual_cp(mesh) -> bool:
 def _embed_sharded(cfg, embed, input_ids, mesh, batch_axes):
     """Embedding lookup with ids sequence-sharded over ``cp``: the table is
     replicated, each chip gathers its own id chunk locally."""
-    from .utils.environment import shard_map_compat
-
     b_ax = batch_axes if batch_axes else None
-    return shard_map_compat(
+    return jax.shard_map(
         lambda tbl, idc: _embed_tokens(cfg, tbl, idc),
         mesh=mesh,
         in_specs=(P(None, None), P(b_ax, "cp")),
@@ -83,14 +81,12 @@ def _embed_sharded(cfg, embed, input_ids, mesh, batch_axes):
 def _gather_seq(ids, mesh, batch_axes):
     """(B, S) cp-sharded -> replicated, via a manual tiled all_gather (the
     output concat would otherwise auto-reshard over cp)."""
-    from .utils.environment import shard_map_compat
-
     b_ax = batch_axes if batch_axes else None
 
     def body(i_c):
         return jax.lax.all_gather(i_c, "cp", axis=1, tiled=True)
 
-    return shard_map_compat(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(P(b_ax, "cp"),),
         out_specs=P(b_ax, None), check_vma=False,
     )(ids)
@@ -100,14 +96,12 @@ def _last_position(x, mesh, batch_axes):
     """(B, S, E) with S cp-sharded -> (B, E) at the last global position,
     replicated. The final chunk lives on the last cp shard; a tiny all_gather
     of each shard's local last row keeps the extraction manual."""
-    from .utils.environment import shard_map_compat
-
     b_ax = batch_axes if batch_axes else None
 
     def body(x_c):
         return jax.lax.all_gather(x_c[:, -1], "cp")[-1]
 
-    return shard_map_compat(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(P(b_ax, "cp", None),),
         out_specs=P(b_ax, None), check_vma=False,
     )(x)
@@ -117,8 +111,6 @@ def _prefix_stats_sharded(q, pk, pv, mesh, batch_axes):
     """Flash-decoding partials against the cp-sharded prefix: local stats per
     shard, then the exact online-softmax merge over cp as manual pmax/psum
     (disjoint keysets, same combination as :func:`_merge_stats`)."""
-    from .utils.environment import shard_map_compat
-
     b_ax = batch_axes if batch_axes else None
 
     def body(q_c, k_c, v_c):
@@ -129,7 +121,7 @@ def _prefix_stats_sharded(q, pk, pv, mesh, batch_axes):
         acc_g = jax.lax.psum(acc * w[..., None], "cp")
         return acc_g, m_g, l_g
 
-    return shard_map_compat(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

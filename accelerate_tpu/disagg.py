@@ -214,8 +214,9 @@ class DisaggServingEngine(ServingEngine):
         # reads exactly 1). Opt-in (shard_decode_slots): slots sharded over
         # the decode slice — same single compiled program, but typed
         # PRNG-key arrays under a multi-device NamedSharding occupy two
-        # dispatch-cache entries per program in jax 0.4.37, so init
-        # pre-warms both and the census reads a flat 2.
+        # dispatch-cache entries per program (one backend compile, two
+        # entries: checked on jax 0.9.0), so init pre-warms both and the
+        # census reads a flat 2.
         (self._decode_mesh, cache_s, vec_s,
          self._decode_sharding) = self._decode_placement(self.decode_devices)
         self._cache = jax.device_put(

@@ -28,6 +28,11 @@ def _worker(fn, args, index: int, num_processes: int, port: int, use_cpu: bool,
         os.environ["FORK_LAUNCHED"] = "1"
         if use_cpu:
             os.environ["JAX_PLATFORMS"] = "cpu"
+            import jax
+
+            # A forked worker inherits the parent's imported jax, which read
+            # the environment before this line: ask through its config too.
+            jax.config.update("jax_platforms", "cpu")
         if virtual_devices:
             flags = os.environ.get("XLA_FLAGS", "")
             os.environ["XLA_FLAGS"] = (
@@ -108,12 +113,10 @@ def _jax_backend_initialized() -> bool:
 
     if "jax" not in sys.modules:
         return False
-    try:
-        from jax._src import xla_bridge
+    # No public jax call answers this without initialising a backend itself.
+    from jax._src import xla_bridge
 
-        return xla_bridge.backends_are_initialized()
-    except Exception:
-        return False
+    return xla_bridge.backends_are_initialized()
 
 
 def debug_launcher(function: Callable, args: tuple = (), num_processes: int = 2):

@@ -32,6 +32,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from ..utils.environment import inside_shard_map
+
 
 @dataclasses.dataclass(unsafe_hash=True)
 class LlamaConfig:
@@ -534,20 +536,7 @@ def _pin_last_dim_replicated(x):
     mesh = AcceleratorState._shared_state.get("_mesh")
     if mesh is None or getattr(x, "ndim", 0) < 2:
         return x
-    try:
-        from jax.sharding import AxisType, get_abstract_mesh
-
-        ambient = get_abstract_mesh()
-        manual = ambient is not None and any(
-            t == AxisType.Manual for t in ambient.axis_types
-        )
-    except ImportError:
-        # jax < 0.5 has no AxisType/get_abstract_mesh; inside shard_map the
-        # mesh axes are bound in the named-axis env instead.
-        from jax._src import core as _core
-
-        manual = bool(_core.nonempty_axis_env())
-    if manual:
+    if inside_shard_map():
         # Inside shard_map (manual axes) — e.g. a comm-hook step or the
         # GPipe stage body — sharding constraints don't apply (and raise);
         # the caller already controls the layout by hand.

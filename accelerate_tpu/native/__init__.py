@@ -1,19 +1,24 @@
 """ctypes bindings for the native host-runtime kernels (host_runtime.cpp).
 
-The shared library is built lazily with the system g++ on first use and
-cached next to the source; everything degrades to numpy when a compiler is
-unavailable or ``ACCELERATE_DISABLE_NATIVE=1`` is set, so the package never
-hard-requires a toolchain.
+The shared library is never committed: it is built from ``host_runtime.cpp``
+with the system g++ on first use and cached next to the source, so a fresh
+checkout and a long-lived working tree behave the same. Everything runs on
+numpy when the build fails (logged once, with the compiler's message) or
+``ACCELERATE_DISABLE_NATIVE=1`` is set, so the package never hard-requires a
+toolchain. ``get_lib() is not None`` says which of the two is in use.
 """
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
 
 import numpy as np
+
+logger = logging.getLogger(__name__)
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "host_runtime.cpp")
@@ -47,12 +52,13 @@ def _build() -> bool:
     try:
         result = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
         if result.returncode != 0:
+            logger.warning("native host runtime: g++ failed, using numpy: %s",
+                           result.stderr[-500:])
             return False
         os.replace(tmp, _LIB_PATH)
         return True
-    except OSError:
-        return False
-    except subprocess.TimeoutExpired:
+    except (OSError, subprocess.TimeoutExpired) as e:
+        logger.warning("native host runtime: build did not run, using numpy: %s", e)
         return False
     finally:
         if os.path.exists(tmp):
@@ -106,7 +112,8 @@ def get_lib():
             lib.at_version.restype = ctypes.c_int
             assert lib.at_version() == 3
             _lib = lib
-        except Exception:
+        except Exception as e:  # host-side optimisation only: numpy takes over
+            logger.warning("native host runtime: load failed, using numpy: %s", e)
             _lib_failed = True
     return _lib
 

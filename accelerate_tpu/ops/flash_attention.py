@@ -7,8 +7,8 @@ The reference delegates fused attention to SDPA/FlashAttention-2/3 via torch
   over KV blocks. Pure jnp, runs on every backend, O(S·B_k) memory instead of
   O(S²); this is what lets seq-2048×16-layer training fit a 16GB v5e chip
   without remat.
-- :func:`flash_attention` — dispatcher: the Pallas TPU kernel when available
-  (ops/pallas_flash.py), else the blockwise fallback.
+- :func:`flash_attention` — dispatcher: the Pallas TPU kernel on a TPU
+  backend (ops/pallas_flash.py), the blockwise path on CPU.
 
 Both support GQA (Hq a multiple of Hkv) and causal masking with query/key
 position offsets (needed by ring attention's rotated chunks).
@@ -17,11 +17,16 @@ position offsets (needed by ring attention's rotated chunks).
 from __future__ import annotations
 
 import functools
-from typing import Optional
+import logging
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ..utils.environment import inside_shard_map
+from ..utils.imports import is_tpu_available
+
+logger = logging.getLogger(__name__)
 
 NEG_INF = -1e30
 
@@ -127,34 +132,25 @@ def attention_stats(q, k, v, *, causal=True, q_offset=0, k_offset=0,
 def flash_attention(q, k, v, *, causal: bool = True, q_offset=0, k_offset=0,
                     block_q: int = 512, block_k: int = 512, interpret=None):
     """Fused attention kernel dispatcher. Uses the Pallas TPU kernel
-    (ops/pallas_flash.py) on real TPU backends, the blockwise jnp path
-    elsewhere (CPU CI); logs once on fallback — never silently.
+    (ops/pallas_flash.py) on a TPU backend, the blockwise jnp path elsewhere
+    (CPU CI; logged once). The choice is by platform only: a kernel that
+    fails to compile on the chip raises, it never gives way to blockwise.
 
     The Pallas call is a Mosaic custom call with no GSPMD partitioning rule:
     call this either on a single device, or from inside a ``shard_map``
     (parallel/cp.py, parallel/sp.py). Model code in the *global* SPMD program
     should use :func:`auto_flash_attention`, which adds the shard_map."""
-    from .pallas_flash import default_interpret, pallas_flash_attention
+    from .pallas_flash import pallas_flash_attention
 
-    if not default_interpret():
+    if is_tpu_available():
         return pallas_flash_attention(
             q, k, v, causal=causal, q_offset=q_offset, k_offset=k_offset,
             block_q=block_q, block_k=block_k, interpret=interpret,
         )
-    _warn_fallback_once()
+    _log_cpu_path_once()
     return blockwise_attention(
         q, k, v, causal=causal, q_offset=q_offset, k_offset=k_offset, block_k=block_k
     )
-
-
-def _inside_manual_context() -> bool:
-    """True inside shard_map (mesh axes bound manually)."""
-    try:
-        from jax._src import core as _core
-
-        return bool(_core.get_axis_env().axis_sizes)
-    except Exception:
-        return False
 
 
 def auto_flash_attention(q, k, v, *, causal: bool = True, mesh=None):
@@ -162,10 +158,14 @@ def auto_flash_attention(q, k, v, *, causal: bool = True, mesh=None):
     ``shard_map`` over the (dp × tp) mesh axes when a multi-device mesh is
     active, because GSPMD cannot partition a Mosaic custom call. Degenerates
     to the plain dispatcher on one device, on CPU (blockwise partitions fine
-    under GSPMD), or when already inside a manual context (pp/cp/sp)."""
-    from .pallas_flash import default_interpret
+    under GSPMD), or when already inside a manual context (pp/cp/sp).
 
-    if default_interpret() or _inside_manual_context():
+    A batch that does not divide the dp axes cannot be split evenly by
+    shard_map (e.g. a bs-1 eval forward on a four-chip host), so it runs
+    ``blockwise_attention`` under GSPMD instead — with a WARNING on every
+    trace, never quietly: on a training step that warning means the kernel
+    is not in the program."""
+    if not is_tpu_available() or inside_shard_map():
         return flash_attention(q, k, v, causal=causal)
     if mesh is None:
         from ..state import AcceleratorState
@@ -179,32 +179,28 @@ def auto_flash_attention(q, k, v, *, causal: bool = True, mesh=None):
 
     dp_cap = mesh.shape.get("dp_replicate", 1) * mesh.shape.get("dp_shard", 1)
     if q.shape[0] % dp_cap != 0:
-        # shard_map needs even splits; GSPMD handles ragged batches for the
-        # blockwise path, so small/uneven batches (e.g. bs-2 eval on a pod)
-        # take that route instead of crashing.
-        _warn_fallback_once()
+        logger.warning(
+            "auto_flash_attention: batch %d does not divide the dp axes (%d) — "
+            "this trace uses blockwise_attention, NOT the Pallas kernel. Pad "
+            "the batch to a multiple of %d to get the kernel.",
+            q.shape[0], dp_cap, dp_cap,
+        )
         return blockwise_attention(q, k, v, causal=causal)
 
     tp = mesh.shape.get("tp", 1)
     # Heads shard over tp only when BOTH q and kv head counts divide: the
     # kernel's GQA group mapping assumes q and kv heads are split together.
     heads = "tp" if tp > 1 and q.shape[2] % tp == 0 and k.shape[2] % tp == 0 else None
-    q_spec = P(("dp_replicate", "dp_shard"), None, heads, None)
-    kv_spec = P(("dp_replicate", "dp_shard"), None, heads, None)
-    fn = functools.partial(flash_attention, causal=causal)
-    from ..utils.environment import shard_map_compat
-
-    return shard_map_compat(
-        fn, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec), out_specs=q_spec,
-        check_vma=False,
+    spec = P(("dp_replicate", "dp_shard"), None, heads, None)
+    return jax.shard_map(
+        functools.partial(flash_attention, causal=causal),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False,
     )(q, k, v)
 
 
 @functools.lru_cache(maxsize=1)
-def _warn_fallback_once():
-    import logging
-
-    logging.getLogger(__name__).info(
-        "flash_attention: no TPU backend attached — using the blockwise jnp "
-        "fallback (memory-efficient but unfused)."
+def _log_cpu_path_once():
+    logger.info(
+        "flash_attention: no TPU backend — using the blockwise jnp path "
+        "(memory-efficient but unfused)."
     )

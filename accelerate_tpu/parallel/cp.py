@@ -23,11 +23,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops.flash_attention import attention_stats, auto_flash_attention
-from ..ops.pallas_flash import (
-    default_interpret,
-    merge_flash_chunks,
-    pallas_flash_attention_with_lse,
-)
+from ..ops.pallas_flash import merge_flash_chunks, pallas_flash_attention_with_lse
+from ..utils.imports import is_tpu_available
 
 
 def _chunk_attention_with_lse(q_c, k_c, v_c, *, causal, q_offset, k_offset):
@@ -37,7 +34,7 @@ def _chunk_attention_with_lse(q_c, k_c, v_c, *, causal, q_offset, k_offset):
     attention_stats jnp path elsewhere. Both are exact online-softmax partials
     that :func:`merge_flash_chunks` combines across ring rotations.
     """
-    if not default_interpret():
+    if is_tpu_available():
         return pallas_flash_attention_with_lse(
             q_c, k_c, v_c, causal=causal, q_offset=q_offset, k_offset=k_offset
         )
@@ -128,9 +125,7 @@ def ring_attention(
             carry = one_step(step, carry)
         return carry[0].astype(q_c.dtype)
 
-    from ..utils.environment import shard_map_compat
-
-    shard = shard_map_compat(
+    shard = jax.shard_map(
         _local,
         mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec),

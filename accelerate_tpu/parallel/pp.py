@@ -168,9 +168,7 @@ def pipeline_apply(
     # ``pp`` out-spec keeps the real outputs resident on the last stage with
     # NO collective at pipe exit — the slice below just addresses that block
     # and GSPMD moves it lazily wherever the consumer needs it.
-    from ..utils.environment import shard_map_compat
-
-    pipelined = shard_map_compat(
+    pipelined = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis_name), stage_params), P()),
@@ -315,9 +313,7 @@ def _pipeline_apply_interleaved(
         (_, out_buf), _ = jax.lax.scan(loop, init, jnp.arange(ticks))
         return out_buf
 
-    from ..utils.environment import shard_map_compat
-
-    pipelined = shard_map_compat(
+    pipelined = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis_name), stage_params), P()),

@@ -62,9 +62,7 @@ def ulysses_attention(
         out = flash_attention(qh, kh, vh, causal=causal)
         return heads_to_seq(out)
 
-    from ..utils.environment import shard_map_compat
-
-    shard = shard_map_compat(
+    shard = jax.shard_map(
         _local, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False
     )
     return shard(q, k, v)

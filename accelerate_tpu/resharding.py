@@ -759,11 +759,8 @@ class ReshardExecutor:
                     staged.append((t.index, src_arr, sharding))
             if staged:
                 positions, arrays, dsts = zip(*staged)
-                try:
-                    moved = jax.device_put(list(arrays), list(dsts),
-                                           donate=bool(donate))
-                except TypeError:  # older jax without donate kwarg
-                    moved = jax.device_put(list(arrays), list(dsts))
+                moved = jax.device_put(list(arrays), list(dsts),
+                                       donate=bool(donate))
                 for pos, arr in zip(positions, moved):
                     out[pos] = arr
                 batch_outs.extend(moved)

@@ -562,8 +562,7 @@ class SDCSentinel:
     # -- reporting ---------------------------------------------------------
 
     def summary(self) -> dict:
-        """The ``sdc`` telemetry block (pinned in tests/test_schemas.py;
-        bench.py embeds it next to ``faults`` in training rows)."""
+        """The ``sdc`` telemetry block (pinned in tests/test_schemas.py)."""
         return {
             "vote_every": self.config.vote_every,
             "repair": self.config.repair,

@@ -220,6 +220,20 @@ def _commit_params(params):
     return jax.tree.map(commit, params)
 
 
+def _single_device_sharding_of(params):
+    """The replicated sharding on the params' device, in the params' own
+    form (NamedSharding over a one-device mesh stays a NamedSharding), or
+    ``None`` (default placement) when they span several devices — the
+    colocated engine is single-device and jit then refuses the mix loudly."""
+    leaf = next((x for x in jax.tree.leaves(params) if isinstance(x, jax.Array)), None)
+    if leaf is None or len(leaf.sharding.device_set) != 1:
+        return None
+    sharding = leaf.sharding
+    if isinstance(sharding, jax.sharding.NamedSharding):
+        return jax.sharding.NamedSharding(sharding.mesh, jax.sharding.PartitionSpec())
+    return sharding
+
+
 def _select_keys(mask, a, b):
     """Per-row key select over typed PRNG key arrays: ``a`` where ``mask``,
     else ``b``. Goes through key_data because jnp.where on extended dtypes is
@@ -727,16 +741,23 @@ class ServingEngine:
         # Published versions (device_put through the reshard executor) also
         # always arrive committed — an uncommitted initial tree would cost
         # one spurious decode recompile at the first hot swap.
-        self._cache = _commit_params(init_slot_cache(
-            self.cfg, self.n_slots, self.t_max, dtype=c.cache_dtype
-        ))
-        self._state = _commit_params(init_slot_state(
-            self.n_slots, seed=c.seed,
-            history=self._spec_ngram))
         # The param tree the dispatch hooks feed the jitted programs. The
         # disaggregated router (disagg.py) repoints this at the decode-mesh
         # copy; the colocated engine uses the model's own placement.
         self._params = _commit_params(model.params)
+        # Cache and slot state are built in the params' own sharding form.
+        # Params prepared by an Accelerator carry a NamedSharding over its
+        # mesh even on one chip; next to them a default-placed cache comes
+        # back from its first prefill as a NamedSharding too, and the rung
+        # that ran first compiles a second time in steady state (seen on the
+        # v5e: 6 prefill executables for a 5-rung ladder).
+        place = _single_device_sharding_of(self._params)
+        self._cache = _commit_params(jax.device_put(init_slot_cache(
+            self.cfg, self.n_slots, self.t_max, dtype=c.cache_dtype
+        ), place))
+        self._state = _commit_params(jax.device_put(init_slot_state(
+            self.n_slots, seed=c.seed,
+            history=self._spec_ngram), place))
         # Weight publication (publish.py): params are double-buffered by
         # monotonic version. ``_params`` always aliases the PRIMARY version;
         # in-flight requests keep decoding whatever version they bound at

@@ -84,7 +84,7 @@ def _maybe_init_jax_distributed():
     # Idempotent across PartialState._reset_state(): the coordinator client
     # outlives the borg dicts, and re-initializing after the backend is live
     # is an error.
-    if getattr(jax._src.distributed.global_state, "client", None) is not None:
+    if jax.distributed.is_initialized():
         return
     num = int(os.environ.get("ACCELERATE_NUM_PROCESSES", "1"))
     idx = int(os.environ.get("ACCELERATE_PROCESS_INDEX", "0"))
@@ -99,18 +99,9 @@ def _maybe_init_jax_distributed():
         return
     if num <= 1:
         return
-    # Multi-process CPU gangs (--cpu / --virtual_devices) need an explicit
-    # cross-process collectives implementation: jax 0.4.37 defaults to
-    # "none", and the first device_put/jit that touches a sharding spanning
-    # the gang dies with "Multiprocess computations aren't implemented on
-    # the CPU backend". Gloo ships in jaxlib; opt in before any backend
-    # client exists. (JAX_CPU_COLLECTIVES_IMPLEMENTATION is not read from
-    # the environment in this jax version — it must go through jax.config.)
-    if "cpu" in (os.environ.get("JAX_PLATFORMS") or ""):
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:
-            pass  # jaxlib without gloo bindings: keep the default
+    # Multi-process CPU gangs (--cpu / --virtual_devices) get their
+    # cross-process collectives from gloo, jax's default
+    # ``jax_cpu_collectives_implementation``; nothing to opt into here.
     try:
         jax.distributed.initialize(
             coordinator_address=coord, num_processes=num, process_id=idx
@@ -150,22 +141,9 @@ class PartialState:
         self.debug = parse_flag_from_env("ACCELERATE_DEBUG_MODE")
         self.fork_launched = parse_flag_from_env("FORK_LAUNCHED", False)
         if cpu:
-            os.environ.setdefault("JAX_PLATFORMS", "cpu")
-        # In launcher-spawned workers, make JAX_PLATFORMS win even when a site
-        # hook pre-registered another backend via jax.config (registration
-        # order would otherwise override the launcher's choice). Never applied
-        # in-process, where a user's explicit jax.config.update must stand.
-        launched = (
-            "ACCELERATE_COORDINATOR_ADDRESS" in os.environ
-            or "ACCELERATE_PROCESS_INDEX" in os.environ
-            or self.fork_launched
-        )
-        platforms = os.environ.get("JAX_PLATFORMS")
-        if platforms and (launched or cpu):
-            try:
-                jax.config.update("jax_platforms", platforms)
-            except Exception:
-                pass  # backend already initialized; keep what we have
+            os.environ.setdefault("JAX_PLATFORMS", "cpu")  # for child processes
+            # jax read the environment at import: ask through its config.
+            jax.config.update("jax_platforms", "cpu")
         _maybe_init_jax_distributed()
 
         self.process_index = jax.process_index()

@@ -369,12 +369,14 @@ class TelemetryRecorder:
                 if extra > 0:
                     self.recompiles += extra
                     if not new_digest and not entry["layout_recompiled"]:
-                        # The one expected same-shape recompile: donated
-                        # buffers get their layout specialized on the second
-                        # call (bench.py warms up twice for the same reason).
-                        # Counted and recorded, but not warning-worthy.
+                        # The one expected same-shape recompile: the step
+                        # leaves its output shardings to GSPMD, which on a
+                        # mesh may return state leaves in another sharding
+                        # than prepare() gave them, so the second call sees
+                        # new input shardings (bench.py warms up twice for
+                        # this). Counted and recorded, not warning-worthy.
                         entry["layout_recompiled"] = True
-                        reason = "donated-buffer layout (expected once)"
+                        reason = "state shardings settle (expected once)"
                     else:
                         reason = (
                             "batch shape/dtype change" if new_digest
@@ -647,7 +649,7 @@ class TelemetryRecorder:
 
     def plan_block(self) -> Optional[dict]:
         """The summary's ``plan`` block: predicted vs measured, calibration
-        deltas — the evidence row bench.py embeds."""
+        deltas."""
         if self._plan is None:
             return None
         step_s, peak_gib = self._plan_measurements()

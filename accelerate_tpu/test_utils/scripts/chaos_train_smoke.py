@@ -18,8 +18,8 @@ Acceptance shape of the training-side chaos pillar end to end
    order, and ``nonfinite_grad`` poisons only the sentinel's metrics,
    never the model state.
 4. Zero steady-state recompiles: the telemetry recompile counter after
-   step 2 (the second call specializes donated-buffer layouts — the one
-   expected same-shape recompile, see telemetry.py) equals the final
+   step 2 (on a mesh the second call may compile once more, when the state
+   comes back in GSPMD's shardings — see telemetry.py) equals the final
    count, across the rollback replay.
 
 The worker subprocess is this same file with ``--worker``.
@@ -141,8 +141,8 @@ def worker(project_dir: str, status_file: str, chaos: bool) -> int:
             done = new_done
             last_loss = float(np.asarray(metrics["loss"]))
             if recompiles_after_warmup is None and done >= 2:
-                # Step 2 absorbed the expected one-time donated-buffer layout
-                # recompile; anything past this point is a real regression.
+                # Step 2 absorbed the one expected same-shape recompile
+                # (telemetry.py); anything past this point is a regression.
                 recompiles_after_warmup = acc.telemetry.recompiles
             print(f"CHAOSTRAIN_STEP {done} {last_loss}", flush=True)
             if done == SAVE_AT and not saved:

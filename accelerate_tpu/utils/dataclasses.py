@@ -533,7 +533,7 @@ class CompileKwargs(KwargsHandler):
       populates jit's dispatch cache — zero recompiles after warmup);
       ``"aot"`` does ``lower(abstract).compile()`` (primes the persistent
       cache only); ``"off"`` disables. ``warmup_calls`` executions per
-      signature absorb the donated-buffer layout specialization (default 2).
+      signature absorb the second-call recompile a mesh can cost (default 2).
     - ``manifest_path``: shapes-manifest override; default
       ``<project_dir>/compile_cache/shapes_manifest.jsonl``.
     - ``cache_budget_bytes``: LRU prune budget for the persistent executable
@@ -836,7 +836,7 @@ class DisaggConfig(KwargsHandler):
       hosting it on the slice's first device. Off by default: jitted
       programs taking typed PRNG-key arrays under a multi-device
       NamedSharding occupy TWO dispatch-cache entries for ONE compiled
-      executable (jax 0.4.37), so the sharded path reports
+      executable (still so on jax 0.9.0), so the sharded path reports
       ``decode_executables == 2`` even though exactly one program is ever
       compiled; the engine pre-warms both entries at init so the census
       stays flat (``steady_recompiles == 0``) either way.

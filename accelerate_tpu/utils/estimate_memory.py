@@ -35,17 +35,11 @@ GiB = 1024 ** 3
 def build_abstract_mesh(parallelism_config) -> AbstractMesh:
     """AbstractMesh with the trainer's canonical axis order (so the planner
     produces identical specs to ParallelismConfig.build_mesh's real mesh)."""
-    import inspect
-
     from ..parallelism_config import MESH_AXIS_ORDER
 
     cfg = parallelism_config
     names = ("pp",) + MESH_AXIS_ORDER
     shape = (cfg.pp_size,) + tuple(cfg.axis_size(ax) for ax in MESH_AXIS_ORDER)
-    # jax moved AbstractMesh from (axis_sizes, axis_names) to a single
-    # ((name, size), ...) shape_tuple around 0.4.36; support both.
-    if "shape_tuple" in inspect.signature(AbstractMesh.__init__).parameters:
-        return AbstractMesh(tuple(zip(names, shape)))
     return AbstractMesh(shape, names)
 
 

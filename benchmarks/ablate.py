@@ -15,9 +15,9 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from accelerate_tpu.utils.environment import honor_jax_platforms_env
+from accelerate_tpu.compile_manager import place_compile_cache
 
-honor_jax_platforms_env()
+place_compile_cache()
 
 
 def measure(seq, iters, *, remat, remat_policy, fused_loss, batch=None, fp8=False):
@@ -73,11 +73,11 @@ def measure(seq, iters, *, remat, remat_policy, fused_loss, batch=None, fp8=Fals
     state = acc.train_state
     for _ in range(2):
         state, metrics = step(state, b)
-        float(np.asarray(metrics["loss"]))
+        jax.block_until_ready(metrics["loss"])
     t0 = time.perf_counter()
     for _ in range(iters):
         state, metrics = step(state, b)
-    loss = float(np.asarray(metrics["loss"]))
+    loss = float(jax.block_until_ready(metrics["loss"]))
     dt = (time.perf_counter() - t0) / iters
     assert np.isfinite(loss), loss
     return batch * seq / dt / len(jax.devices()), loss
@@ -107,14 +107,18 @@ def main():
     if args.variants:
         keep = args.variants.split(",")
         variants = {k: v for k, v in variants.items() if k in keep}
+    failed = []
     for name, kw in variants.items():
-        # flush per row (streaming-evidence rule, round-3 postmortem): a
-        # driver timeout mid-sweep must keep every finished variant's number.
+        # One row per variant, flushed: a variant that does not fit must not
+        # cost the rows of those that do. Any failure is a nonzero exit.
         try:
             tok, loss = measure(args.seq, args.iters, **kw)
             print(f"{name:28s} {tok:10.1f} tok/s/chip   loss {loss:.4f}", flush=True)
         except Exception as e:
+            failed.append(name)
             print(f"{name:28s} FAILED: {type(e).__name__}: {str(e)[:200]}", flush=True)
+    if failed:
+        sys.exit(f"{len(failed)} variant(s) failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

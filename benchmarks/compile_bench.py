@@ -62,9 +62,9 @@ def main():
 
     import jax
 
-    from accelerate_tpu.utils.environment import honor_jax_platforms_env
-
-    honor_jax_platforms_env()
+    # This script times compilation itself: a persistent-cache hit would be
+    # timed as a compile, so the cache is off here whatever the environment.
+    jax.config.update("jax_enable_compilation_cache", False)
     print(json.dumps({"row": "start", "platform": jax.devices()[0].platform}), flush=True)
 
     rows = []

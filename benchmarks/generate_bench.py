@@ -236,9 +236,9 @@ def main():
     import jax
     import jax.numpy as jnp
 
-    from accelerate_tpu.utils.environment import honor_jax_platforms_env
+    from accelerate_tpu.compile_manager import place_compile_cache
 
-    honor_jax_platforms_env()
+    place_compile_cache()
 
     from accelerate_tpu import Model, dispatch_model, load_checkpoint_and_dispatch
     from accelerate_tpu.generation import generate
@@ -279,13 +279,10 @@ def main():
     res_model = Model(module=module, params=resident.params)
 
     t0 = time.perf_counter()
-    out = generate(res_model, prompt, max_new_tokens=args.new_tokens)
-    out.block_until_ready()
-    np.asarray(out)
+    jax.block_until_ready(generate(res_model, prompt, max_new_tokens=args.new_tokens))
     first_s = time.perf_counter() - t0  # includes compile
     t0 = time.perf_counter()
-    out = generate(res_model, prompt, max_new_tokens=args.new_tokens)
-    np.asarray(out)
+    jax.block_until_ready(generate(res_model, prompt, max_new_tokens=args.new_tokens))
     warm_s = time.perf_counter() - t0
     per_token = warm_s / args.new_tokens
     print(json.dumps({
@@ -305,10 +302,9 @@ def main():
 
         qm = quantize_model_for_decode(res_model)
         clear_generation_cache()
-        np.asarray(generate(qm, prompt, max_new_tokens=args.new_tokens))  # compile
+        jax.block_until_ready(generate(qm, prompt, max_new_tokens=args.new_tokens))  # compile
         t0 = time.perf_counter()
-        out = generate(qm, prompt, max_new_tokens=args.new_tokens)
-        np.asarray(out)
+        jax.block_until_ready(generate(qm, prompt, max_new_tokens=args.new_tokens))
         warm_q = time.perf_counter() - t0
         print(json.dumps({
             "row": "resident_int8", "s_per_token": round(warm_q / args.new_tokens, 4),

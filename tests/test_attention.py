@@ -214,9 +214,7 @@ def test_pallas_flash_under_shard_map_dp_tp():
     spec = P(("dp_replicate", "dp_shard"), None, "tp", None)
     fn = functools.partial(pallas_flash_attention, causal=True, block_q=64, block_k=64,
                            interpret=True)
-    from accelerate_tpu.utils.environment import shard_map_compat
-
-    sharded = shard_map_compat(fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+    sharded = jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
                                check_vma=False)
     q_s = jax.device_put(q, NamedSharding(mesh, spec))
     k_s = jax.device_put(k, NamedSharding(mesh, spec))

@@ -1,6 +1,5 @@
-"""bench.py must never rot: it is the only path perf evidence reaches the
-driver. CPU smoke of the child (tiny config substitution) — asserts the
-final JSON row parses, carries the contract fields, and measures something."""
+"""The chip entry points refuse to run without a chip, and the control flow
+of ``chip_smoke.py`` stays runnable (``--rehearse``) between chip runs."""
 
 import json
 import os
@@ -9,74 +8,39 @@ import sys
 
 import pytest
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _run(script, *args, timeout):
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    env.pop("XLA_FLAGS", None)  # one CPU device, like a bare run
+    return subprocess.run(
+        [sys.executable, os.path.join(REPO, script), *args],
+        capture_output=True, text=True, timeout=timeout, env=env, cwd=REPO,
+    )
+
+
+@pytest.mark.parametrize("script", ["bench.py", "chip_smoke.py"])
+def test_no_chip_is_a_failure_not_a_cpu_run(script):
+    """Off-chip: nonzero exit, the missing TPU named, and no result row —
+    never a CPU number under a device metric's name."""
+    r = _run(script, timeout=120)
+    assert r.returncode != 0, r.stdout[-2000:]
+    assert "no TPU" in r.stderr and "'cpu'" in r.stderr, r.stderr[-2000:]
+    assert not [l for l in r.stdout.splitlines() if l.startswith("{")], r.stdout[-2000:]
+
 
 @pytest.mark.slow
-def test_bench_child_cpu_smoke():
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
-    env.pop("XLA_FLAGS", None)  # single CPU device, like a bare bench run
-    r = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py"), "--child",
-         "--oom-level=0", "--budget-s=240"],
-        capture_output=True, text=True, timeout=600, env=env, cwd=repo,
-    )
+def test_chip_smoke_rehearsal_cpu():
+    """The opt-in rehearsal runs every phase at a tiny size; every line says
+    it ran on the CPU and the result row is marked as a rehearsal."""
+    r = _run("chip_smoke.py", "--rehearse", timeout=600)
     assert r.returncode == 0, r.stderr[-2000:]
-    rows = [json.loads(l) for l in r.stdout.splitlines() if l.startswith("{")]
-    assert rows, r.stdout[-2000:]
-    final = rows[-1]
-    assert final["event"] == "final"
-    assert final["metric"] == "llama_fsdp_train_tokens_per_sec_per_chip"
-    assert final["value"] > 0
-    assert {"mfu_2048", "params_b", "device_kind", "platform"} <= final.keys()
-    # Off-chip the fp8/int8/8192 phases must be skipped, not attempted.
-    assert "tok_s_fp8_2048" not in final and "seq8192_error" not in final
-    # Telemetry summary rides in every bench row (step-time distribution,
-    # recompiles, peak HBM) so rounds stay comparable.
-    tel = final.get("telemetry")
-    assert tel, f"telemetry summary missing from final row: {final}"
-    assert tel["steps"] > 0
-    assert tel["step_time_mean_s"] > 0
-    assert "recompiles" in tel and "peak_hbm_bytes" in tel
-
-
-def test_supervisor_cpu_fallback_after_dead_probes(monkeypatch, capsys):
-    """A relay that stays dead through the probe cap must yield a measured
-    CPU-mesh-ladder row with the reason attached — not an error row."""
-    import importlib.util
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "bench_fallback_test", os.path.join(repo, "bench.py")
-    )
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    calls = {"children": 0}
-
-    def fake_probe(timeout_s=90, env=None):
-        # The device backend hangs forever; the CPU fallback env answers.
-        if env is not None and env.get("JAX_PLATFORMS") == "cpu":
-            return True, ""
-        return False, "timeout"
-
-    def fake_child(cmd, timeout_s, env=None):
-        calls["children"] += 1
-        assert env is not None and env.get("JAX_PLATFORMS") == "cpu"
-        row = {"metric": bench.METRIC, "value": 12.5, "unit": "tok/s/chip",
-               "vs_baseline": 0.1, "event": "final"}
-        return 0, row, ""
-
-    monkeypatch.setattr(bench, "_backend_probe", fake_probe)
-    monkeypatch.setattr(bench, "_run_child_streaming", fake_child)
-    monkeypatch.setattr(bench.time, "sleep", lambda s: None)
-
-    rc = bench.supervise()
-    out = capsys.readouterr().out
-    assert rc == 0
-    rows = [json.loads(l) for l in out.splitlines() if l.startswith("{")]
-    assert any(r.get("event") == "cpu_fallback" for r in rows)
-    final = rows[-1]
-    assert final["event"] == "final" and final["value"] == 12.5
-    assert final["fallback"] == "cpu-mesh-ladder"
-    assert "unreachable" in final["fallback_reason"]
-    assert calls["children"] == 1, "fallback must not burn extra child attempts"
+    *lines, last = r.stdout.splitlines()
+    assert all(l.startswith("[platform=cpu rehearsal]") for l in lines), r.stdout[-2000:]
+    for phase in ("device", "kernels", "trainer", "server"):
+        assert any(f"phase {phase}: PASS" in l for l in lines), phase
+    assert json.loads(last) == {
+        "ok": True, "rehearsal": True,
+        "device": {"platform": "cpu", "kind": "cpu", "count": 1},
+    }

@@ -142,6 +142,21 @@ def test_launch_single_process_env(tmp_path):
     assert env["PARALLELISM_CONFIG_TP_SIZE"] == "2"
 
 
+def test_launch_refuses_local_gang_on_accelerator(tmp_path, monkeypatch, capsys):
+    """A chip belongs to one process: N local ranks without --cpu would each
+    claim every chip and hang, so the launcher refuses before spawning and
+    names --num_processes 1. (``tpu,cpu`` is what a TPU host exports.)"""
+    from unittest import mock
+
+    from accelerate_tpu.commands.accelerate_cli import main
+
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    with mock.patch("subprocess.Popen", side_effect=AssertionError("spawned")):
+        rc = main(["launch", "--num_processes", "2", str(tmp_path / "never_run.py")])
+    assert rc == 2
+    assert "--num_processes 1" in capsys.readouterr().err
+
+
 def _square(x):
     assert x == 3
 

@@ -446,6 +446,35 @@ def test_persistent_cache_dir_created_and_validated(tmp_path):
         jax.config.update("jax_compilation_cache_dir", prev)
 
 
+def test_compile_cache_is_placed_from_outside(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: jax reads it itself and no code sets
+    another directory — neither the entry scripts' helper nor a JitConfig.
+    Unset: the helper picks ``<checkout>/.jax_cache``, a fixed path."""
+    import jax
+
+    from accelerate_tpu.compile_manager import (
+        configure_persistent_cache,
+        place_compile_cache,
+    )
+    from accelerate_tpu.utils import JitConfig
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/x")
+        assert place_compile_cache() == "/x"
+        jit_config = JitConfig(persistent_cache_dir=str(tmp_path / "mine"))
+        assert configure_persistent_cache(jit_config) == "/x"
+        assert jax.config.jax_compilation_cache_dir == prev
+        assert not (tmp_path / "mine").exists()
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert place_compile_cache() == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == os.path.join(repo, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
 def test_persistent_cache_unwritable_warns_and_disables(tmp_path, caplog):
     from accelerate_tpu.utils import JitConfig
 

@@ -239,7 +239,7 @@ def test_decode_steady_state_one_executable(llama):
 
 def test_shard_decode_slots_optin_flat_census(llama):
     """The opt-in slot-sharded decode placement keeps a FLAT dispatch census
-    (pre-warmed at init — jax 0.4.37 holds two dispatch entries for one
+    (pre-warmed at init — jax 0.9.0 holds two dispatch entries for one
     compiled typed-key program under a multi-device NamedSharding) and zero
     steady recompiles; outputs stay bit-equal to the colocated engine."""
     cfg, model = llama

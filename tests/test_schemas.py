@@ -68,7 +68,7 @@ SPECULATION_KEYS = {
 
 # The engine ``stats()["sdc"]`` block (DecodeCanary.summary; None when no
 # canary is attached) and the telemetry ``summary()["sdc"]`` block
-# (SDCSentinel.summary) — bench.py embeds the latter next to ``faults``.
+# (SDCSentinel.summary).
 SDC_CANARY_KEYS = {
     "every", "armed", "golden_digest", "probes", "mismatches",
     "quarantines", "suppressed_rows",

@@ -176,6 +176,25 @@ def test_single_decode_executable_steady_state(llama):
     assert stats["steady_recompiles"] == 0
 
 
+def test_prefill_executables_flat_with_mesh_placed_params(llama):
+    """Params prepared by an Accelerator carry a NamedSharding over its mesh
+    even on one device. The cache must start in that same form, or the first
+    prefill returns it changed and the rung that ran first compiles twice
+    (first seen on the v5e: 6 prefill executables for a 5-rung ladder)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    cfg, model = llama
+    mesh = Mesh(np.asarray(jax.devices()[:1]), ("dp_shard",))
+    placed = Model(module=model.module, params=jax.device_put(
+        model.params, NamedSharding(mesh, PartitionSpec())))
+    engine = ServingEngine(
+        placed, ServingConfig(n_slots=2, max_len=64, prefill_chunks=[4, 8])
+    )
+    engine.warmup()  # walks rung 8 first, then rung 4
+    engine.run(_prompts(cfg, [8, 12]), max_new_tokens=3)  # rung 8 again
+    assert engine.stats()["prefill_executables"] == 2
+
+
 def test_occupancy_and_token_accounting(llama):
     cfg, model = llama
     budgets = [3, 6, 4, 5, 7, 2]
