@@ -73,8 +73,8 @@ from .logging import get_logger
 from .planner import (BandwidthTable, PlannerError, kv_bytes_per_token,
                       plan_disagg_slices)
 from .resharding import ReshardExecutor
-from .serving import (ServingEngine, SlotState, _cache_size, _release_step,
-                      init_slot_state, plan_chunks)
+from .serving import (TICK, ServingEngine, SlotState, _cache_size,
+                      _release_step, init_slot_state, plan_chunks)
 
 logger = get_logger(__name__)
 
@@ -359,58 +359,35 @@ class DisaggServingEngine(ServingEngine):
         (disjoint devices — the chunks run concurrently), then one decode
         step on the decode mesh. Degraded mode (every lane quarantined)
         prefills head-of-line colocated on the decode mesh instead."""
-        prof = self._profiler
-        t0 = time.perf_counter() if prof is not None else 0.0
-        tick_no = self._stats["ticks"]
-        snap = self._begin_tick()
-        self._admit()
-        self._sample_queue_depth()
-        self._drain_handoffs()
-        if not self._degraded:
-            self._assign_lanes()
-        t1 = time.perf_counter() if prof is not None else 0.0
-        for _ in range(max(1, int(self.config.prefill_chunks_per_tick))):
-            if self._degraded:
-                # Colocated fallback: the base head-of-line discipline, the
-                # base dispatch path (lane is None routes there).
-                if not self._prefilling:
-                    break
-                self._prefill_one(self._prefilling[0])
-            else:
-                runnable = [r for r in self._prefilling if r.lane is not None]
-                if not runnable:
-                    break
-                for req in runnable:
-                    self._prefill_one(req)
-        t2 = time.perf_counter() if prof is not None else 0.0
-        self._tick_fetch_s = 0.0  # filled by _decode_tick's device_get timer
-        if self._decoding:
-            self._decode_tick()
-        self._drain_decode_tick()
-        t3 = time.perf_counter() if prof is not None else 0.0
-        self._end_tick(snap)
-        if prof is not None:
-            # Same lagged per-tick attribution as the colocated engine's
-            # tick (serving.py): host perf_counter sections only, the
-            # bookkeeping residual closes the identity. admit_s absorbs the
-            # router-only phases (handoff drain + lane assignment).
-            t4 = time.perf_counter()
-            prof.on_tick(
-                tick_no, t4 - t0,
-                sections={
-                    "admit_s": t1 - t0,
-                    "prefill_s": t2 - t1,
-                    "decode_s": (t3 - t2) - self._tick_fetch_s,
-                    "host_fetch_s": self._tick_fetch_s,
-                    "bookkeeping_s": t4 - t3,
-                },
-                gauges={
-                    "journal_lsn": (self._journal.stats()["appends"]
-                                    if self._journal is not None else None),
-                    "jit_cache": self.executable_counts(),
-                    "occupancy": len(self._decoding),
-                },
-            )
+        with self._phase(TICK):
+            # serving.admit takes in the router-only steps: handoff drain
+            # and lane assignment.
+            with self._phase("serving.admit"):
+                snap = self._begin_tick()
+                self._admit()
+                self._sample_queue_depth()
+                self._drain_handoffs()
+                if not self._degraded:
+                    self._assign_lanes()
+            for _ in range(max(1, int(self.config.prefill_chunks_per_tick))):
+                if self._degraded:
+                    # Colocated fallback: the base head-of-line discipline,
+                    # the base dispatch path (lane is None routes there).
+                    if not self._prefilling:
+                        break
+                    self._prefill_one(self._prefilling[0])
+                else:
+                    runnable = [r for r in self._prefilling
+                                if r.lane is not None]
+                    if not runnable:
+                        break
+                    for req in runnable:
+                        self._prefill_one(req)
+            if self._decoding:
+                self._decode_tick()
+            self._drain_decode_tick()
+            with self._phase("serving.end_tick"):
+                self._end_tick(snap)
 
     def _assign_lanes(self) -> None:
         """Hand free lanes to lane-less prefilling requests, health-checking
@@ -920,6 +897,8 @@ class DisaggServingEngine(ServingEngine):
         size = _cache_size(self._decode)
         if size is not None:
             self._decode_executables_baseline = size
+        if self._prefill_executables_warm is not None:
+            self._prefill_executables_warm = _cache_size(self._prefill)
         self._rstats["resizes"] += 1
         if tr is not None:
             tr.end(h_commit, self._stats["ticks"], rebound=rebound,
@@ -1014,31 +993,34 @@ class DisaggServingEngine(ServingEngine):
                 for slot, r in L.decoding.items():
                     if r.weights_version == v:
                         mask[slot] = True
-                L.cache, L.state, toks, emitted, bad = self._decode(
-                    L.params_by_version[v], L.cache, L.state, mask)
-                self._stats["decode_steps"] += 1
-                toks_np, emitted_np, done_np, bad_np = jax.device_get(
-                    (toks, emitted, L.state.done, bad))
-                for slot, req in list(L.decoding.items()):
-                    if req.weights_version != v or not mask[slot]:
-                        continue
-                    if bool(bad_np[slot]):
-                        del L.decoding[slot]
-                        L.state = _release_step(L.state, np.int32(slot))
-                        req.slot = None
-                        self._retry_or_fail(
-                            req, reason=("nonfinite logits while draining "
-                                         f"layout {L.layout_id}"))
-                        continue
-                    cnt = int(emitted_np[slot])
-                    for t in toks_np[slot, :cnt]:
-                        req.out.append(int(t))
-                    if self._speculate_k > 0 and cnt > 0:
-                        req.spec_drafted += self._speculate_k
-                        req.spec_accepted += max(cnt - 1, 0)
-                    if bool(done_np[slot]):
-                        del L.decoding[slot]
-                        self._finish(req, "ok")
+                with self._phase("serving.decode_dispatch"):
+                    L.cache, L.state, toks, emitted, bad = self._decode(
+                        L.params_by_version[v], L.cache, L.state, mask)
+                    self._stats["decode_steps"] += 1
+                with self._phase("serving.decode_fetch"):
+                    toks_np, emitted_np, done_np, bad_np = jax.device_get(
+                        (toks, emitted, L.state.done, bad))
+                with self._phase("serving.bookkeeping"):
+                    t_fetch = time.perf_counter()
+                    for slot, req in list(L.decoding.items()):
+                        if req.weights_version != v or not mask[slot]:
+                            continue
+                        if bool(bad_np[slot]):
+                            del L.decoding[slot]
+                            L.state = _release_step(L.state, np.int32(slot))
+                            req.slot = None
+                            self._retry_or_fail(
+                                req, reason=("nonfinite logits while "
+                                             f"draining layout {L.layout_id}"))
+                            continue
+                        cnt = int(emitted_np[slot])
+                        self._emit(req, toks_np[slot, :cnt], t_fetch)
+                        if self._speculate_k > 0 and cnt > 0:
+                            req.spec_drafted += self._speculate_k
+                            req.spec_accepted += max(cnt - 1, 0)
+                        if bool(done_np[slot]):
+                            del L.decoding[slot]
+                            self._finish(req, "ok")
         self._prune_drained()
 
     def _prune_drained(self) -> None:
@@ -1104,6 +1086,7 @@ class DisaggServingEngine(ServingEngine):
         prompt_len = min(sum(self.ladder), self.t_max - 2)
         prompt = np.ones((prompt_len,), np.int32)
         self.run([prompt] * len(self._lanes), max_new_tokens=2)
+        self._prefill_executables_warm = _cache_size(self._prefill)
         self.reset_metrics()
 
     def reset_metrics(self) -> None:

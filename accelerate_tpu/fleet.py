@@ -84,6 +84,7 @@ import numpy as np
 
 from .journal import RequestJournal
 from .logging import get_logger
+from .serving import timing_row_keys
 from .utils.constants import (
     CELL_DEAD_EXIT_CODE,
     FLEET_DEGRADED_EXIT_CODE,
@@ -368,7 +369,7 @@ class FleetRouter:
                     np.full((budget,), pad, np.int32)]),
                 "new_tokens": 0, "ttft_s": None, "tpot_s": None,
                 "weights_version": None, "attempt": 1, "recovered": False,
-                "drafted": 0, "accepted": 0,
+                "drafted": 0, "accepted": 0, **timing_row_keys(),
                 "cell": None, "spilled": False, "drained_from": None,
             }
             self._requests[rid] = {"cid": eng_cid, "cell": None,
@@ -590,6 +591,7 @@ class FleetRouter:
                     "recovered": True,
                     "drafted": int(trec.get("drafted", 0)),
                     "accepted": int(trec.get("accepted", 0)),
+                    **timing_row_keys(),
                     "cell": cell.name, "spilled": rec["spilled"],
                     "drained_from": cell.name,
                 }

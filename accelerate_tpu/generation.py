@@ -143,6 +143,13 @@ def _row_positions(start, b: int, s: int) -> jax.Array:
     return jnp.broadcast_to(start + offs, (b, s))
 
 
+# The named scopes below (``attn``, ``cache_write``, ``mlp``, ``moe.router``,
+# ``moe.experts``, ``lm_head``) change the HLO's metadata only: a device
+# trace can group a step's ops by them, where the fusions' own names say
+# shapes.
+
+
+@jax.named_scope("cache_write")
 def _cache_write(ck, k_new, start):
     """Write ``k_new`` (B, S, Hkv, D) into the cache slice ``ck``
     (B, T, Hkv, D) at row offset ``start`` — a scalar (one contiguous
@@ -194,6 +201,7 @@ def _dense(p, x):
     return y
 
 
+@jax.named_scope("mlp")
 def _mlp(cfg, p, x):
     from .models.llama import activation_fn
 
@@ -248,6 +256,7 @@ def _qkv_proj(attn, hn, cos, sin, rotary_dim=None):
     return rope(proj("q_proj")), rope(proj("k_proj")), proj("v_proj")
 
 
+@jax.named_scope("attn")
 def _attend(q, k, v, q_positions, kv_valid=None):
     """q (B,Sq,Hq,D) vs cached k/v (B,T,Hkv,D); causal wrt absolute cache
     slots. The causal bound kv_pos <= q_position also excludes unwritten
@@ -331,10 +340,11 @@ def _llama_forward_cached(cfg, params, input_ids, cache: KVCache, return_all=Fal
     x, (new_k, new_v) = jax.lax.scan(one_layer, x, (stacked, cache.k, cache.v))
     x = _chassis_norm(cfg, model_p["norm"], x)
     h_out = x if return_all else x[:, -1]
-    if cfg.tie_word_embeddings:
-        logits = h_out @ embed.T.astype(cfg.dtype)
-    else:
-        logits = h_out @ params["lm_head"]["kernel"].astype(cfg.dtype)
+    with jax.named_scope("lm_head"):
+        if cfg.tie_word_embeddings:
+            logits = h_out @ embed.T.astype(cfg.dtype)
+        else:
+            logits = h_out @ params["lm_head"]["kernel"].astype(cfg.dtype)
     ls = getattr(cfg, "logits_scaling", 1.0)
     if ls != 1.0:  # Granite: logits / scaling
         logits = logits / jnp.asarray(ls, logits.dtype)
@@ -577,21 +587,23 @@ def _mixtral_forward_cached(cfg, params, input_ids, cache: KVCache, return_all=F
     def moe(p, h):
         T = b * s
         tokens = h.reshape(T, -1)
-        router_logits = tokens.astype(jnp.float32) @ p["router"].astype(jnp.float32)
-        probs = jax.nn.softmax(router_logits, axis=-1)
-        topv, topi = jax.lax.top_k(probs, k)  # (T, k)
-        topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
+        with jax.named_scope("moe.router"):
+            router_logits = tokens.astype(jnp.float32) @ p["router"].astype(jnp.float32)
+            probs = jax.nn.softmax(router_logits, axis=-1)
+            topv, topi = jax.lax.top_k(probs, k)  # (T, k)
+            topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
         # Dense dispatch over experts: fine at decode sizes, exact (dropless).
         def per_expert(e):
             gate = jax.nn.silu(tokens @ p["w_gate"][e].astype(tokens.dtype))
             up = tokens @ p["w_up"][e].astype(tokens.dtype)
             return (gate * up) @ p["w_down"][e].astype(tokens.dtype)
 
-        expert_out = jax.vmap(per_expert)(jnp.arange(cfg.num_local_experts))  # (E, T, H)
-        picked = jnp.take_along_axis(
-            jnp.transpose(expert_out, (1, 0, 2)), topi[..., None], axis=1
-        )  # (T, k, H)
-        out = jnp.sum(picked * topv[..., None].astype(picked.dtype), axis=1)
+        with jax.named_scope("moe.experts"):
+            expert_out = jax.vmap(per_expert)(jnp.arange(cfg.num_local_experts))  # (E, T, H)
+            picked = jnp.take_along_axis(
+                jnp.transpose(expert_out, (1, 0, 2)), topi[..., None], axis=1
+            )  # (T, k, H)
+            out = jnp.sum(picked * topv[..., None].astype(picked.dtype), axis=1)
         return out.reshape(b, s, -1)
 
     def one_layer(carry, layer):
@@ -610,7 +622,8 @@ def _mixtral_forward_cached(cfg, params, input_ids, cache: KVCache, return_all=F
 
     x, (new_k, new_v) = jax.lax.scan(one_layer, x, (stacked, cache.k, cache.v))
     x = rms_norm(x, model_p["norm"]["weight"].astype(x.dtype), cfg.rms_norm_eps)
-    logits = (x if return_all else x[:, -1]) @ params["lm_head"]["kernel"].astype(cfg.dtype)
+    with jax.named_scope("lm_head"):
+        logits = (x if return_all else x[:, -1]) @ params["lm_head"]["kernel"].astype(cfg.dtype)
     return logits.astype(jnp.float32), KVCache(new_k, new_v, start + s)
 
 

@@ -158,6 +158,7 @@ def _fwd(q3, k3, v3, offs, *, causal, scale, block_q, block_k, sk_actual,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_fwd",
     )(offs, q3, k3, v3)
     return out, lse
 
@@ -300,6 +301,7 @@ def _bwd(q3, k3, v3, offs, out, lse, g_out, g_lse, *, causal, scale, block_q,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_dq",
     )(offs, q3, k3, v3, g_out, lse, delta)
 
     # dK/dV: grid over KV heads; innermost dim folds (GQA group g, q block qi)
@@ -341,6 +343,7 @@ def _bwd(q3, k3, v3, offs, out, lse, g_out, g_lse, *, causal, scale, block_q,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_dkv",
     )(offs, q3, k3, v3, g_out, lse, delta)
     return dq, dk, dv
 

@@ -21,9 +21,8 @@ gap left by telemetry (loop health) and tracing (per-request spans):
   counters/gauges/histograms and ``stats()`` providers into it, and ONE
   Prometheus text renderer (:meth:`MetricsHub.render`) exposes them under
   the pinned ``accelerate_tpu_<subsystem>_<name>`` scheme — replacing the
-  per-module emitters that used to live in ``tracing.py`` / ``serving.py``
-  (old names stay as aliases for one release, announced by a single
-  ``warning_once``). SLO burn-rate records are computed on the hub's
+  per-module emitters that used to live in ``tracing.py`` / ``serving.py``.
+  SLO burn-rate records are computed on the hub's
   rolling windows.
 - :class:`FlightRecorder` is a bounded ring buffer of the last N step/tick
   attribution records, recent spans, the journal LSN, memory gauges, and
@@ -192,10 +191,6 @@ class MetricsHub:
       series (tracing's per-kind span counters); still rendered by THIS
       renderer so the name set stays auditable in one place.
 
-    Old metric names live on as aliases for one release
-    (:meth:`alias`): the renderer duplicates the new series under the old
-    name and fires a single ``warning_once`` naming the replacement.
-
     SLO burn rate: :meth:`register_slo` + :meth:`observe_slo` feed bounded
     rolling windows; :meth:`burn_rates` turns them into
     error-rate-over-budget records, rendered as
@@ -207,9 +202,7 @@ class MetricsHub:
         self._instruments: Dict[str, Any] = {}
         self._providers: Dict[str, Callable[[], Dict[str, Any]]] = {}
         self._text_providers: List[Callable[[], List[str]]] = []
-        self._aliases: Dict[str, str] = {}  # old full name -> new full name
         self._slos: Dict[str, dict] = {}
-        self._alias_warned = False
 
     # -- instruments -----------------------------------------------------
 
@@ -263,11 +256,6 @@ class MetricsHub:
         series the instrument surface can't express)."""
         if fn not in self._text_providers:
             self._text_providers.append(fn)
-
-    def alias(self, old_name: str, new_name: str) -> None:
-        """Keep ``old_name`` rendering (duplicating ``new_name``'s series)
-        for one release; the renderer warns once that it is deprecated."""
-        self._aliases[old_name] = new_name
 
     # -- SLO rolling windows + burn rate ---------------------------------
 
@@ -361,23 +349,6 @@ class MetricsHub:
                 lines.extend(fn())
             except Exception:
                 logger.exception("metrics text provider failed")
-        if self._aliases:
-            if not self._alias_warned:
-                self._alias_warned = True
-                logger.warning_once(
-                    "metrics: deprecated metric-name aliases are still "
-                    "exported (%s) — they render for one release; scrape "
-                    "the accelerate_tpu_<subsystem>_<name> replacements."
-                    % ", ".join(f"{o}->{n}"
-                                for o, n in sorted(self._aliases.items())))
-            rendered = {}
-            for ln in lines:
-                if ln and not ln.startswith("#"):
-                    rendered[ln.split("{")[0].split(" ")[0]] = ln
-            for old, new in sorted(self._aliases.items()):
-                src = rendered.get(new)
-                if src is not None:
-                    lines.append(old + src[len(new):])
         return "\n".join(lines) + "\n"
 
     def metric_names(self) -> set:

@@ -565,6 +565,79 @@ def _cache_size(fn) -> Optional[int]:
 
 
 # ---------------------------------------------------------------------------
+# The tick's phases
+# ---------------------------------------------------------------------------
+
+TICK = "serving.tick"
+TICK_PHASES = (
+    "serving.admit",              # preemption latch, deadlines, admission, queue sample
+    "serving.prefill",            # a prompt chunk: host build and dispatch
+    "serving.first_token_fetch",  # the blocking fetch of a final chunk's token
+    "serving.decode_dispatch",    # version groups and the decode call
+    "serving.decode_fetch",       # the fused device_get: host blocked on the device
+    "serving.bookkeeping",        # per-slot loop after the fetch, retire, compile watch
+    "serving.end_tick",           # journal, chaos draw, hang guard, SDC canary
+)
+_DEVICE_WAIT_PHASES = ("serving.first_token_fetch", "serving.decode_fetch")
+_TOKEN_GAP_SAMPLE = 65536  # newest gaps kept for stats()["token_gap"]
+
+
+class _Phase:
+    """One phase of a tick (``with engine._phase(name):``), timed by one
+    pair of clock reads that feeds three sinks: a
+    ``jax.profiler.TraceAnnotation`` (so the phase lies on the host plane of
+    any running profile, on the device trace's clock), the engine's
+    always-on accumulator behind ``stats()["tick_phases"]``, and a span of
+    the ``TraceRecorder`` when one is attached.
+
+    A phase is charged its self time: a phase opened inside another pauses
+    it. Host time between two phases of a tick is charged to the one that
+    follows, and what is left at the tick's end to ``serving.end_tick``, so
+    the phases add up to the tick's wall time with no residual."""
+
+    __slots__ = ("_eng", "_name", "_attrs", "_ann", "_span", "_tick")
+
+    def __init__(self, eng, name, attrs):
+        self._eng = eng
+        self._name = name
+        self._attrs = attrs
+
+    def __enter__(self):
+        eng, name = self._eng, self._name
+        self._ann = jax.profiler.TraceAnnotation(name)
+        self._ann.__enter__()
+        now = time.perf_counter()
+        stack = eng._phase_stack
+        if stack:
+            top = stack[-1]
+            eng._tick_acc[name if top == TICK else top] += now - eng._phase_t
+        else:
+            eng._tick_t0 = now
+        eng._phase_t = now
+        stack.append(name)
+        self._tick = eng._stats["ticks"]
+        tr = eng.tracing
+        self._span = (tr.phase_begin(name, self._tick, now, self._attrs)
+                      if tr is not None else None)
+        return self
+
+    def __exit__(self, *exc):
+        eng, name = self._eng, self._name
+        now = time.perf_counter()
+        stack = eng._phase_stack
+        stack.pop()
+        eng._tick_acc["serving.end_tick" if name == TICK else name] += (
+            now - eng._phase_t)
+        eng._phase_t = now
+        if self._span is not None:
+            eng.tracing.phase_end(self._span, self._tick, now)
+        if not stack:  # the outermost tick has closed
+            eng._fold_tick(self._tick, now - eng._tick_t0)
+        self._ann.__exit__(*exc)
+        return False
+
+
+# ---------------------------------------------------------------------------
 # Host-side request bookkeeping
 # ---------------------------------------------------------------------------
 
@@ -572,8 +645,9 @@ def _cache_size(fn) -> Optional[int]:
 class _Request:
     __slots__ = (
         "id", "tokens", "budget", "rng", "slot", "lane", "chunks", "next_chunk",
-        "consumed", "out", "submit_t", "admit_t", "first_token_t", "done_t",
-        "deadline", "retries", "status", "weights_version", "canary", "layout",
+        "consumed", "out", "submit_t", "admit_t", "first_dispatch_t",
+        "first_token_t", "done_t", "token_t", "deadline", "retries", "status",
+        "weights_version", "canary", "layout",
         "client_request_id", "recoveries", "spec_drafted", "spec_accepted",
     )
 
@@ -590,8 +664,10 @@ class _Request:
         self.out: list[int] = []      # sampled continuation (incl. EOS)
         self.submit_t = time.perf_counter()
         self.admit_t = None           # slot granted (TTFT = queue + prefill)
+        self.first_dispatch_t = None  # its first prompt chunk is dispatched
         self.first_token_t = None
         self.done_t = None
+        self.token_t: list[float] = []  # perf_counter stamp of each of `out`
         self.deadline = None          # absolute perf_counter SLO, or None
         self.retries = 0              # recovery resubmissions consumed
         self.status = None            # terminal: ok | timeout | shed | failed
@@ -615,9 +691,36 @@ class _Request:
         self.consumed = 0
         self.out = []
         self.admit_t = None
+        self.first_dispatch_t = None
         self.first_token_t = None
+        self.token_t = []
         self.spec_drafted = 0
         self.spec_accepted = 0
+
+
+TTFT_TERMS = ("queue_wait_s", "prefill_blocked_s", "prefill_own_s")
+
+
+def _ttft_terms(req: _Request) -> tuple[float, float, float]:
+    """A request's TTFT as :data:`TTFT_TERMS`: queued for a slot; granted
+    one but behind other requests' chunks, or waiting for a lane; from its
+    own first dispatch to its first token. They telescope to ``ttft_s``."""
+    return (req.admit_t - req.submit_t,
+            req.first_dispatch_t - req.admit_t,
+            req.first_token_t - req.first_dispatch_t)
+
+
+def timing_row_keys(req: Optional[_Request] = None) -> dict:
+    """The timing keys of a ``poll()`` row: :data:`TTFT_TERMS` and each new
+    token's seconds after submit. Without a request — a row shed by the
+    fleet router, or one replayed from the journal, which keeps no
+    timings — every one is None."""
+    if req is None:
+        return dict.fromkeys(TTFT_TERMS + ("token_times_s",))
+    terms = (_ttft_terms(req) if req.first_token_t is not None
+             else (None, None, None))
+    return dict(zip(TTFT_TERMS, terms),
+                token_times_s=[t - req.submit_t for t in req.token_t])
 
 
 class ServingEngine:
@@ -656,11 +759,11 @@ class ServingEngine:
         # ``is None`` check, same zero-cost contract as telemetry/chaos.
         self.tracing = tracing if tracing is not None else getattr(
             telemetry, "tracing", None)
-        # Device-time attribution (profiler.py DeviceTimeProfiler): ticks
-        # feed lagged per-tick term records (admit/prefill/decode/fetch +
-        # the bookkeeping residual) from host perf_counter sections — no
-        # extra device syncs. Defaults to the telemetry recorder's
-        # profiler (TelemetryKwargs(profile=...)); same None contract.
+        # Device-time attribution (profiler.py DeviceTimeProfiler): each
+        # tick feeds it a lagged term record made of the tick's phase
+        # seconds (_fold_tick) — no extra device syncs. Defaults to the
+        # telemetry recorder's profiler (TelemetryKwargs(profile=...));
+        # same None contract.
         self._profiler = profiler if profiler is not None else getattr(
             telemetry, "profiler", None)
         # Crash-durable request journal (journal.py): ``journal=`` takes a
@@ -785,10 +888,13 @@ class ServingEngine:
         self._last_done_t: Optional[float] = None
         self._ttfts: list[float] = []
         self._tpots: list[float] = []
-        # TTFT attribution: time queued for a slot vs time prefilling once
-        # granted — the split that tells congestion from compute.
-        self._queue_waits: list[float] = []
-        self._prefill_lats: list[float] = []
+        # TTFT attribution, noted at each first token: (queued for a slot,
+        # granted but behind other requests' chunks or waiting for a lane,
+        # from its own first dispatch to its first token). The three
+        # telescope to the request's ttft_s.
+        self._ttft_terms: list[tuple[float, float, float]] = []
+        # Gap before each fetched token since the request's previous one.
+        self._token_gaps: deque[float] = deque(maxlen=_TOKEN_GAP_SAMPLE)
         # Rolling-window SLO aggregates (stats()["window"]): the lifetime
         # percentiles above average over the whole run, so a long healthy
         # prefix masks a current breach (and an early shed storm taints the
@@ -803,7 +909,10 @@ class ServingEngine:
             "prompt_tokens_in": 0,
             "slot_allocs": 0, "slot_reuses": 0, "occupancy_sum": 0,
             "peak_occupancy": 0, "queue_depth_sum": 0, "queue_samples": 0,
-            "steady_recompiles": 0,
+            "steady_recompiles": 0, "prefill_steady_recompiles": 0,
+            # Seconds in each phase of the tick and in whole ticks
+            # (stats()["tick_phases"]).
+            "tick_wall_s": 0.0, **dict.fromkeys(TICK_PHASES, 0.0),
             # Speculative-decoding counters (stats()["speculation"] block +
             # the hub's accelerate_tpu_spec_* series). All zero when
             # speculate_k == 0.
@@ -824,10 +933,16 @@ class ServingEngine:
         self._spoil_op = None        # lazily jitted draft_mismatch program
         self._draining = False
         self._idle_ticks = 0
-        # Per-tick fused-fetch wall accumulator (profiler host_fetch_s
-        # term); reset by tick(), accumulated by _decode_tick (which the
-        # disagg router also calls — the attribute must always exist).
-        self._tick_fetch_s = 0.0
+        # The phases open right now (the tick at the bottom), the last
+        # boundary's clock read, and the running tick's seconds per phase,
+        # folded into _stats when the tick closes (_Phase, _fold_tick).
+        self._phase_stack: list[str] = []
+        self._phase_t = 0.0
+        self._tick_t0 = 0.0
+        self._tick_acc = dict.fromkeys(TICK_PHASES, 0.0)
+        # _cache_size(self._prefill) when warmup() ended; None until then,
+        # and then prefill programs compile on demand and nothing is watched.
+        self._prefill_executables_warm: Optional[int] = None
         # Decode canary (sdc.py DecodeCanary): attached via
         # attach_sdc_canary(); every tick-end hook is a single None check.
         self._sdc_canary = None
@@ -990,7 +1105,11 @@ class ServingEngine:
         one. ``attempt`` counts executions (1 + retries + crash-restart
         recoveries) and ``recovered`` flags rows that crossed a crash: a
         cached pre-crash completion replayed from the journal, or an
-        in-flight request re-run bit-equal after ``recover()``."""
+        in-flight request re-run bit-equal after ``recover()``. The timing
+        keys (:func:`timing_row_keys`): ``queue_wait_s`` +
+        ``prefill_blocked_s`` + ``prefill_own_s`` = ``ttft_s``, and
+        ``token_times_s``, each new token's seconds after ``submit`` (its
+        first is ``ttft_s``; tokens of one fetch share a time)."""
         out = list(self._finished)
         self._finished.clear()
         return out
@@ -1011,36 +1130,46 @@ class ServingEngine:
         slot. Raises :class:`ServingStalledError` via the hang guard if
         ``max_idle_ticks`` rounds pass with pending requests and zero
         progress."""
+        with self._phase(TICK):
+            with self._phase("serving.admit"):
+                snap = self._begin_tick()
+                self._admit()
+                self._sample_queue_depth()
+            for _ in range(max(1, int(self.config.prefill_chunks_per_tick))):
+                if not self._prefilling:
+                    break
+                self._prefill_one(self._prefilling[0])
+            if self._decoding:
+                self._decode_tick()
+            with self._phase("serving.end_tick"):
+                self._end_tick(snap)
+
+    def _phase(self, name: str, attrs: Optional[dict] = None) -> _Phase:
+        """The one timing mechanism of the tick: see :class:`_Phase`.
+        ``attrs`` go onto the recorder's span and are built by the caller
+        only when a recorder is attached."""
+        return _Phase(self, name, attrs)
+
+    def _fold_tick(self, tick_no: int, wall_s: float) -> None:
+        """A tick has closed: its phase seconds go into the always-on
+        counters and, as the five sections of its term record, to the
+        profiler when one is attached."""
+        acc, s = self._tick_acc, self._stats
+        s["tick_wall_s"] += wall_s
+        for name in TICK_PHASES:
+            s[name] += acc[name]
         prof = self._profiler
-        t0 = time.perf_counter() if prof is not None else 0.0
-        tick_no = self._stats["ticks"]
-        snap = self._begin_tick()
-        self._admit()
-        self._sample_queue_depth()
-        t1 = time.perf_counter() if prof is not None else 0.0
-        for _ in range(max(1, int(self.config.prefill_chunks_per_tick))):
-            if not self._prefilling:
-                break
-            self._prefill_one(self._prefilling[0])
-        t2 = time.perf_counter() if prof is not None else 0.0
-        self._tick_fetch_s = 0.0  # filled by _decode_tick's device_get timer
-        if self._decoding:
-            self._decode_tick()
-        t3 = time.perf_counter() if prof is not None else 0.0
-        self._end_tick(snap)
         if prof is not None:
-            # Lagged per-tick attribution: host perf_counter sections only
-            # (the fused device_get is already the tick's one host sync —
-            # the profiler adds none). bookkeeping_s closes the identity.
-            t4 = time.perf_counter()
             prof.on_tick(
-                tick_no, t4 - t0,
+                tick_no, wall_s,
                 sections={
-                    "admit_s": t1 - t0,
-                    "prefill_s": t2 - t1,
-                    "decode_s": (t3 - t2) - self._tick_fetch_s,
-                    "host_fetch_s": self._tick_fetch_s,
-                    "bookkeeping_s": t4 - t3,
+                    "admit_s": acc["serving.admit"],
+                    "prefill_s": (acc["serving.prefill"]
+                                  + acc["serving.first_token_fetch"]),
+                    "decode_s": acc["serving.decode_dispatch"],
+                    "host_fetch_s": acc["serving.decode_fetch"],
+                    "bookkeeping_s": (acc["serving.bookkeeping"]
+                                      + acc["serving.end_tick"]),
                 },
                 gauges={
                     "journal_lsn": (self._journal.stats()["appends"]
@@ -1049,6 +1178,8 @@ class ServingEngine:
                     "occupancy": len(self._decoding),
                 },
             )
+        for name in TICK_PHASES:
+            acc[name] = 0.0
 
     # -- robustness plumbing (shared with the disagg router's tick) --------
 
@@ -1203,49 +1334,89 @@ class ServingEngine:
         overrides to run the chunk on the prefill mesh and stream its KV
         page across)."""
         size, valid = req.chunks[req.next_chunk]
-        chunk = np.zeros((1, size), np.int32)
-        chunk[0, :valid] = req.tokens[req.consumed:req.consumed + valid]
         is_first = req.next_chunk == 0
         is_final = req.next_chunk == len(req.chunks) - 1
         tr = self.tracing
-        t0 = time.perf_counter() if tr is not None else None
-        try:
-            if self.chaos is not None:
-                fault = self.chaos.draw("prefill_dispatch",
-                                        self._stats["ticks"], unit=req.id)
-                if fault is not None:
-                    raise InjectedFaultError(fault)
-            tok, done0 = self._prefill_dispatch(req, chunk, valid, is_first,
-                                                is_final)
-        except RuntimeError as e:
-            # InjectedFaultError or a real XLA runtime failure — recovery is
-            # identical. Programming errors (TypeError etc.) still propagate.
-            self._on_prefill_failure(req, e)
-            return
-        req.next_chunk += 1
-        req.consumed += valid
-        self._stats["prefill_chunks"] += 1
-        self._stats["prefill_pad_tokens"] += size - valid
-        if tr is not None:
-            tr.prefill_chunk(req.id, self._stats["ticks"], t0,
-                             time.perf_counter(), size=size, valid=valid,
-                             lane=req.lane, slot=req.slot,
-                             index=req.next_chunk - 1, final=is_final)
-        if is_final:
-            self._prefilling.remove(req)
-            req.first_token_t = time.perf_counter()
-            req.out.append(int(tok))  # small host fetch — the TTFT moment
-            if (self._journal is not None
-                    and not self._journal_suppressed(req.id)):
-                self._journal_tokens.setdefault(req.id, []).append(
-                    req.out[-1])
+        with self._phase("serving.prefill", None if tr is None else {
+                "request_id": req.id, "size": size, "final": is_final}):
+            chunk = np.zeros((1, size), np.int32)
+            chunk[0, :valid] = req.tokens[req.consumed:req.consumed + valid]
+            t0 = time.perf_counter() if tr is not None or is_first else None
+            if is_first:
+                req.first_dispatch_t = t0
+            try:
+                if self.chaos is not None:
+                    fault = self.chaos.draw("prefill_dispatch",
+                                            self._stats["ticks"], unit=req.id)
+                    if fault is not None:
+                        raise InjectedFaultError(fault)
+                tok, done0 = self._prefill_dispatch(req, chunk, valid,
+                                                    is_first, is_final)
+            except RuntimeError as e:
+                # InjectedFaultError or a real XLA runtime failure — recovery
+                # is identical. Programming errors (TypeError etc.) still
+                # propagate.
+                self._on_prefill_failure(req, e)
+                return
+            req.next_chunk += 1
+            req.consumed += valid
+            self._stats["prefill_chunks"] += 1
+            self._stats["prefill_pad_tokens"] += size - valid
+            self._watch_prefill_recompiles()
             if tr is not None:
-                tr.first_token(req.id, self._stats["ticks"],
-                               req.first_token_t)
-            if bool(done0):
-                self._retire(req)
-            else:
-                self._decoding[req.slot] = req
+                tr.prefill_chunk(req.id, self._stats["ticks"], t0,
+                                 time.perf_counter(), size=size, valid=valid,
+                                 lane=req.lane, slot=req.slot,
+                                 index=req.next_chunk - 1, final=is_final)
+            if is_final:
+                self._prefilling.remove(req)
+                # The TTFT moment is noted as it always was, when the final
+                # chunk has been dispatched and before its token is fetched.
+                req.first_token_t = time.perf_counter()
+                with self._phase("serving.first_token_fetch"):
+                    first = int(tok)
+                self._emit(req, (first,), req.first_token_t)
+                # noted here and not at the finish, so that a request still
+                # decoding when a window closes counts
+                self._ttft_terms.append(_ttft_terms(req))
+                if tr is not None:
+                    tr.first_token(req.id, self._stats["ticks"],
+                                   req.first_token_t)
+                if bool(done0):
+                    self._retire(req)
+                else:
+                    self._decoding[req.slot] = req
+
+    def _emit(self, req: _Request, toks, t: float) -> None:
+        """``toks`` of one fetch, stamped ``t``, join the request's output
+        (and the journal's batch); the wait before each since the request's
+        previous token joins the gap sample."""
+        if not len(toks):
+            return
+        if req.token_t:
+            self._token_gaps.append(t - req.token_t[-1])
+            self._token_gaps.extend([0.0] * (len(toks) - 1))
+        new = [int(x) for x in toks]
+        req.out.extend(new)
+        req.token_t.extend([t] * len(new))
+        if self._journal is not None and not self._journal_suppressed(req.id):
+            self._journal_tokens.setdefault(req.id, []).extend(new)
+
+    def _watch_prefill_recompiles(self) -> None:
+        """After ``warmup()`` every rung of the ladder has its program; one
+        more is a compile in steady state."""
+        warm = self._prefill_executables_warm
+        size = _cache_size(self._prefill) if warm is not None else None
+        if size is not None and size > warm:
+            self._stats["prefill_steady_recompiles"] += size - warm
+            self._prefill_executables_warm = size
+            if _log_ok():
+                logger.warning(
+                    "serving: prefill compiled mid-flight (%d extra "
+                    "executable(s)) — warmup() should have built every rung "
+                    "of the ladder; see docs/usage_guides/serving.md.",
+                    size - warm,
+                )
 
     def _prefill_dispatch(self, req: _Request, chunk, valid: int,
                           is_first: bool, is_final: bool):
@@ -1305,76 +1476,74 @@ class ServingEngine:
         tr = self.tracing
         k_spec = self._speculate_k
         for version, mask in self._decode_groups():
-            t0 = time.perf_counter() if (tr is not None
-                                         or k_spec > 0) else None
-            if tr is not None:
-                group_rids = [r.id for s, r in self._decoding.items()
-                              if r.weights_version == version and mask[s]]
-            self._cache, self._state, toks, emitted, bad = self._decode(
-                self._params_for(version), self._cache, self._state, mask
-            )
-            self._stats["decode_steps"] += 1
-            if self.telemetry is not None:
-                # PR-1 recompile-watchdog cross-check: sample the decode
-                # step's executable cache exactly like a train step's — any
-                # mid-flight growth lands as a "recompile" event in the
-                # telemetry JSONL.
-                try:
-                    self.telemetry._watch_recompiles(self._decode, toks)
-                except Exception:
-                    pass
+            with self._phase("serving.decode_dispatch"):
+                t0 = time.perf_counter() if (tr is not None
+                                             or k_spec > 0) else None
+                if tr is not None:
+                    group_rids = [r.id for s, r in self._decoding.items()
+                                  if r.weights_version == version and mask[s]]
+                self._cache, self._state, toks, emitted, bad = self._decode(
+                    self._params_for(version), self._cache, self._state, mask
+                )
+                self._stats["decode_steps"] += 1
+                if self.telemetry is not None:
+                    # PR-1 recompile-watchdog cross-check: sample the decode
+                    # step's executable cache exactly like a train step's —
+                    # any mid-flight growth lands as a "recompile" event in
+                    # the telemetry JSONL.
+                    try:
+                        self.telemetry._watch_recompiles(self._decode, toks)
+                    except Exception:
+                        pass
             # The per-tick host sync: fetch this round's tokens (a (N, k+1)
             # block under speculation) + per-slot emitted counts + done
             # flags + the nonfinite sentinel (one fused device_get — no
             # extra stall). Under a mixed-version tick this runs once per
-            # group, reading only the rows that group's mask advanced. The
-            # profiler times THIS existing sync (it never adds one): the
-            # fetch wall is the tick's host_fetch_s attribution term.
-            if self._profiler is not None:
-                tf0 = time.perf_counter()
-            toks_np, emitted_np, done_np, bad_np = jax.device_get(
-                (toks, emitted, self._state.done, bad))
-            if self._profiler is not None:
-                self._tick_fetch_s += time.perf_counter() - tf0
-            if flip_slot is not None and mask[flip_slot]:
-                toks_np = np.array(toks_np)
-                toks_np[flip_slot, 0] ^= 1
-                flip_slot = None  # one flip per tick, not per version group
-            group_drafted = group_accepted = 0
-            for slot, req in list(self._decoding.items()):
-                if req.weights_version != version or not mask[slot]:
-                    continue
-                if bool(bad_np[slot]):
-                    self._on_poisoned_slot(slot, req)
-                    continue
-                cnt = int(emitted_np[slot])
-                for t in toks_np[slot, :cnt]:
-                    req.out.append(int(t))
-                    if (self._journal is not None
-                            and not self._journal_suppressed(req.id)):
-                        self._journal_tokens.setdefault(req.id, []).append(
-                            req.out[-1])
+            # group, reading only the rows that group's mask advanced.
+            with self._phase("serving.decode_fetch"):
+                toks_np, emitted_np, done_np, bad_np = jax.device_get(
+                    (toks, emitted, self._state.done, bad))
+            with self._phase("serving.bookkeeping"):
+                t_fetch = time.perf_counter()  # this fetch's tokens' stamp
+                if flip_slot is not None and mask[flip_slot]:
+                    toks_np = np.array(toks_np)
+                    toks_np[flip_slot, 0] ^= 1
+                    flip_slot = None  # one flip per tick, not per version group
+                group_drafted = group_accepted = 0
+                for slot, req in list(self._decoding.items()):
+                    if req.weights_version != version or not mask[slot]:
+                        continue
+                    if bool(bad_np[slot]):
+                        self._on_poisoned_slot(slot, req)
+                        continue
+                    cnt = int(emitted_np[slot])
+                    self._emit(req, toks_np[slot, :cnt], t_fetch)
+                    if k_spec > 0:
+                        req.spec_drafted += k_spec
+                        req.spec_accepted += max(cnt - 1, 0)
+                        group_drafted += k_spec
+                        group_accepted += max(cnt - 1, 0)
+                        self._stats["spec_decode_tokens"] += cnt
+                    if bool(done_np[slot]):
+                        del self._decoding[slot]
+                        self._retire(req)
                 if k_spec > 0:
-                    req.spec_drafted += k_spec
-                    req.spec_accepted += max(cnt - 1, 0)
-                    group_drafted += k_spec
-                    group_accepted += max(cnt - 1, 0)
-                    self._stats["spec_decode_tokens"] += cnt
-                if bool(done_np[slot]):
-                    del self._decoding[slot]
-                    self._retire(req)
-            if k_spec > 0:
-                self._stats["spec_drafted"] += group_drafted
-                self._stats["spec_accepted"] += group_accepted
-                # Per-tick verify-time attribution: the whole speculative
-                # dispatch IS the k+1-position verification forward.
-                self._stats["spec_verify_s"] += time.perf_counter() - t0
-            if tr is not None:
-                tr.decode_tick(self._stats["ticks"], t0, time.perf_counter(),
-                               weights_version=version, occupancy=live,
-                               n_slots=self.n_slots, request_ids=group_rids,
-                               drafted=group_drafted,
-                               accepted=group_accepted)
+                    self._stats["spec_drafted"] += group_drafted
+                    self._stats["spec_accepted"] += group_accepted
+                    # Per-tick verify-time attribution: the whole speculative
+                    # dispatch IS the k+1-position verification forward.
+                    self._stats["spec_verify_s"] += time.perf_counter() - t0
+                if tr is not None:
+                    tr.decode_tick(self._stats["ticks"], t0,
+                                   time.perf_counter(),
+                                   weights_version=version, occupancy=live,
+                                   n_slots=self.n_slots,
+                                   request_ids=group_rids,
+                                   drafted=group_drafted,
+                                   accepted=group_accepted)
+                self._watch_decode_recompiles()
+
+    def _watch_decode_recompiles(self) -> None:
         size = _cache_size(self._decode)
         if size is not None:
             if self._decode_executables_baseline is None:
@@ -1383,11 +1552,13 @@ class ServingEngine:
                 extra = size - self._decode_executables_baseline
                 self._stats["steady_recompiles"] += extra
                 self._decode_executables_baseline = size
-                logger.warning(
-                    "serving: decode step recompiled mid-flight (%d extra "
-                    "executable(s)) — the steady state should be exactly one "
-                    "program; see docs/usage_guides/serving.md.", extra,
-                )
+                if _log_ok():
+                    logger.warning(
+                        "serving: decode step recompiled mid-flight (%d "
+                        "extra executable(s)) — the steady state should be "
+                        "exactly one program; see "
+                        "docs/usage_guides/serving.md.", extra,
+                    )
 
     def _retire(self, req: _Request) -> None:
         """Natural completion: the device row already flagged itself done, so
@@ -1414,9 +1585,6 @@ class ServingEngine:
         if status == "ok":
             self._ttfts.append(ttft)
             self._tpots.append(tpot)
-            if req.admit_t is not None:
-                self._queue_waits.append(req.admit_t - req.submit_t)
-                self._prefill_lats.append(req.first_token_t - req.admit_t)
             # Throughput/latency aggregates stay ok-only, so a shed storm
             # can't flatter (or taint) the SLO numbers.
             self._stats["completed"] += 1
@@ -1445,6 +1613,7 @@ class ServingEngine:
             "weights_version": req.weights_version,
             "attempt": attempt, "recovered": req.recoveries > 0,
             "drafted": req.spec_drafted, "accepted": req.spec_accepted,
+            **timing_row_keys(req),
         }
         self._finished.append(result)
         if req.client_request_id is not None:
@@ -1776,6 +1945,7 @@ class ServingEngine:
                     "recovered": True,
                     "drafted": int(trec.get("drafted", 0)),
                     "accepted": int(trec.get("accepted", 0)),
+                    **timing_row_keys(),
                 }
                 self._finished.append(result)
                 self._cached_rows[rid] = result
@@ -2198,6 +2368,7 @@ class ServingEngine:
             self.run([prompt], max_new_tokens=2)
         finally:
             self._journal = jr
+        self._prefill_executables_warm = _cache_size(self._prefill)
         self.reset_metrics()
 
     def reset_metrics(self) -> None:
@@ -2214,8 +2385,8 @@ class ServingEngine:
         self._last_done_t = None
         self._ttfts.clear()
         self._tpots.clear()
-        self._queue_waits.clear()
-        self._prefill_lats.clear()
+        self._ttft_terms.clear()
+        self._token_gaps.clear()
         self._window.clear()
         self._queue_depth_window.clear()
         self._finished.clear()
@@ -2247,8 +2418,9 @@ class ServingEngine:
         }
 
     def stats(self) -> dict:
-        """The serving telemetry block: TTFT/TPOT percentiles, queue depth,
-        slot occupancy, aggregate tokens/s, executable census."""
+        """The serving telemetry block: TTFT/TPOT percentiles, TTFT's terms,
+        the gap between tokens, the tick's phases, queue depth, slot
+        occupancy, aggregate tokens/s, executable census."""
         s = dict(self._stats)
         execs = self.executable_counts()
         elapsed = None
@@ -2256,6 +2428,7 @@ class ServingEngine:
             elapsed = (self._last_done_t or time.perf_counter()) - self._first_submit_t
         ttft = np.asarray(self._ttfts, np.float64)
         tpot = np.asarray(self._tpots, np.float64)
+        terms = self._ttft_terms_array()
         out = {
             "requests_submitted": s["submitted"],
             "requests_completed": s["completed"],
@@ -2269,13 +2442,17 @@ class ServingEngine:
             "ttft_p95_s": float(np.percentile(ttft, 95)) if ttft.size else None,
             # TTFT attribution: queued-for-a-slot vs prefilling-once-granted
             # means — congestion vs compute (the disagg router exists to
-            # shrink the first term without starving the second).
+            # shrink the first term without starving the second). Their
+            # tails, and the second term split in two, are in "ttft_terms".
             "ttft_queue_wait_mean_s": (
-                float(np.mean(self._queue_waits)) if self._queue_waits else None
+                float(terms[:, 0].mean()) if len(terms) else None
             ),
             "ttft_prefill_mean_s": (
-                float(np.mean(self._prefill_lats)) if self._prefill_lats else None
+                float(terms[:, 1:].sum(axis=1).mean()) if len(terms) else None
             ),
+            "ttft_terms": self.ttft_term_stats(),
+            "token_gap": self.token_gap_stats(),
+            "tick_phases": self.tick_phase_stats(),
             "tpot_mean_s": float(tpot.mean()) if tpot.size else None,
             "ticks": s["ticks"],
             "decode_steps": s["decode_steps"],
@@ -2295,6 +2472,7 @@ class ServingEngine:
             "slot_allocs": s["slot_allocs"],
             "slot_reuses": s["slot_reuses"],
             "steady_recompiles": s["steady_recompiles"],
+            "prefill_steady_recompiles": s["prefill_steady_recompiles"],
             "decode_executables": execs["decode"],
             "prefill_executables": execs["prefill"],
             "weights_version": self._weights_version,
@@ -2306,6 +2484,50 @@ class ServingEngine:
             "speculation": self.speculation_stats(),
         }
         return out
+
+    def _ttft_terms_array(self) -> np.ndarray:
+        """(n, 3): a row of :data:`TTFT_TERMS` per first token."""
+        return np.asarray(self._ttft_terms, np.float64).reshape(-1, 3)
+
+    def ttft_term_stats(self) -> dict:
+        """The ``ttft_terms`` block: median and 95th percentile of each of
+        :data:`TTFT_TERMS` over the ``n`` requests that reached their first
+        token since the last ``reset_metrics()``."""
+        terms = self._ttft_terms_array()
+        out = {"n": len(terms)}
+        for i, name in enumerate(TTFT_TERMS):
+            for q in (50, 95):
+                out[f"{name[:-2]}_p{q}_s"] = (
+                    float(np.percentile(terms[:, i], q)) if len(terms) else None)
+        return out
+
+    def token_gap_stats(self) -> dict:
+        """The ``token_gap`` block: the wait before a token since the same
+        request's previous one, over the newest ``n`` tokens fetched (the
+        first of a request has none; tokens of one fetch after the first
+        wait 0)."""
+        gaps = np.asarray(self._token_gaps, np.float64)
+        return {
+            "n": int(gaps.size),
+            "p50_s": float(np.percentile(gaps, 50)) if gaps.size else None,
+            "p95_s": float(np.percentile(gaps, 95)) if gaps.size else None,
+            "max_s": float(gaps.max()) if gaps.size else None,
+        }
+
+    def tick_phase_stats(self) -> dict:
+        """The ``tick_phases`` block: seconds in each of :data:`TICK_PHASES`
+        (they add up to ``wall_s``, the seconds inside ``tick()``), of them
+        ``device_wait_s`` blocked in the two fetches and ``host_s`` the rest."""
+        s = self._stats
+        phases = {name: float(s[name]) for name in TICK_PHASES}
+        wait = sum(phases[name] for name in _DEVICE_WAIT_PHASES)
+        return {
+            "ticks": s["ticks"],
+            "wall_s": float(s["tick_wall_s"]),
+            "phases_s": phases,
+            "device_wait_s": wait,
+            "host_s": float(s["tick_wall_s"]) - wait,
+        }
 
     def speculation_stats(self) -> dict:
         """The ``speculation`` telemetry block: draft/accept counters and

@@ -225,7 +225,6 @@ def _serving_leg(acc, module, probe):
         "accelerate_tpu_slo_serving_availability_burn_rate",
         "accelerate_tpu_serving_ticks",
         "accelerate_tpu_tracing_spans_total",
-        "accelerate_tpu_trace_spans_total",  # alias, one release
     ):
         assert required in names, (required, sorted(names)[:30])
     assert acc.telemetry.tracing.metrics_text() == hub.render(), (

@@ -34,6 +34,16 @@ deterministic fields, so a seeded chaos run replays a *bit-identical*
 tick-domain trace — the same invariant ``chaos.py`` guarantees for its
 fault log. Wall clocks feed only ``explain()`` and the Chrome export.
 
+Parents
+-------
+A span's ``parent`` is the ``seq`` of the span that caused it. Each engine
+tick is one ``serving.tick`` span; the tick's phases (``serving.admit`` ...
+``serving.end_tick``, see ``ServingEngine._phase``) and every engine-level
+span recorded during the tick are its children. A request's spans
+(prefill chunks, handoffs, retries, finish) are children of that request's
+``queued`` span, whatever tick they ran in; they already share its
+``request_id``.
+
 Like every subsystem here the recorder is off by default and hooks are
 zero-cost ``if tracing is not None`` checks; all tracing is host-side
 Python — no extra device fetches, so the ONE-decode-executable /
@@ -132,13 +142,14 @@ class Span:
     """
 
     __slots__ = (
-        "seq", "subsystem", "name", "kind", "tid", "request_id",
+        "seq", "parent", "subsystem", "name", "kind", "tid", "request_id",
         "start_tick", "end_tick", "t0", "t1", "attrs", "flow",
     )
 
     def __init__(self, seq, subsystem, name, kind, tid, request_id,
-                 start_tick, t0, attrs):
+                 start_tick, t0, attrs, parent=None):
         self.seq = seq
+        self.parent = parent  # seq of the span that caused this one, or None
         self.subsystem = subsystem
         self.name = name
         self.kind = kind
@@ -155,6 +166,7 @@ class Span:
         """Deterministic projection: no wall clocks, sorted attrs."""
         return {
             "seq": self.seq,
+            "parent": self.parent,
             "subsystem": self.subsystem,
             "name": self.name,
             "kind": self.kind,
@@ -183,7 +195,7 @@ class _ReqTrace:
         "queue_wait_s", "prefill_active_s", "handoff_s", "backoff_s",
         "decode_ticks", "retries", "prompt_tokens", "new_tokens",
         "weights_version", "canary", "lanes", "slot", "ttft_s",
-        "drafted", "accepted",
+        "drafted", "accepted", "root",
     )
 
     def __init__(self, rid, tick, t, prompt_tokens, deadline_s):
@@ -212,6 +224,7 @@ class _ReqTrace:
         self.ttft_s = None
         self.drafted = 0
         self.accepted = 0
+        self.root = None  # seq of the ``queued`` span: parent of the rest
 
 
 class TraceRecorder:
@@ -219,7 +232,8 @@ class TraceRecorder:
 
     Hooks are grouped by caller:
 
-    - serving.py: ``request_submitted`` / ``request_granted`` /
+    - serving.py: ``phase_begin`` / ``phase_end`` (the tick and its
+      phases), ``request_submitted`` / ``request_granted`` /
       ``prefill_chunk`` / ``first_token`` / ``decode_tick`` /
       ``request_retry`` / ``quarantine`` / ``request_finished``
     - disagg.py: ``handoff`` / ``handoff_retry`` / ``handoff_flush`` /
@@ -247,6 +261,8 @@ class TraceRecorder:
         # drains, canary windows).
         self._stack: List[Span] = []
         self._open: Dict[int, Span] = {}
+        # Open tick-phase spans, the tick itself at the bottom.
+        self._phases: List[Span] = []
         self._flow_seq = 0
         # Pending chaos annotation: a fault drawn with no open engine
         # span annotates the *next* span recorded for its unit (the retry
@@ -254,20 +270,15 @@ class TraceRecorder:
         self._pending_fault: Optional[Dict[str, Any]] = None
         self._chaos_seed: Optional[int] = None
         self._counts: Dict[str, int] = {}
-        # Prometheus exposition now lives on the unified MetricsHub
+        # Prometheus exposition lives on the unified MetricsHub
         # (profiler.py): one renderer, one naming scheme. The recorder
-        # registers its own stats as the "tracing" provider plus a legacy
-        # text block keeping the pre-hub accelerate_tpu_trace_* names as
-        # aliases for one release.
+        # registers its own stats as the "tracing" provider plus the
+        # per-kind span counters as a text block.
         from .profiler import MetricsHub
 
         self.hub = hub if hub is not None else MetricsHub()
         self.hub.register_provider("tracing", self.stats, replace=True)
         self.hub.register_text(self._span_metric_lines)
-        self.hub.alias("accelerate_tpu_trace_dropped_spans_total",
-                       "accelerate_tpu_tracing_dropped_spans")
-        self.hub.alias("accelerate_tpu_trace_requests",
-                       "accelerate_tpu_tracing_requests")
 
     # ------------------------------------------------------------------
     # span plumbing
@@ -277,6 +288,8 @@ class TraceRecorder:
 
     def _new_span(self, subsystem, name, kind, tick, *, tid=None,
                   request_id=None, t=None, attrs=None) -> Optional[Span]:
+        """Records one span. Its parent is the request's ``queued`` span
+        where it belongs to a request, else the tick that is open."""
         if len(self._spans) >= self.config.max_spans:
             self._dropped += 1
             if not self._warned_drop:
@@ -287,14 +300,20 @@ class TraceRecorder:
                     self.config.max_spans,
                 )
             return None
+        rt = self._requests.get(request_id) if request_id is not None else None
+        parent = rt.root if rt is not None else None
+        if parent is None and self._phases:
+            parent = self._phases[0].seq
         span = Span(self._seq, subsystem, name, kind, tid, request_id,
                     tick, t if t is not None else self._now(),
-                    attrs if attrs is not None else {})
+                    attrs if attrs is not None else {}, parent)
         self._seq += 1
         self._spans.append(span)
         self._counts[kind] = self._counts.get(kind, 0) + 1
         pending = self._pending_fault
-        if pending is not None and subsystem != "chaos" and (
+        # A tick's phases never take a pending chaos annotation: it belongs
+        # to the retry or decode-tick span the fault shows up as.
+        if pending is not None and subsystem != "chaos" and kind != "tick_phase" and (
             request_id is None or pending.get("unit") in (0, request_id)
         ):
             span.attrs.update(injected=True, point=pending["point"],
@@ -367,6 +386,29 @@ class TraceRecorder:
                        request_id=request_id, attrs=attrs)
 
     # ------------------------------------------------------------------
+    # the tick and its phases (serving.py ``ServingEngine._phase``)
+    # ------------------------------------------------------------------
+    def phase_begin(self, name: str, tick: int, t: Optional[float],
+                    attrs: Optional[Dict[str, Any]] = None) -> Optional[Span]:
+        """Open one phase of an engine tick at the engine's own clock read
+        ``t``. The first one opened is the tick (``serving.tick``); those
+        opened while it is open are its children."""
+        span = self._new_span("serving", name, "tick_phase", tick, tid="tick",
+                              t=t, attrs=attrs)
+        if span is not None:
+            self._phases.append(span)
+        return span
+
+    def phase_end(self, span: Optional[Span], tick: int,
+                  t: Optional[float]) -> None:
+        if span is None:
+            return
+        span.end_tick = tick
+        span.t1 = t
+        if span in self._phases:  # also drops phases an exception left open
+            del self._phases[self._phases.index(span):]
+
+    # ------------------------------------------------------------------
     # request lifecycle hooks (serving.py)
     # ------------------------------------------------------------------
     def request_submitted(self, rid: int, tick: int, t: Optional[float], *,
@@ -381,6 +423,7 @@ class TraceRecorder:
                                      "budget": budget})
         if span is not None:
             self._open_req[rid] = span
+            rt.root = span.seq
 
     def request_granted(self, rid: int, tick: int, t: Optional[float], *,
                         slot, lane, weights_version: int,
@@ -782,6 +825,8 @@ class TraceRecorder:
             if span.request_id is not None:
                 args["request_id"] = span.request_id
             args["tick"] = span.start_tick
+            if span.parent is not None:
+                args["parent"] = span.parent
             ev = {"ph": "X", "pid": pid, "tid": tid, "name": span.name,
                   "cat": span.subsystem, "ts": round(ts, 3),
                   "dur": round(max(dur, 1.0), 3), "args": args}
@@ -837,10 +882,8 @@ class TraceRecorder:
         return "".join(c if c.isalnum() or c == "_" else "_" for c in name)
 
     def _span_metric_lines(self) -> List[str]:
-        """Per-kind span counters for the hub renderer: the canonical
-        ``accelerate_tpu_tracing_spans_total{kind=...}`` series plus the
-        pre-hub ``accelerate_tpu_trace_spans_total`` spelling, kept as an
-        alias for one release (the hub's alias warning covers it)."""
+        """Per-kind span counters for the hub renderer:
+        ``accelerate_tpu_tracing_spans_total{kind=...}``."""
         lines = [
             "# HELP accelerate_tpu_tracing_spans_total spans recorded by kind",
             "# TYPE accelerate_tpu_tracing_spans_total counter",
@@ -848,10 +891,6 @@ class TraceRecorder:
         for kind in sorted(self._counts):
             lines.append(
                 f'accelerate_tpu_tracing_spans_total{{kind="{self._sanitize(kind)}"}} '
-                f"{self._counts[kind]}")
-        for kind in sorted(self._counts):
-            lines.append(
-                f'accelerate_tpu_trace_spans_total{{kind="{self._sanitize(kind)}"}} '
                 f"{self._counts[kind]}")
         return lines
 
@@ -905,6 +944,7 @@ class TraceRecorder:
         self._open_req.clear()
         self._stack.clear()
         self._open.clear()
+        self._phases.clear()
         self._flow_seq = 0
         self._pending_fault = None
         self._counts.clear()

@@ -465,7 +465,9 @@ def test_off_by_default_no_chaos_no_faults(llama):
         assert res[i]["status"] == "ok"
         assert set(res[i]) == {"id", "status", "tokens", "new_tokens",
                                "ttft_s", "tpot_s", "weights_version",
-                               "attempt", "recovered", "drafted", "accepted"}
+                               "attempt", "recovered", "drafted", "accepted",
+                               "queue_wait_s", "prefill_blocked_s",
+                               "prefill_own_s", "token_times_s"}
         assert res[i]["attempt"] == 1 and res[i]["recovered"] is False
     f = eng.stats()["faults"]
     assert f["injected"] == 0 and f["degraded"] is False

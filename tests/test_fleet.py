@@ -173,6 +173,7 @@ def test_shed_only_when_all_cells_breach(llama, tmp_path):
     assert set(row) == {
         "id", "status", "tokens", "new_tokens", "ttft_s", "tpot_s",
         "weights_version", "attempt", "recovered", "drafted", "accepted",
+        "queue_wait_s", "prefill_blocked_s", "prefill_own_s", "token_times_s",
         "cell", "spilled", "drained_from",
     }
     assert row["tokens"].shape == (len(prompts[4]) + 4,)

@@ -276,16 +276,6 @@ def test_hub_provider_walk_skips_non_numeric():
     assert names == {"accelerate_tpu_j_appends", "accelerate_tpu_j_nested_ok"}
 
 
-def test_hub_alias_duplicates_series():
-    hub = MetricsHub()
-    hub.register_provider("tracing", lambda: {"requests": 4})
-    hub.alias("accelerate_tpu_trace_requests",
-              "accelerate_tpu_tracing_requests")
-    text = hub.render()
-    assert "accelerate_tpu_tracing_requests 4" in text
-    assert "accelerate_tpu_trace_requests 4" in text
-
-
 def test_hub_slo_burn_rate():
     hub = MetricsHub()
     with pytest.raises(ValueError):

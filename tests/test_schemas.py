@@ -43,6 +43,8 @@ def _prompts(cfg, lengths, seed=3):
 POLL_ROW_KEYS = {
     "id", "status", "tokens", "new_tokens", "ttft_s", "tpot_s",
     "weights_version", "attempt", "recovered", "drafted", "accepted",
+    # serving.timing_row_keys: TTFT's three terms and each token's time
+    "queue_wait_s", "prefill_blocked_s", "prefill_own_s", "token_times_s",
 }
 
 SERVING_STATS_KEYS = {
@@ -50,10 +52,11 @@ SERVING_STATS_KEYS = {
     "prompt_tokens_in", "elapsed_s", "tokens_per_s",
     "ttft_p50_s", "ttft_p95_s", "ttft_queue_wait_mean_s",
     "ttft_prefill_mean_s", "tpot_mean_s",
+    "ttft_terms", "token_gap", "tick_phases",
     "ticks", "decode_steps", "prefill_chunks", "prefill_pad_tokens",
     "prefill_ladder", "n_slots", "mean_occupancy", "peak_occupancy",
     "mean_queue_depth", "slot_allocs", "slot_reuses", "steady_recompiles",
-    "decode_executables", "prefill_executables", "weights_version",
+    "prefill_steady_recompiles", "decode_executables", "prefill_executables", "weights_version",
     "canary", "window", "faults", "journal", "sdc", "speculation",
 }
 
@@ -161,8 +164,8 @@ PROFILE_SUMMARY_KEYS = {
 
 # Prometheus series a fresh profiled+traced telemetry recorder renders from
 # the ONE MetricsHub renderer — the pinned accelerate_tpu_<subsystem>_<name>
-# scheme plus the one-release legacy aliases. Activity (spans, steps, SLO
-# windows) only ADDS names; this is the floor that must never drift.
+# scheme. Activity (spans, steps, SLO windows) only ADDS names; this is the
+# floor that must never drift.
 HUB_BASE_METRIC_NAMES = {
     "accelerate_tpu_telemetry_steps",
     "accelerate_tpu_telemetry_recompiles",
@@ -179,9 +182,6 @@ HUB_BASE_METRIC_NAMES = {
     "accelerate_tpu_tracing_requests",
     "accelerate_tpu_tracing_open_spans",
     "accelerate_tpu_tracing_flows",
-    # deprecated aliases, kept one release (profiler.MetricsHub.alias)
-    "accelerate_tpu_trace_dropped_spans_total",
-    "accelerate_tpu_trace_requests",
 }
 
 
@@ -362,7 +362,7 @@ def test_summary_block_schema(tmp_path):
 def test_profile_block_schema_and_hub_metric_names(tmp_path):
     """TelemetryKwargs(profile=True): summary() grows the pinned profile
     block and the MetricsHub renders the pinned base name set (telemetry +
-    profile + tracing providers plus the one-release legacy aliases)."""
+    profile + tracing providers)."""
     from accelerate_tpu import Accelerator, DeviceTimeProfiler
     from accelerate_tpu.utils import TelemetryKwargs
 

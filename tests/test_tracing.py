@@ -247,8 +247,9 @@ def test_metrics_text_matches_stats(llama):
     # window_stats parity rides through the nested "window" block.
     assert float(lines["accelerate_tpu_serving_window_requests"]) == (
         stats["window"]["requests"])
-    assert "accelerate_tpu_trace_spans_total" in text
-    assert float(lines["accelerate_tpu_trace_requests"]) == 2
+    assert "accelerate_tpu_tracing_spans_total" in text
+    assert float(lines["accelerate_tpu_tracing_requests"]) == 2
+    assert "accelerate_tpu_trace_" not in text   # the pre-hub names are gone
 
 
 # ---------------------------------------------------------------------------
