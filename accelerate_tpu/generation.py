@@ -262,6 +262,11 @@ def _attend(q, k, v, q_positions, kv_valid=None):
     slots. The causal bound kv_pos <= q_position also excludes unwritten
     cache slots (every query position is < cache length after the write).
     ``kv_valid`` (B, T) additionally masks slots holding left-padding.
+    K and V are read as cached, Hkv heads wide: query head j reads KV head
+    j // G, G = Hq // Hkv, by folding each group into the query rows
+    (B,Hkv,G*Sq,D) — one batched matmul per KV head, no repeated copy of K
+    or V, so the bytes a step reads scale with B x T x Hkv, not Hq. G == 1
+    (MHA) and Hkv == 1 (MQA) are the same einsum with an axis of one.
     ``QuantPages`` k/v dequantize HERE — adjacent to the attention dots, the
     same fusion-adjacency trick as ``_kernel`` — so the cache rides HBM as
     int8 and XLA fuses convert×scale into the einsum."""
@@ -269,21 +274,20 @@ def _attend(q, k, v, q_positions, kv_valid=None):
         k = dequantize_kv_page(k, q.dtype)
     if isinstance(v, QuantPages):
         v = dequantize_kv_page(v, q.dtype)
-    hq, hkv = q.shape[2], k.shape[2]
-    if hq != hkv:
-        rep = hq // hkv
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
-    scale = 1.0 / np.sqrt(q.shape[-1])
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
-    t = k.shape[1]
+    b, sq, hq, d = q.shape
+    t, hkv = k.shape[1:3]
+    g = hq // hkv
+    q = q.reshape(b, sq, hkv, g, d).transpose(0, 2, 3, 1, 4).reshape(b, hkv, g * sq, d)
+    scale = 1.0 / np.sqrt(d)
+    logits = (jnp.einsum("bhmd,bkhd->bhmk", q, k) * scale).reshape(b, hkv, g, sq, t)
     kv_pos = jnp.arange(t, dtype=jnp.int32)[None, :]  # (1, T)
     causal = kv_pos[None, :, :] <= q_positions[:, :, None]  # (B, Sq, T)
     if kv_valid is not None:
         causal = causal & kv_valid[:, None, :].astype(bool)
-    logits = jnp.where(causal[:, None], logits, jnp.finfo(logits.dtype).min)
+    logits = jnp.where(causal[:, None, None], logits, jnp.finfo(logits.dtype).min)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    out = jnp.einsum("bhmk,bkhd->bhmd", probs.reshape(b, hkv, g * sq, t), v)
+    return out.reshape(b, hkv, g, sq, d).transpose(0, 3, 1, 2, 4).reshape(b, sq, hq, d)
 
 
 def _llama_forward_cached(cfg, params, input_ids, cache: KVCache, return_all=False,

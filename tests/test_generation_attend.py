@@ -1,0 +1,127 @@
+"""``generation._attend`` scores grouped query heads against K and V as the
+cache holds them. First against a reference written here with the explicit
+``jnp.repeat`` it replaced, which pins the head mapping j -> j // G; then in
+the serving cell's programs, compiled for a described v5e chip: no array of
+the repeated shape is left, and no slice-sized copy stands in its place.
+
+The topology is described in a fixture (never while a module is imported);
+``tests/chipbench/test_chipbench_aot.py`` is the other file that does so."""
+
+import math
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from accelerate_tpu.generation import _attend, dequantize_kv_page, quantize_kv_page
+
+
+def _attend_with_repeat(q, k, v, q_positions, kv_valid=None):
+    rep = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(q.shape[-1])
+    seen = jnp.arange(k.shape[1])[None, None, :] <= q_positions[:, :, None]
+    if kv_valid is not None:
+        seen = seen & kv_valid[:, None, :]
+    logits = jnp.where(seen[:, None], logits, jnp.finfo(logits.dtype).min)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(logits, axis=-1), v)
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["all_rows", "kv_valid"])
+@pytest.mark.parametrize("quantized", [False, True], ids=["float", "quant_pages"])
+@pytest.mark.parametrize("sq", [1, 5])
+@pytest.mark.parametrize("hq,hkv", [(8, 8), (8, 2), (8, 1)], ids=["mha", "gqa", "mqa"])
+def test_attend_equals_the_explicit_repeat(hq, hkv, sq, quantized, masked):
+    b, t, d = 3, 16, 8
+    kq, kk, kv = jax.random.split(jax.random.key(hq * 100 + hkv * 10 + sq), 3)
+    q = jax.random.normal(kq, (b, sq, hq, d), jnp.float32)
+    k = jax.random.normal(kk, (b, t, hkv, d), jnp.float32)
+    v = jax.random.normal(kv, (b, t, hkv, d), jnp.float32)
+    # each row at its own offset, as the slot cache has them
+    q_positions = jnp.array([3, 7, 10])[:, None] + jnp.arange(sq)[None, :]
+    kv_valid = (jnp.arange(t)[None, :] >= jnp.array([0, 2, 5])[:, None]) if masked else None
+    cached_k, cached_v = (quantize_kv_page(k), quantize_kv_page(v)) if quantized else (k, v)
+    if quantized:
+        k, v = dequantize_kv_page(cached_k, q.dtype), dequantize_kv_page(cached_v, q.dtype)
+
+    out = _attend(q, cached_k, cached_v, q_positions, kv_valid)
+
+    assert out.shape == (b, sq, hq, d) and out.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(_attend_with_repeat(q, k, v, q_positions, kv_valid)),
+                               rtol=2e-5, atol=2e-5)
+
+
+# -- the compiled programs of mistral_serve_steady ---------------------------------
+
+
+@pytest.fixture(scope="module")
+def steady_programs():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from chipbench import aot, spec
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # such a compile is written to the persistent cache and cannot be read back
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    cell = spec.load_cell("mistral_serve_steady")
+    yield cell, aot.serving_programs(cell, topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%\S+ = \w+\[([0-9,]*)\]\S* ([\w\-]+)\(")
+
+
+def _arrays(hlo_text, scheduled_only=False):
+    """(elements, opcode) of every array-valued instruction; with
+    ``scheduled_only`` those a fusion holds inside itself are left out, since
+    they never reach memory as a buffer of their own."""
+    inside_fusion = False
+    for line in hlo_text.splitlines():
+        if line.startswith(("%", "ENTRY")):
+            inside_fusion = line.startswith("%fused_computation")
+        m = _INSTRUCTION.match(line)
+        if m and not (scheduled_only and inside_fusion):
+            yield math.prod(int(n) for n in m.group(1).split(",") if n), m.group(2)
+
+
+@pytest.mark.parametrize("case", ["decode_no_repeated_array", "prefill_no_repeated_array",
+                                  "decode_no_slice_sized_copy", "decode_temporaries"])
+def test_the_steady_cell_compiles_without_the_gqa_repeat(steady_programs, case):
+    from chipbench import aot
+
+    cell, programs = steady_programs
+    cfg, eng = cell.config, cell.workload["engine"]
+    program = programs[case.split("_")[0]]
+    heads, kv_heads, d = cfg["num_attention_heads"], cfg["num_key_value_heads"], cfg["head_dim"]
+    rows = (eng["n_slots"] if program is programs["decode"] else 1) * eng["max_len"]
+    kv_slice, repeated = rows * kv_heads * d, rows * heads * d
+    if case == "decode_no_repeated_array":
+        # K or V copied out once for every query head, in whatever shape
+        assert repeated not in {n for n, _ in _arrays(program.as_text())}
+    elif case == "prefill_no_repeated_array":
+        # one slot's rows: the logits over the vocabulary and a four-layer slice
+        # of the cache have as many elements, so here only what a broadcast writes
+        assert repeated not in {n for n, op in _arrays(program.as_text()) if op == "broadcast"}
+    elif case == "decode_no_slice_sized_copy":
+        moved = [op for n, op in _arrays(program.as_text(), scheduled_only=True)
+                 if n == kv_slice and op in ("copy", "transpose")]
+        assert not moved
+    else:
+        # Beside the donated cache the step still holds one copy of it (the
+        # whole-cache copies around the per-slot write, ROADMAP S8) and two
+        # layer slices: less than one repeated buffer more, where there were three.
+        cache = 2 * 2 * cfg["num_hidden_layers"] * kv_slice
+        assert aot.memory_of(program)["temporaries"] < cache + 2 * repeated
