@@ -44,6 +44,11 @@ from .utils.quantization import DecodeQuant, dequantize_decode_kernel
 
 
 class KVCache(NamedTuple):
+    """Every layer's K and V in one buffer each. A cached forward carries both
+    whole through its layer loop and writes only the new rows, in place
+    (``_cache_step``); a jitted caller that donates the cache gets it back as
+    the same buffers."""
+
     k: jax.Array  # (L, B, T_max, Hkv, D)
     v: jax.Array  # (L, B, T_max, Hkv, D)
     # () int32 — tokens written so far (batch-global), or (B,) int32 for a
@@ -150,23 +155,38 @@ def _row_positions(start, b: int, s: int) -> jax.Array:
 
 
 @jax.named_scope("cache_write")
-def _cache_write(ck, k_new, start):
-    """Write ``k_new`` (B, S, Hkv, D) into the cache slice ``ck``
-    (B, T, Hkv, D) at row offset ``start`` — a scalar (one contiguous
-    ``dynamic_update_slice``) or per-row vector (scatter at each row's own
-    offset, the slot-paged path). A ``QuantPages`` cache quantizes the new
-    pages here, writing data and scale leaves at the same offsets."""
-    if isinstance(ck, QuantPages):
-        q = quantize_kv_page(k_new)
-        return QuantPages(_cache_write(ck.data, q.data, start),
-                          _cache_write(ck.scale, q.scale, start))
-    k_new = k_new.astype(ck.dtype)
+def _cache_write(buf, new, layer, start):
+    """Write ``new`` (B, S, Hkv, D) into the whole cache buffer ``buf``
+    (L, B, T, Hkv, D), in place, at layer ``layer`` and row offset ``start``:
+    a scalar (one ``dynamic_update_slice`` at ``(layer, 0, start, 0, 0)``) or
+    a per-row vector (a scatter at ``[layer, row, start[row] + s]``, the
+    slot-paged path) — ``start.ndim`` decides, at trace time. Only the new
+    rows move: ``buf`` rides the layer loop's carry, so the result aliases
+    it. A ``QuantPages`` cache quantizes the new pages here, writing data
+    and scale leaves at the same offsets."""
+    if isinstance(buf, QuantPages):
+        q = quantize_kv_page(new)
+        return QuantPages(_cache_write(buf.data, q.data, layer, start),
+                          _cache_write(buf.scale, q.scale, layer, start))
+    new = new.astype(buf.dtype)
     if getattr(start, "ndim", 0) == 1:
-        b, s = k_new.shape[:2]
+        b, s = new.shape[:2]
         rows = jnp.arange(b, dtype=jnp.int32)[:, None]
         cols = start[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
-        return ck.at[rows, cols].set(k_new)
-    return jax.lax.dynamic_update_slice(ck, k_new, (0, start, 0, 0))
+        return buf.at[layer, rows, cols].set(new)
+    return jax.lax.dynamic_update_slice(buf, new[None], (layer, 0, start, 0, 0))
+
+
+def _cache_step(ck, cv, k_new, v_new, layer, start):
+    """One layer's turn at the cache, the idiom every cached forward shares:
+    write the new K and V rows into the whole buffers (:func:`_cache_write`),
+    then read that layer's (B, T, Hkv, D) slices back for attention. Returns
+    ``(ck, cv, k_layer, v_layer)``. The buffers are the scan's carry and
+    nothing mutates the slices, so no step copies the cache or stacks a
+    layer's slice into a second one."""
+    ck, cv = _cache_write(ck, k_new, layer, start), _cache_write(cv, v_new, layer, start)
+    k_i, v_i = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, False), (ck, cv))
+    return ck, cv, k_i, v_i
 
 
 # ---------------------------------------------------------------------------
@@ -323,25 +343,25 @@ def _llama_forward_cached(cfg, params, input_ids, cache: KVCache, return_all=Fal
     res_mult = getattr(cfg, "residual_multiplier", 1.0)
 
     def one_layer(carry, layer):
-        h = carry
-        p, ck, cv = layer  # layer params, (B,T,Hkv,D) cache slices
+        h, ck, cv = carry  # hidden state, the whole (L,B,T,Hkv,D) cache
+        p, i = layer  # layer params, layer index
         attn = p["self_attn"]
         hn = _chassis_norm(cfg, p["input_layernorm"], h)
         q, k_new, v_new = _qkv_proj(attn, hn, cos, sin, rotary_dim=rd)
         if attn_mult is not None:  # same q-folding trick as LlamaAttention
             q = q * jnp.asarray(attn_mult * np.sqrt(cfg.head_dim), q.dtype)
-        ck = _cache_write(ck, k_new, start)
-        cv = _cache_write(cv, v_new, start)
-        out = _attend(q, ck, cv, positions, kv_valid)
+        ck, cv, k_i, v_i = _cache_step(ck, cv, k_new, v_new, i, start)
+        out = _attend(q, k_i, v_i, positions, kv_valid)
         out = _out_proj(out, attn["o_proj"]["kernel"])
         if "bias" in attn["o_proj"]:
             out = out + attn["o_proj"]["bias"].astype(out.dtype)
         h = h + scale_residual(out, res_mult)
         hn = _chassis_norm(cfg, p["post_attention_layernorm"], h)
         h = h + scale_residual(_mlp(cfg, p["mlp"], hn), res_mult)
-        return h, (ck, cv)
+        return (h, ck, cv), None
 
-    x, (new_k, new_v) = jax.lax.scan(one_layer, x, (stacked, cache.k, cache.v))
+    layers = jnp.arange(cache.k.shape[0], dtype=jnp.int32)
+    (x, new_k, new_v), _ = jax.lax.scan(one_layer, (x, cache.k, cache.v), (stacked, layers))
     x = _chassis_norm(cfg, model_p["norm"], x)
     h_out = x if return_all else x[:, -1]
     with jax.named_scope("lm_head"):
@@ -425,16 +445,15 @@ def _gpt2_forward_cached(cfg, params, input_ids, cache: KVCache, return_all=Fals
     x = x + jnp.take(tr["wpe"]["embedding"], pos_ids, axis=0).astype(cfg.dtype)
 
     def one_layer(carry, layer):
-        h = carry
-        p, ck, cv = layer
+        h, ck, cv = carry
+        p, i = layer
         hn = _layer_norm(h, p["ln_1"], cfg.layer_norm_epsilon)
         qkv = jnp.einsum(
             "bsh,hcnd->bscnd", hn, p["attn"]["c_attn"]["kernel"].astype(hn.dtype)
         ) + p["attn"]["c_attn"]["bias"].astype(hn.dtype)
         q, k_new, v_new = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        ck = _cache_write(ck, k_new, start)
-        cv = _cache_write(cv, v_new, start)
-        out = _attend(q, ck, cv, positions_b, kv_valid)
+        ck, cv, k_i, v_i = _cache_step(ck, cv, k_new, v_new, i, start)
+        out = _attend(q, k_i, v_i, positions_b, kv_valid)
         h = h + (
             jnp.einsum("bsnd,ndh->bsh", out, p["attn"]["c_proj"]["kernel"].astype(out.dtype))
             + p["attn"]["c_proj"]["bias"].astype(out.dtype)
@@ -444,9 +463,10 @@ def _gpt2_forward_cached(cfg, params, input_ids, cache: KVCache, return_all=Fals
             hn @ p["c_fc"]["kernel"].astype(hn.dtype) + p["c_fc"]["bias"].astype(hn.dtype)
         )
         h = h + mid @ p["c_proj"]["kernel"].astype(mid.dtype) + p["c_proj"]["bias"].astype(mid.dtype)
-        return h, (ck, cv)
+        return (h, ck, cv), None
 
-    x, (new_k, new_v) = jax.lax.scan(one_layer, x, (stacked, cache.k, cache.v))
+    layers = jnp.arange(cache.k.shape[0], dtype=jnp.int32)
+    (x, new_k, new_v), _ = jax.lax.scan(one_layer, (x, cache.k, cache.v), (stacked, layers))
     x = _layer_norm(x, tr["ln_f"], cfg.layer_norm_epsilon)
     logits = (x if return_all else x[:, -1]) @ wte.T.astype(cfg.dtype)
     return logits.astype(jnp.float32), KVCache(new_k, new_v, start + s)
@@ -476,25 +496,25 @@ def _opt_forward_cached(cfg, params, input_ids, cache: KVCache, return_all=False
     ).astype(cfg.dtype)
 
     def one_layer(carry, layer):
-        h = carry
-        p, ck, cv = layer
+        h, ck, cv = carry
+        p, i = layer
         attn = p["self_attn"]
         hn = _layer_norm(h, p["self_attn_layer_norm"], cfg.layer_norm_eps)
         q = _proj(hn, attn["q_proj"]["kernel"]) + attn["q_proj"]["bias"].astype(hn.dtype)
         k_new = _proj(hn, attn["k_proj"]["kernel"]) + attn["k_proj"]["bias"].astype(hn.dtype)
         v_new = _proj(hn, attn["v_proj"]["kernel"]) + attn["v_proj"]["bias"].astype(hn.dtype)
-        ck = _cache_write(ck, k_new, start)
-        cv = _cache_write(cv, v_new, start)
-        out = _attend(q, ck, cv, positions_b, kv_valid)
+        ck, cv, k_i, v_i = _cache_step(ck, cv, k_new, v_new, i, start)
+        out = _attend(q, k_i, v_i, positions_b, kv_valid)
         h = h + _out_proj(out, attn["out_proj"]["kernel"]) + attn["out_proj"]["bias"].astype(h.dtype)
         hn = _layer_norm(h, p["final_layer_norm"], cfg.layer_norm_eps)
         mid = jax.nn.relu(
             hn @ p["fc1"]["kernel"].astype(hn.dtype) + p["fc1"]["bias"].astype(hn.dtype)
         )
         h = h + mid @ p["fc2"]["kernel"].astype(mid.dtype) + p["fc2"]["bias"].astype(mid.dtype)
-        return h, (ck, cv)
+        return (h, ck, cv), None
 
-    x, (new_k, new_v) = jax.lax.scan(one_layer, x, (stacked, cache.k, cache.v))
+    layers = jnp.arange(cache.k.shape[0], dtype=jnp.int32)
+    (x, new_k, new_v), _ = jax.lax.scan(one_layer, (x, cache.k, cache.v), (stacked, layers))
     x = _layer_norm(x, model_p["final_layer_norm"], cfg.layer_norm_eps)
     logits = (x if return_all else x[:, -1]) @ embed.T.astype(cfg.dtype)
     return logits.astype(jnp.float32), KVCache(new_k, new_v, start + s)
@@ -522,8 +542,8 @@ def _neox_forward_cached(cfg, params, input_ids, cache: KVCache, return_all=Fals
     cos, sin = rotary_embedding(rope_positions, rnd, cfg.rotary_emb_base, x.dtype)
 
     def one_layer(carry, layer):
-        h = carry
-        p, ck, cv = layer
+        h, ck, cv = carry
+        p, i = layer
         attn = p["attention"]
         hn = _layer_norm(h, p["input_layernorm"], cfg.layer_norm_eps)
         qkv = jnp.einsum(
@@ -532,9 +552,8 @@ def _neox_forward_cached(cfg, params, input_ids, cache: KVCache, return_all=Fals
         q, k_new, v_new = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
         q = jnp.concatenate([apply_rope(q[..., :rnd], cos, sin), q[..., rnd:]], -1)
         k_new = jnp.concatenate([apply_rope(k_new[..., :rnd], cos, sin), k_new[..., rnd:]], -1)
-        ck = _cache_write(ck, k_new, start)
-        cv = _cache_write(cv, v_new, start)
-        out = _attend(q, ck, cv, positions_b, kv_valid)
+        ck, cv, k_i, v_i = _cache_step(ck, cv, k_new, v_new, i, start)
+        out = _attend(q, k_i, v_i, positions_b, kv_valid)
         attn_out = (
             jnp.einsum("bsnd,ndh->bsh", out, attn["dense"]["kernel"].astype(out.dtype))
             + attn["dense"]["bias"].astype(out.dtype)
@@ -558,9 +577,10 @@ def _neox_forward_cached(cfg, params, input_ids, cache: KVCache, return_all=Fals
         else:
             h = h + attn_out
             h = h + mlp(h)
-        return h, (ck, cv)
+        return (h, ck, cv), None
 
-    x, (new_k, new_v) = jax.lax.scan(one_layer, x, (stacked, cache.k, cache.v))
+    layers = jnp.arange(cache.k.shape[0], dtype=jnp.int32)
+    (x, new_k, new_v), _ = jax.lax.scan(one_layer, (x, cache.k, cache.v), (stacked, layers))
     x = _layer_norm(x, gp["final_layer_norm"], cfg.layer_norm_eps)
     logits = (x if return_all else x[:, -1]) @ params["embed_out"]["kernel"].astype(cfg.dtype)
     return logits.astype(jnp.float32), KVCache(new_k, new_v, start + s)
@@ -611,20 +631,20 @@ def _mixtral_forward_cached(cfg, params, input_ids, cache: KVCache, return_all=F
         return out.reshape(b, s, -1)
 
     def one_layer(carry, layer):
-        h = carry
-        p, ck, cv = layer
+        h, ck, cv = carry
+        p, i = layer
         attn = p["self_attn"]
         hn = rms_norm(h, p["input_layernorm"]["weight"].astype(h.dtype), cfg.rms_norm_eps)
         q, k_new, v_new = _qkv_proj(attn, hn, cos, sin)
-        ck = _cache_write(ck, k_new, start)
-        cv = _cache_write(cv, v_new, start)
-        out = _attend(q, ck, cv, positions, kv_valid)
+        ck, cv, k_i, v_i = _cache_step(ck, cv, k_new, v_new, i, start)
+        out = _attend(q, k_i, v_i, positions, kv_valid)
         h = h + _out_proj(out, attn["o_proj"]["kernel"])
         hn = rms_norm(h, p["post_attention_layernorm"]["weight"].astype(h.dtype), cfg.rms_norm_eps)
         h = h + moe(p["moe"], hn)
-        return h, (ck, cv)
+        return (h, ck, cv), None
 
-    x, (new_k, new_v) = jax.lax.scan(one_layer, x, (stacked, cache.k, cache.v))
+    layers = jnp.arange(cache.k.shape[0], dtype=jnp.int32)
+    (x, new_k, new_v), _ = jax.lax.scan(one_layer, (x, cache.k, cache.v), (stacked, layers))
     x = rms_norm(x, model_p["norm"]["weight"].astype(x.dtype), cfg.rms_norm_eps)
     with jax.named_scope("lm_head"):
         logits = (x if return_all else x[:, -1]) @ params["lm_head"]["kernel"].astype(cfg.dtype)
@@ -738,15 +758,14 @@ def _t5_decode(cfg, params, input_ids, cache: KVCache, enc: EncDecState, return_
         probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
         return jnp.einsum("bhqk,bkhd->bqhd", probs, cv)
 
-    def block(h, p, ck, cv, xk, xv):
+    def block(h, ck, cv, p, i, xk, xv):
         a = p["self_attn"]
         hn = _t5_rms(h, p["ln0"]["weight"].astype(h.dtype), eps)
         q = _proj(hn, a["q"]["kernel"])
         k_new = _proj(hn, a["k"]["kernel"])
         v_new = _proj(hn, a["v"]["kernel"])
-        ck = jax.lax.dynamic_update_slice(ck, k_new.astype(ck.dtype), (0, start, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cv, v_new.astype(cv.dtype), (0, start, 0, 0))
-        out = self_attend(q, ck, cv)
+        ck, cv, k_i, v_i = _cache_step(ck, cv, k_new, v_new, i, start)
+        out = self_attend(q, k_i, v_i)
         h = h + _out_proj(out, a["o"]["kernel"])
 
         c = p["cross_attn"]
@@ -759,24 +778,16 @@ def _t5_decode(cfg, params, input_ids, cache: KVCache, enc: EncDecState, return_
         mid = jax.nn.relu(hn @ p["ffn"]["wi"]["kernel"].astype(hn.dtype))
         return h + mid @ p["ffn"]["wo"]["kernel"].astype(mid.dtype), ck, cv
 
-    # block_0 owns cache slot 0; the scan covers slots 1..L-1.
-    y, ck0, cv0 = block(
-        y, dec["block_0"], cache.k[0], cache.v[0], enc.cross_k[0], enc.cross_v[0]
-    )
+    # block_0 owns cache layer 0; the scan covers layers 1..L-1.
+    carry = block(y, cache.k, cache.v, dec["block_0"], 0, enc.cross_k[0], enc.cross_v[0])
 
     def one_layer(carry, layer):
-        h = carry
-        p, ck, cv, xk, xv = layer
-        h, ck, cv = block(h, p, ck, cv, xk, xv)
-        return h, (ck, cv)
+        return block(*carry, *layer), None
 
-    y, (krest, vrest) = jax.lax.scan(
-        one_layer,
-        y,
-        (dec["layers"]["block"], cache.k[1:], cache.v[1:], enc.cross_k[1:], enc.cross_v[1:]),
+    layers = jnp.arange(1, cache.k.shape[0], dtype=jnp.int32)
+    (y, new_k, new_v), _ = jax.lax.scan(
+        one_layer, carry, (dec["layers"]["block"], layers, enc.cross_k[1:], enc.cross_v[1:])
     )
-    new_k = jnp.concatenate([ck0[None], krest], axis=0)
-    new_v = jnp.concatenate([cv0[None], vrest], axis=0)
 
     y = _t5_rms(y, dec["final_ln"]["weight"].astype(y.dtype), eps)
     h_out = y if return_all else y[:, -1]
@@ -821,16 +832,15 @@ def _whisper_decode(cfg, params, input_ids, cache: KVCache, enc: EncDecState, re
         return _proj(x, p["kernel"]) + p["bias"].astype(x.dtype)
 
     def one_layer(carry, layer):
-        h = carry
-        p, ck, cv, xk, xv = layer
+        h, ck, cv = carry
+        p, i, xk, xv = layer
         a = p["self_attn"]
         hn = _layer_norm(h, p["self_attn_layer_norm"], eps)
         q = proj_b(hn, a["q_proj"])  # _attend applies the 1/sqrt(d) scale
         k_new = _proj(hn, a["k_proj"]["kernel"])  # Whisper: no K bias
         v_new = proj_b(hn, a["v_proj"])
-        ck = jax.lax.dynamic_update_slice(ck, k_new.astype(ck.dtype), (0, start, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cv, v_new.astype(cv.dtype), (0, start, 0, 0))
-        out = _attend(q, ck, cv, positions)
+        ck, cv, k_i, v_i = _cache_step(ck, cv, k_new, v_new, i, start)
+        out = _attend(q, k_i, v_i, positions)
         h = h + _out_proj(out, a["out_proj"]["kernel"]) + a["out_proj"]["bias"].astype(h.dtype)
 
         c = p["encoder_attn"]
@@ -845,10 +855,11 @@ def _whisper_decode(cfg, params, input_ids, cache: KVCache, enc: EncDecState, re
             approximate=False,
         )
         h = h + mid @ p["fc2"]["kernel"].astype(mid.dtype) + p["fc2"]["bias"].astype(mid.dtype)
-        return h, (ck, cv)
+        return (h, ck, cv), None
 
-    y, (new_k, new_v) = jax.lax.scan(
-        one_layer, y, (stacked, cache.k, cache.v, enc.cross_k, enc.cross_v)
+    layers = jnp.arange(cache.k.shape[0], dtype=jnp.int32)
+    (y, new_k, new_v), _ = jax.lax.scan(
+        one_layer, (y, cache.k, cache.v), (stacked, layers, enc.cross_k, enc.cross_v)
     )
     y = _layer_norm(y, dec["layer_norm"], eps)
     logits = (y if return_all else y[:, -1]) @ embed.T.astype(cfg.dtype)

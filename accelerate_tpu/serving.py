@@ -273,7 +273,10 @@ def _build_decode_step(fwd, cfg, temperature, top_k, top_p, eos_token_id,
     or done compute masked garbage — the fixed shape is what buys zero
     steady-state recompiles). Cache and state buffers are donated; params
     are NOT (the weight-publication hot swap relies on rebinding them
-    without invalidating live buffers).
+    without invalidating live buffers). The donated cache stays ONE buffer a
+    side through the step: the forward's layer loop carries it whole and
+    scatters each slot's new rows into it in place (``generation._cache_step``),
+    so a step copies no cache and holds no second one beside it.
 
     Both modes return the same 5-tuple
     ``(cache, state, toks (N, k+1) int32, emitted (N,) int32, bad (N,))`` —

@@ -1,8 +1,10 @@
 """``generation._attend`` scores grouped query heads against K and V as the
 cache holds them. First against a reference written here with the explicit
 ``jnp.repeat`` it replaced, which pins the head mapping j -> j // G; then in
-the serving cell's programs, compiled for a described v5e chip: no array of
-the repeated shape is left, and no slice-sized copy stands in its place.
+the serving cells' programs, compiled for a described v5e chip: no array of
+the repeated shape is left, and no slice-sized copy stands in its place. The
+same compiles show that a decode step writes the cache in place: it holds no
+second copy of the cache and puts no layer's slice back into a stack.
 
 The topology is described in a fixture (never while a module is imported);
 ``tests/chipbench/test_chipbench_aot.py`` is the other file that does so."""
@@ -54,11 +56,13 @@ def test_attend_equals_the_explicit_repeat(hq, hkv, sq, quantized, masked):
                                rtol=2e-5, atol=2e-5)
 
 
-# -- the compiled programs of mistral_serve_steady ---------------------------------
+# -- the compiled programs of the serving cells ------------------------------------
 
 
 @pytest.fixture(scope="module")
-def steady_programs():
+def cell_programs():
+    """``get(cell_name) -> (cell, {"decode": compiled, "prefill": compiled})``, each
+    cell compiled once for a described v5e chip."""
     import os
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -75,17 +79,24 @@ def steady_programs():
     before = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    cell = spec.load_cell("mistral_serve_steady")
-    yield cell, aot.serving_programs(cell, topo.devices[0])
+    compiled = {}
+
+    def get(name):
+        if name not in compiled:
+            cell = spec.load_cell(name)
+            compiled[name] = cell, aot.serving_programs(cell, topo.devices[0])
+        return compiled[name]
+
+    yield get
     jax.config.update("jax_enable_compilation_cache", before)
     compilation_cache.reset_cache()
 
 
-_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%\S+ = \w+\[([0-9,]*)\]\S* ([\w\-]+)\(")
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%(\S+) = \w+\[([0-9,]*)\]\S* ([\w\-]+)\(")
 
 
 def _arrays(hlo_text, scheduled_only=False):
-    """(elements, opcode) of every array-valued instruction; with
+    """(elements, opcode, name) of every array-valued instruction; with
     ``scheduled_only`` those a fusion holds inside itself are left out, since
     they never reach memory as a buffer of their own."""
     inside_fusion = False
@@ -94,15 +105,13 @@ def _arrays(hlo_text, scheduled_only=False):
             inside_fusion = line.startswith("%fused_computation")
         m = _INSTRUCTION.match(line)
         if m and not (scheduled_only and inside_fusion):
-            yield math.prod(int(n) for n in m.group(1).split(",") if n), m.group(2)
+            yield math.prod(int(n) for n in m.group(2).split(",") if n), m.group(3), m.group(1)
 
 
 @pytest.mark.parametrize("case", ["decode_no_repeated_array", "prefill_no_repeated_array",
-                                  "decode_no_slice_sized_copy", "decode_temporaries"])
-def test_the_steady_cell_compiles_without_the_gqa_repeat(steady_programs, case):
-    from chipbench import aot
-
-    cell, programs = steady_programs
+                                  "decode_no_slice_sized_copy"])
+def test_the_steady_cell_compiles_without_the_gqa_repeat(cell_programs, case):
+    cell, programs = cell_programs("mistral_serve_steady")
     cfg, eng = cell.config, cell.workload["engine"]
     program = programs[case.split("_")[0]]
     heads, kv_heads, d = cfg["num_attention_heads"], cfg["num_key_value_heads"], cfg["head_dim"]
@@ -110,18 +119,39 @@ def test_the_steady_cell_compiles_without_the_gqa_repeat(steady_programs, case):
     kv_slice, repeated = rows * kv_heads * d, rows * heads * d
     if case == "decode_no_repeated_array":
         # K or V copied out once for every query head, in whatever shape
-        assert repeated not in {n for n, _ in _arrays(program.as_text())}
+        assert repeated not in {n for n, _, _ in _arrays(program.as_text())}
     elif case == "prefill_no_repeated_array":
         # one slot's rows: the logits over the vocabulary and a four-layer slice
         # of the cache have as many elements, so here only what a broadcast writes
-        assert repeated not in {n for n, op in _arrays(program.as_text()) if op == "broadcast"}
-    elif case == "decode_no_slice_sized_copy":
-        moved = [op for n, op in _arrays(program.as_text(), scheduled_only=True)
+        assert repeated not in {n for n, op, _ in _arrays(program.as_text()) if op == "broadcast"}
+    else:
+        moved = [op for n, op, _ in _arrays(program.as_text(), scheduled_only=True)
                  if n == kv_slice and op in ("copy", "transpose")]
         assert not moved
+
+
+# One layer's K and V slices in bf16 for the steady cell: the cache rides the layer
+# loop's carry and is written in place, so beside the donated cache a decode step
+# holds less than that. Mixtral's step still copies a layer's experts (ROADMAP S7).
+@pytest.mark.parametrize("cell_name,temporaries_under", [
+    ("mistral_serve_steady", 2 * 2 * 24 * 2048 * 8 * 128),
+    ("mixtral_serve_decode", 4_000_000_000),
+], ids=["steady", "mixtral"])
+@pytest.mark.parametrize("case", ["temporaries", "no_cache_sized_copy_or_put_back"])
+def test_the_decode_program_writes_the_cache_in_place(cell_programs, cell_name,
+                                                      temporaries_under, case):
+    from chipbench import aot
+
+    cell, programs = cell_programs(cell_name)
+    cfg, eng = cell.config, cell.workload["engine"]
+    cache = (cfg["num_hidden_layers"] * eng["n_slots"] * eng["max_len"]
+             * cfg["num_key_value_heads"] * cfg["head_dim"])
+    if case == "temporaries":
+        assert aot.memory_of(programs["decode"])["temporaries"] < temporaries_under
     else:
-        # Beside the donated cache the step still holds one copy of it (the
-        # whole-cache copies around the per-slot write, ROADMAP S8) and two
-        # layer slices: less than one repeated buffer more, where there were three.
-        cache = 2 * 2 * cfg["num_hidden_layers"] * kv_slice
-        assert aot.memory_of(program)["temporaries"] < cache + 2 * repeated
+        # K's or V's whole stack copied, or a fusion that puts a layer's slice into
+        # a stack (XLA names a fusion after what it holds); the scatter fusion that
+        # writes the new rows has the cache's shape too, and aliases its operand
+        moved = [name for n, op, name in _arrays(programs["decode"].as_text(), scheduled_only=True)
+                 if n == cache and (op == "copy" or "dynamic-update-slice" in name)]
+        assert not moved
