@@ -5,7 +5,7 @@ ENV = XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu
 PYTEST = $(ENV) python -m pytest -q
 
 .PHONY: chip_smoke test test_smoke test_core test_models test_parallel test_big_modeling \
-        test_cli test_examples test_checkpointing test_hub test_tpu quality bench \
+        test_cli test_examples test_checkpointing test_hub test_tpu quality \
         telemetry-smoke warmup-smoke faulttol-smoke serving-smoke plan-smoke \
         reshard-smoke disagg-smoke chaos-smoke chaos-train-smoke publish-smoke \
         autoscale-smoke trace-smoke gameday-smoke sdc-smoke profile-smoke \
@@ -73,9 +73,6 @@ chip_smoke:
 # With ACCELERATE_TEST_USE_TPU=1 a missing chip fails the tier, it does not skip.
 test_tpu:
 	ACCELERATE_TEST_USE_TPU=1 python -m pytest -q -rs tests/tpu/
-
-bench:
-	python bench.py
 
 # Observability gate: 20-step toy loop with telemetry on, then assert the
 # per-rank JSONL report is well-formed (schema, recompile counting, summary

@@ -255,7 +255,7 @@ def record_watchdog_signature(accelerator, batch, digest: str) -> None:
 
 def place_compile_cache() -> str:
     """Place JAX's persistent compilation cache for an entry script
-    (``chip_smoke.py``, ``bench.py``, ``benchmarks/``): call it first thing.
+    (``chip_smoke.py``, ``chipbench.run``): call it first thing.
 
     ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself and nothing here
     sets another directory. Unset: ``<checkout>/.jax_cache`` — a fixed path,

@@ -738,9 +738,8 @@ class DataLoaderDispatcher(BaseDataLoader):
                  dispatch_group_size: int = 8, **kwargs):
         super().__init__(dataset, batch_sampler=batch_sampler, **kwargs)
         self.split_batches = split_batches
-        # The per-broadcast cost is FIXED (~7 ms on a 2-proc host gang,
-        # benchmarks/input_pipeline_bench.py — payload size barely matters
-        # below ~1 MB), so rank 0 reads ahead and ships
+        # The per-broadcast cost is FIXED (~7 ms on a 2-proc host gang;
+        # payload size barely matters below ~1 MB), so rank 0 reads ahead and ships
         # ``dispatch_group_size`` batches per collective, amortizing that
         # fixed cost to ~1 ms/batch. Same batches, same order — only the
         # collective cadence changes; every rank buffers one group.

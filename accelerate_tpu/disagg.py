@@ -1,8 +1,8 @@
 """Disaggregated serving (layer L7 — inference serving, two meshes).
 
-The colocated :class:`~accelerate_tpu.serving.ServingEngine` already gets
-slot-paged KV, chunked prefill, and a zero-recompile decode step — but
-prefill and decode still share one device queue, so a long prompt burst
+The colocated :class:`~accelerate_tpu.serving.ServingEngine` already gets a
+dense per-slot KV cache, chunked prefill, and a zero-recompile decode step —
+but prefill and decode still share one device queue, so a long prompt burst
 stalls every in-flight decode and p95 TTFT spikes under open-loop load.
 This module is the DistServe/Splitwise-class fix, planner-shaped: partition
 the device set into a **prefill mesh** and a **decode mesh**, sized by
@@ -68,7 +68,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from .chaos import InjectedFaultError, deterministic_jitter
-from .generation import KVCache, QuantPages, init_slot_cache
+from .kv_cache import KVCache, QuantPages, init_slot_cache, slots_partition
 from .logging import get_logger
 from .planner import (BandwidthTable, PlannerError, kv_bytes_per_token,
                       plan_disagg_slices)
@@ -195,7 +195,7 @@ class DisaggServingEngine(ServingEngine):
                         if dc.expected_prompt_tokens is not None
                         else max(1.0, self.t_max / 2.0))
             ratio = expected / max(1, int(self.config.max_new_tokens))
-        kvb = kv_bytes_per_token(self.cfg, dtype=self._cache.k.dtype)
+        kvb = kv_bytes_per_token(self.cfg, dtype=self._cache.dtype)
         self.slice_plan = plan_disagg_slices(
             len(devs), prefill_decode_flop_ratio=ratio,
             bw=BandwidthTable.from_dict(dc.bandwidths),
@@ -260,32 +260,13 @@ class DisaggServingEngine(ServingEngine):
         self._hstats = {"transfers": 0, "bytes": 0, "inserts": 0,
                         "flushes": 0, "lane_chunks": 0}
 
-        # Page extract: slice the lane's freshly written page out of its
-        # (L, 1, T_max, Hkv, D) cache. One executable per ladder rung.
-        # Tree-mapped so int8 QuantPages (data + per-page scale leaves,
-        # both T-major on axis 2) slice as one unit.
-        self._extract = jax.jit(
-            lambda k, v, start, size: jax.tree.map(
-                lambda a: jax.lax.dynamic_slice_in_dim(a, start, size, axis=2),
-                (k, v),
-            ),
-            static_argnums=(3,),
-        )
+        # Page extract: the lane's freshly written rows out of its one-slot
+        # cache. One executable per ladder rung.
+        self._extract = jax.jit(KVCache.rows, static_argnums=(2,))
 
         # Page insert: write a transferred page into the decode-side slot
         # cache at the request's own offset, and commit its true length.
-        def _insert(cache: KVCache, k_page, v_page, slot, start, valid):
-            zero = jnp.zeros((), jnp.int32)
-
-            def upd(a, page):
-                return jax.lax.dynamic_update_slice(
-                    a, page, (zero, slot, start, zero, zero))
-
-            k = jax.tree.map(upd, cache.k, k_page)
-            v = jax.tree.map(upd, cache.v, v_page)
-            return KVCache(k, v, cache.length.at[slot].set(start + valid))
-
-        self._insert = jax.jit(_insert, donate_argnums=(0,))
+        self._insert = jax.jit(KVCache.insert_rows, donate_argnums=(0,))
 
         # Slot arming: once the final page has landed, publish the prefill
         # step's terminal state for this slot — exactly the fields the
@@ -338,7 +319,7 @@ class DisaggServingEngine(ServingEngine):
         n_d = len(decode_devices)
         if dc.shard_decode_slots and n_d > 1 and self.n_slots % n_d == 0:
             mesh = Mesh(np.asarray(decode_devices), ("slots",))
-            return (mesh, NamedSharding(mesh, P(None, "slots")),
+            return (mesh, NamedSharding(mesh, slots_partition("slots")),
                     NamedSharding(mesh, P("slots")), NamedSharding(mesh, P()))
         if dc.shard_decode_slots and _log_ok():
             logger.warning_once(
@@ -437,7 +418,7 @@ class DisaggServingEngine(ServingEngine):
         self._hstats["lane_chunks"] += 1
 
         size = int(chunk.shape[1])
-        pages = self._extract(lane.cache.k, lane.cache.v, np.int32(start), size)
+        pages = self._extract(lane.cache, np.int32(start), size)
         self._hstats["transfers"] += 1
         t0 = None
         if self._hstats["transfers"] % dc.handoff_sample_every == 0:
@@ -542,7 +523,7 @@ class DisaggServingEngine(ServingEngine):
                         measured_s=time.perf_counter() - tb0)
                 elif backoff > 0:
                     time.sleep(backoff)
-        if poison and jnp.issubdtype(pages[0].dtype, jnp.floating):
+        if poison and self._cache.holds_nan:
             # Poisoned page: what lands on the decode mesh is all-NaN. The
             # decode-side nonfinite-logits sentinel must catch it once the
             # slot arms — pinned by tests and the chaos smoke.
@@ -551,7 +532,7 @@ class DisaggServingEngine(ServingEngine):
                  jnp.full_like(pages[1], jnp.nan)),
                 self._decode_sharding,
             )
-        if isinstance(pages[0], QuantPages) and self.chaos is not None:
+        if self._cache.quantized and self.chaos is not None:
             dq = self.chaos.draw("page_dequant", self._stats["ticks"],
                                  unit=req.id)
             if dq is not None and dq.kind == "poison":
@@ -727,7 +708,7 @@ class DisaggServingEngine(ServingEngine):
         ratio = (float(flop_ratio) if flop_ratio is not None
                  else float(self.slice_plan.flop_ratio))
         try:
-            kvb = kv_bytes_per_token(self.cfg, dtype=self._cache.k.dtype)
+            kvb = kv_bytes_per_token(self.cfg, dtype=self._cache.dtype)
             plan = plan_disagg_slices(
                 len(devs), prefill_decode_flop_ratio=ratio,
                 bw=BandwidthTable.from_dict(dc.bandwidths),
@@ -956,8 +937,7 @@ class DisaggServingEngine(ServingEngine):
                     jax.random.key(self.config.seed),
                     j == 0, j == len(chunks) - 1,
                 )
-                pages = self._extract(lane.cache.k, lane.cache.v,
-                                      np.int32(start), size)
+                pages = self._extract(lane.cache, np.int32(start), size)
                 pages_d = jax.device_put(pages, dsh)
                 cache = self._insert(cache, pages_d[0], pages_d[1],
                                      np.int32(0), np.int32(start),

@@ -9,13 +9,13 @@ level.
 
 Design:
 
-- The cache is an explicit pytree ``(k, v)`` of shape ``(L, B, T_max, Hkv, D)``
+- The cache (kv_cache.py, which alone knows its layout) is an explicit pytree
   threaded through pure functions — no flax mutable collections, so the same
   code runs under ``jit``, ``shard_map``, and the big-model streaming path.
-- ``prefill`` runs the prompt through a ``lax.scan`` over the stacked layer
-  params (the ``nn.scan`` weight layout IS the cache layout) and writes each
-  layer's rotated K/V; ``decode_step`` attends one query against the cache
-  with a static-shape position mask.
+- One loop (:func:`_forward_cached`) carries it through a ``lax.scan`` over
+  the stacked layer params and hands each layer's block one callable that
+  writes the layer's new K/V rows and attends over the layer with a
+  static-shape position mask; a family supplies its embedding, block and head.
 - Attention math mirrors models/llama.py exactly (RMSNorm → fused QKV
   projections → RoPE at absolute positions → GQA by head repetition → SwiGLU
   MLP); parity with ``module.apply`` is pinned by tests/test_generation.py.
@@ -40,103 +40,18 @@ from .models.llama import (
     rotary_embedding,
     scale_residual,
 )
+from . import kv_cache  # cache_step is called through the module: tests substitute it
+from .kv_cache import (  # noqa: F401  (re-exported: the cache's public names)
+    KVCache,
+    QuantPages,
+    cache_spec,
+    dequantize_kv_page,
+    float_pages,
+    init_cache,
+    init_slot_cache,
+    quantize_kv_page,
+)
 from .utils.quantization import DecodeQuant, dequantize_decode_kernel
-
-
-class KVCache(NamedTuple):
-    """Every layer's K and V in one buffer each. A cached forward carries both
-    whole through its layer loop and writes only the new rows, in place
-    (``_cache_step``); a jitted caller that donates the cache gets it back as
-    the same buffers."""
-
-    k: jax.Array  # (L, B, T_max, Hkv, D)
-    v: jax.Array  # (L, B, T_max, Hkv, D)
-    # () int32 — tokens written so far (batch-global), or (B,) int32 for a
-    # slot-paged cache (serving.py) where every row advances independently.
-    length: jax.Array
-
-
-class QuantPages(NamedTuple):
-    """int8 KV pages with per-page absmax scales — the KV-cache twin of the
-    ``QuantizedTensor`` weight pattern (utils/quantization.py). Rides inside
-    ``KVCache.k``/``.v`` as a pytree subtree, so ``lax.scan`` over layers,
-    disagg page slicing, and ``device_put`` all work unchanged; attention
-    dequantizes adjacent to the dot (see ``_attend``) so pages cross HBM and
-    the disagg handoff link as int8 (~4x fewer bytes than bf16/fp32)."""
-
-    data: jax.Array   # int8, same layout as the float cache it replaces
-    scale: jax.Array  # f32, data.shape[:-1] + (1,) — one scale per page row
-
-    @property
-    def dtype(self):
-        return self.data.dtype
-
-    @property
-    def shape(self):
-        return self.data.shape
-
-    @property
-    def nbytes(self):
-        return self.data.nbytes + self.scale.nbytes
-
-
-def quantize_kv_page(x) -> QuantPages:
-    """Symmetric int8 quantization over the trailing (head_dim) axis."""
-    amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    scale = jnp.maximum(amax, jnp.finfo(jnp.float32).tiny) / 127.0
-    data = jnp.clip(jnp.round(x.astype(jnp.float32) / scale), -127, 127)
-    return QuantPages(data.astype(jnp.int8), scale)
-
-
-def dequantize_kv_page(pages: QuantPages, dtype):
-    return pages.data.astype(dtype) * pages.scale.astype(dtype)
-
-
-def _cache_dims(cfg) -> tuple[int, int, int, int]:
-    """(layers, kv_heads, head_dim, max_positions) for any supported config.
-    For encoder-decoder configs these describe the DECODER self-attention
-    cache; T5's relative positions are unbounded (max_pos = 2**30)."""
-    if hasattr(cfg, "n_dec"):  # T5
-        return cfg.n_dec, cfg.num_heads, cfg.d_kv, 2**30
-    if hasattr(cfg, "decoder_layers"):  # Whisper
-        return (
-            cfg.decoder_layers, cfg.decoder_attention_heads,
-            cfg.decoder_head_dim, cfg.max_target_positions,
-        )
-    layers = getattr(cfg, "num_hidden_layers", None) or cfg.n_layer
-    kv_heads = (
-        getattr(cfg, "num_key_value_heads", None)
-        or getattr(cfg, "num_attention_heads", None)
-        or cfg.n_head
-    )
-    max_pos = getattr(cfg, "max_position_embeddings", None) or cfg.n_positions
-    return layers, kv_heads, cfg.head_dim, max_pos
-
-
-def init_cache(cfg, batch: int, max_len: int, dtype=None) -> KVCache:
-    layers, kv_heads, head_dim, _ = _cache_dims(cfg)
-    shape = (layers, batch, max_len, kv_heads, head_dim)
-    dtype = dtype or cfg.dtype
-    if np.dtype(dtype) == np.int8:
-        # Quantized KV pages: int8 data + per-page f32 scales (ones so an
-        # unwritten page dequantizes to exact zeros, like the float cache).
-        def _pages():
-            return QuantPages(jnp.zeros(shape, jnp.int8),
-                              jnp.ones(shape[:-1] + (1,), jnp.float32))
-        return KVCache(k=_pages(), v=_pages(),
-                       length=jnp.zeros((), jnp.int32))
-    return KVCache(
-        k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
-        length=jnp.zeros((), jnp.int32),
-    )
-
-
-def init_slot_cache(cfg, n_slots: int, max_len: int, dtype=None) -> KVCache:
-    """Slot-paged cache (serving.py): same buffer layout as :func:`init_cache`
-    but ``length`` is a per-slot ``(n_slots,)`` vector, so every row advances
-    independently — one request retiring never stalls its neighbors."""
-    cache = init_cache(cfg, n_slots, max_len, dtype)
-    return cache._replace(length=jnp.zeros((n_slots,), jnp.int32))
 
 
 def _row_positions(start, b: int, s: int) -> jax.Array:
@@ -146,47 +61,6 @@ def _row_positions(start, b: int, s: int) -> jax.Array:
     if getattr(start, "ndim", 0) == 1:
         return start[:, None] + offs
     return jnp.broadcast_to(start + offs, (b, s))
-
-
-# The named scopes below (``attn``, ``cache_write``, ``mlp``, ``moe.router``,
-# ``moe.experts``, ``lm_head``) change the HLO's metadata only: a device
-# trace can group a step's ops by them, where the fusions' own names say
-# shapes.
-
-
-@jax.named_scope("cache_write")
-def _cache_write(buf, new, layer, start):
-    """Write ``new`` (B, S, Hkv, D) into the whole cache buffer ``buf``
-    (L, B, T, Hkv, D), in place, at layer ``layer`` and row offset ``start``:
-    a scalar (one ``dynamic_update_slice`` at ``(layer, 0, start, 0, 0)``) or
-    a per-row vector (a scatter at ``[layer, row, start[row] + s]``, the
-    slot-paged path) — ``start.ndim`` decides, at trace time. Only the new
-    rows move: ``buf`` rides the layer loop's carry, so the result aliases
-    it. A ``QuantPages`` cache quantizes the new pages here, writing data
-    and scale leaves at the same offsets."""
-    if isinstance(buf, QuantPages):
-        q = quantize_kv_page(new)
-        return QuantPages(_cache_write(buf.data, q.data, layer, start),
-                          _cache_write(buf.scale, q.scale, layer, start))
-    new = new.astype(buf.dtype)
-    if getattr(start, "ndim", 0) == 1:
-        b, s = new.shape[:2]
-        rows = jnp.arange(b, dtype=jnp.int32)[:, None]
-        cols = start[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
-        return buf.at[layer, rows, cols].set(new)
-    return jax.lax.dynamic_update_slice(buf, new[None], (layer, 0, start, 0, 0))
-
-
-def _cache_step(ck, cv, k_new, v_new, layer, start):
-    """One layer's turn at the cache, the idiom every cached forward shares:
-    write the new K and V rows into the whole buffers (:func:`_cache_write`),
-    then read that layer's (B, T, Hkv, D) slices back for attention. Returns
-    ``(ck, cv, k_layer, v_layer)``. The buffers are the scan's carry and
-    nothing mutates the slices, so no step copies the cache or stacks a
-    layer's slice into a second one."""
-    ck, cv = _cache_write(ck, k_new, layer, start), _cache_write(cv, v_new, layer, start)
-    k_i, v_i = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, False), (ck, cv))
-    return ck, cv, k_i, v_i
 
 
 # ---------------------------------------------------------------------------
@@ -290,10 +164,7 @@ def _attend(q, k, v, q_positions, kv_valid=None):
     ``QuantPages`` k/v dequantize HERE — adjacent to the attention dots, the
     same fusion-adjacency trick as ``_kernel`` — so the cache rides HBM as
     int8 and XLA fuses convert×scale into the einsum."""
-    if isinstance(k, QuantPages):
-        k = dequantize_kv_page(k, q.dtype)
-    if isinstance(v, QuantPages):
-        v = dequantize_kv_page(v, q.dtype)
+    k, v = float_pages(k, q.dtype), float_pages(v, q.dtype)
     b, sq, hq, d = q.shape
     t, hkv = k.shape[1:3]
     g = hq // hkv
@@ -310,69 +181,123 @@ def _attend(q, k, v, q_positions, kv_valid=None):
     return out.reshape(b, hkv, g, sq, d).transpose(0, 3, 1, 2, 4).reshape(b, sq, hq, d)
 
 
-def _llama_forward_cached(cfg, params, input_ids, cache: KVCache, return_all=False,
-                          pad_offset=None, kv_valid=None):
+def _forward_cached(decoder, cfg, params, input_ids, cache: KVCache, return_all=False,
+                    pad_offset=None, kv_valid=None):
     """Run ``input_ids`` (appended at cache.length) through all layers,
     returning (logits, new_cache) — last-token logits, or every position's
-    with ``return_all`` (speculative verification needs them).
+    with ``return_all`` (speculative verification needs them). The one loop
+    that carries the cache through the layers, whatever the family:
+    ``decoder(cfg, params, input_ids, pos_ids)`` returns what is the family's
+    own, ``(x, layers, block, norm, head, *xs)`` — the embedded tokens, the
+    stacked layer params, ``block(p, h, attend, *xs) -> h``, the final norm
+    over every position, the head, and any further per-layer inputs of the
+    block. ``attend(q, k_new, v_new)`` is a layer's whole dealing with the
+    cache: it writes the new K/V rows and attends over the layer
+    (``cache_step`` then ``_attend``), so no block holds the buffers.
 
     Left-padded batches (the transformers convention): ``pad_offset`` (B,)
-    counts each row's leading pads — RoPE positions shift down by it so row
-    content starts at position 0 — and ``kv_valid`` (B, T_max) masks the pad
-    slots out of attention forever.
+    counts each row's leading pads — the position ids the family embeds or
+    rotates by shift down by it so row content starts at position 0 — and
+    ``kv_valid`` (B, T_max) masks the pad slots out of attention forever.
     """
     if not cfg.scan_layers:
         raise ValueError("generation requires scan_layers=True (stacked blocks)")
-    model_p = params["model"] if "model" in params else params
-    stacked = model_p["layers"]["block"]
-    embed = model_p["embed_tokens"]["embedding"]
-
     b, s = input_ids.shape
-    t_max = cache.k.shape[2]
     start = cache.length
     positions = _row_positions(start, b, s)
+    pos_ids = positions
+    if pad_offset is not None:
+        pos_ids = jnp.maximum(positions - pad_offset[:, None], 0)
+    x, stacked, block, norm, head, *xs = decoder(cfg, params, input_ids, pos_ids)
+
+    def one_layer(carry, layer):
+        h, ck, cv = carry  # hidden state, the whole (L,B,T,Hkv,D) cache
+        p, i, *x_i = layer  # layer params, layer index
+
+        def attend(q, k_new, v_new):
+            nonlocal ck, cv
+            ck, cv, k_i, v_i = kv_cache.cache_step(ck, cv, k_new, v_new, i, start)
+            return _attend(q, k_i, v_i, positions, kv_valid)
+
+        return (block(p, h, attend, *x_i), ck, cv), None
+
+    layers = jnp.arange(cache.n_layers, dtype=jnp.int32)
+    (x, new_k, new_v), _ = jax.lax.scan(one_layer, (x, cache.k, cache.v), (stacked, layers, *xs))
+    x = norm(x)
+    logits = head(x if return_all else x[:, -1])
+    return logits.astype(jnp.float32), KVCache(new_k, new_v, start + s)
+
+
+def _moe_mlp(cfg, p, h):
+    """Mixtral's routed sparse MLP on raw params (mirrors models/moe.py —
+    dropless here since decode batches are tiny)."""
+    b, s = h.shape[:2]
+    tokens = h.reshape(b * s, -1)
+    with jax.named_scope("moe.router"):
+        router_logits = tokens.astype(jnp.float32) @ p["router"].astype(jnp.float32)
+        probs = jax.nn.softmax(router_logits, axis=-1)
+        topv, topi = jax.lax.top_k(probs, cfg.num_experts_per_tok)  # (T, k)
+        topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
+
+    # Dense dispatch over experts: fine at decode sizes, exact (dropless).
+    def per_expert(e):
+        gate = jax.nn.silu(tokens @ p["w_gate"][e].astype(tokens.dtype))
+        up = tokens @ p["w_up"][e].astype(tokens.dtype)
+        return (gate * up) @ p["w_down"][e].astype(tokens.dtype)
+
+    with jax.named_scope("moe.experts"):
+        expert_out = jax.vmap(per_expert)(jnp.arange(cfg.num_local_experts))  # (E, T, H)
+        picked = jnp.take_along_axis(
+            jnp.transpose(expert_out, (1, 0, 2)), topi[..., None], axis=1
+        )  # (T, k, H)
+        out = jnp.sum(picked * topv[..., None].astype(picked.dtype), axis=1)
+    return out.reshape(b, s, -1)
+
+
+def _llama_decoder(cfg, params, input_ids, pos_ids):
+    """Llama-family decode (mirrors models/llama.py and its chassis knobs).
+    Mixtral is this block with the routed sparse MLP (``p["moe"]``) where the
+    dense one (``p["mlp"]``) stands."""
+    model_p = params["model"] if "model" in params else params
+    embed = model_p["embed_tokens"]["embedding"]
 
     x = _embed_tokens(cfg, embed, input_ids)
-    rope_positions = positions
-    if pad_offset is not None:
-        rope_positions = jnp.maximum(positions - pad_offset[:, None], 0)
     rd = getattr(cfg, "rotary_dim", None) or cfg.head_dim
-    cos, sin = rotary_embedding(rope_positions, rd, cfg.rope_theta, x.dtype)
+    cos, sin = rotary_embedding(pos_ids, rd, cfg.rope_theta, x.dtype)
 
     attn_mult = getattr(cfg, "attention_multiplier", None)
     res_mult = getattr(cfg, "residual_multiplier", 1.0)
 
-    def one_layer(carry, layer):
-        h, ck, cv = carry  # hidden state, the whole (L,B,T,Hkv,D) cache
-        p, i = layer  # layer params, layer index
+    def block(p, h, attend):
         attn = p["self_attn"]
         hn = _chassis_norm(cfg, p["input_layernorm"], h)
         q, k_new, v_new = _qkv_proj(attn, hn, cos, sin, rotary_dim=rd)
         if attn_mult is not None:  # same q-folding trick as LlamaAttention
             q = q * jnp.asarray(attn_mult * np.sqrt(cfg.head_dim), q.dtype)
-        ck, cv, k_i, v_i = _cache_step(ck, cv, k_new, v_new, i, start)
-        out = _attend(q, k_i, v_i, positions, kv_valid)
-        out = _out_proj(out, attn["o_proj"]["kernel"])
+        out = _out_proj(attend(q, k_new, v_new), attn["o_proj"]["kernel"])
         if "bias" in attn["o_proj"]:
             out = out + attn["o_proj"]["bias"].astype(out.dtype)
         h = h + scale_residual(out, res_mult)
         hn = _chassis_norm(cfg, p["post_attention_layernorm"], h)
-        h = h + scale_residual(_mlp(cfg, p["mlp"], hn), res_mult)
-        return (h, ck, cv), None
+        ffn = _moe_mlp(cfg, p["moe"], hn) if "moe" in p else _mlp(cfg, p["mlp"], hn)
+        return h + scale_residual(ffn, res_mult)
 
-    layers = jnp.arange(cache.k.shape[0], dtype=jnp.int32)
-    (x, new_k, new_v), _ = jax.lax.scan(one_layer, (x, cache.k, cache.v), (stacked, layers))
-    x = _chassis_norm(cfg, model_p["norm"], x)
-    h_out = x if return_all else x[:, -1]
-    with jax.named_scope("lm_head"):
-        if cfg.tie_word_embeddings:
-            logits = h_out @ embed.T.astype(cfg.dtype)
-        else:
-            logits = h_out @ params["lm_head"]["kernel"].astype(cfg.dtype)
-    ls = getattr(cfg, "logits_scaling", 1.0)
-    if ls != 1.0:  # Granite: logits / scaling
-        logits = logits / jnp.asarray(ls, logits.dtype)
-    return logits.astype(jnp.float32), KVCache(new_k, new_v, start + s)
+    def head(h_out):
+        with jax.named_scope("lm_head"):
+            if cfg.tie_word_embeddings:
+                logits = h_out @ embed.T.astype(cfg.dtype)
+            else:
+                logits = h_out @ params["lm_head"]["kernel"].astype(cfg.dtype)
+        ls = getattr(cfg, "logits_scaling", 1.0)
+        if ls != 1.0:  # Granite: logits / scaling
+            logits = logits / jnp.asarray(ls, logits.dtype)
+        return logits
+
+    return (x, model_p["layers"]["block"], block,
+            lambda x: _chassis_norm(cfg, model_p["norm"], x), head)
+
+
+_llama_forward_cached = partial(_forward_cached, _llama_decoder)
 
 
 # ---------------------------------------------------------------------------
@@ -412,7 +337,7 @@ def sample_logits(logits, rng, *, temperature=1.0, top_k: Optional[int] = None,
 
 
 # ---------------------------------------------------------------------------
-# The loop
+# The other decoder-only families
 # ---------------------------------------------------------------------------
 
 def _layer_norm(x, p, eps):
@@ -422,128 +347,72 @@ def _layer_norm(x, p, eps):
     return y * p["scale"].astype(x.dtype) + p["bias"].astype(x.dtype)
 
 
-def _gpt2_forward_cached(cfg, params, input_ids, cache: KVCache, return_all=False,
-                         pad_offset=None, kv_valid=None):
-    """GPT-2 decode with the same cache contract (learned positions, fused
-    c_attn, GELU MLP — mirrors models/gpt2.py). ``pad_offset``/``kv_valid``:
-    left-padded batches (see _llama_forward_cached)."""
-    if not cfg.scan_layers:
-        raise ValueError("generation requires scan_layers=True (stacked blocks)")
+def _gpt2_decoder(cfg, params, input_ids, pos_ids):
+    """GPT-2 decode (learned positions, fused c_attn, GELU MLP — mirrors
+    models/gpt2.py)."""
     tr = params["transformer"]
-    stacked = tr["h"]["block"]
     wte = tr["wte"]["embedding"]
-
-    b, s = input_ids.shape
-    t_max = cache.k.shape[2]
-    start = cache.length
-    positions_b = _row_positions(start, b, s)
-    pos_ids = positions_b
-    if pad_offset is not None:
-        pos_ids = jnp.maximum(positions_b - pad_offset[:, None], 0)
 
     x = jnp.take(wte, input_ids, axis=0).astype(cfg.dtype)
     x = x + jnp.take(tr["wpe"]["embedding"], pos_ids, axis=0).astype(cfg.dtype)
 
-    def one_layer(carry, layer):
-        h, ck, cv = carry
-        p, i = layer
+    def block(p, h, attend):
         hn = _layer_norm(h, p["ln_1"], cfg.layer_norm_epsilon)
         qkv = jnp.einsum(
             "bsh,hcnd->bscnd", hn, p["attn"]["c_attn"]["kernel"].astype(hn.dtype)
         ) + p["attn"]["c_attn"]["bias"].astype(hn.dtype)
-        q, k_new, v_new = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        ck, cv, k_i, v_i = _cache_step(ck, cv, k_new, v_new, i, start)
-        out = _attend(q, k_i, v_i, positions_b, kv_valid)
+        out = attend(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2])
         h = h + (
             jnp.einsum("bsnd,ndh->bsh", out, p["attn"]["c_proj"]["kernel"].astype(out.dtype))
             + p["attn"]["c_proj"]["bias"].astype(out.dtype)
         )
         hn = _layer_norm(h, p["ln_2"], cfg.layer_norm_epsilon)
-        mid = jax.nn.gelu(
-            hn @ p["c_fc"]["kernel"].astype(hn.dtype) + p["c_fc"]["bias"].astype(hn.dtype)
-        )
-        h = h + mid @ p["c_proj"]["kernel"].astype(mid.dtype) + p["c_proj"]["bias"].astype(mid.dtype)
-        return (h, ck, cv), None
+        mid = jax.nn.gelu(_dense(p["c_fc"], hn))
+        return h + mid @ p["c_proj"]["kernel"].astype(mid.dtype) + p["c_proj"]["bias"].astype(mid.dtype)
 
-    layers = jnp.arange(cache.k.shape[0], dtype=jnp.int32)
-    (x, new_k, new_v), _ = jax.lax.scan(one_layer, (x, cache.k, cache.v), (stacked, layers))
-    x = _layer_norm(x, tr["ln_f"], cfg.layer_norm_epsilon)
-    logits = (x if return_all else x[:, -1]) @ wte.T.astype(cfg.dtype)
-    return logits.astype(jnp.float32), KVCache(new_k, new_v, start + s)
+    return (x, tr["h"]["block"], block,
+            lambda x: _layer_norm(x, tr["ln_f"], cfg.layer_norm_epsilon),
+            lambda h_out: h_out @ wte.T.astype(cfg.dtype))
 
 
-def _opt_forward_cached(cfg, params, input_ids, cache: KVCache, return_all=False,
-                        pad_offset=None, kv_valid=None):
-    """OPT decode with the same cache contract (learned positions with the
-    fairseq offset of 2, pre-LN ReLU blocks — mirrors models/opt.py).
-    ``pad_offset``/``kv_valid``: left-padded batches."""
-    if not cfg.scan_layers:
-        raise ValueError("generation requires scan_layers=True (stacked blocks)")
+def _opt_decoder(cfg, params, input_ids, pos_ids):
+    """OPT decode (learned positions with the fairseq offset of 2, pre-LN
+    ReLU blocks — mirrors models/opt.py)."""
     model_p = params["model"]
-    stacked = model_p["layers"]["block"]
     embed = model_p["embed_tokens"]["embedding"]
-
-    b, s = input_ids.shape
-    start = cache.length
-    positions_b = _row_positions(start, b, s)
-    pos_ids = positions_b
-    if pad_offset is not None:
-        pos_ids = jnp.maximum(positions_b - pad_offset[:, None], 0)
 
     x = jnp.take(embed, input_ids, axis=0).astype(cfg.dtype)
     x = x + jnp.take(
         model_p["embed_positions"]["embedding"], pos_ids + cfg.POSITION_OFFSET, axis=0
     ).astype(cfg.dtype)
 
-    def one_layer(carry, layer):
-        h, ck, cv = carry
-        p, i = layer
+    def block(p, h, attend):
         attn = p["self_attn"]
         hn = _layer_norm(h, p["self_attn_layer_norm"], cfg.layer_norm_eps)
         q = _proj(hn, attn["q_proj"]["kernel"]) + attn["q_proj"]["bias"].astype(hn.dtype)
         k_new = _proj(hn, attn["k_proj"]["kernel"]) + attn["k_proj"]["bias"].astype(hn.dtype)
         v_new = _proj(hn, attn["v_proj"]["kernel"]) + attn["v_proj"]["bias"].astype(hn.dtype)
-        ck, cv, k_i, v_i = _cache_step(ck, cv, k_new, v_new, i, start)
-        out = _attend(q, k_i, v_i, positions_b, kv_valid)
+        out = attend(q, k_new, v_new)
         h = h + _out_proj(out, attn["out_proj"]["kernel"]) + attn["out_proj"]["bias"].astype(h.dtype)
         hn = _layer_norm(h, p["final_layer_norm"], cfg.layer_norm_eps)
-        mid = jax.nn.relu(
-            hn @ p["fc1"]["kernel"].astype(hn.dtype) + p["fc1"]["bias"].astype(hn.dtype)
-        )
-        h = h + mid @ p["fc2"]["kernel"].astype(mid.dtype) + p["fc2"]["bias"].astype(mid.dtype)
-        return (h, ck, cv), None
+        mid = jax.nn.relu(_dense(p["fc1"], hn))
+        return h + mid @ p["fc2"]["kernel"].astype(mid.dtype) + p["fc2"]["bias"].astype(mid.dtype)
 
-    layers = jnp.arange(cache.k.shape[0], dtype=jnp.int32)
-    (x, new_k, new_v), _ = jax.lax.scan(one_layer, (x, cache.k, cache.v), (stacked, layers))
-    x = _layer_norm(x, model_p["final_layer_norm"], cfg.layer_norm_eps)
-    logits = (x if return_all else x[:, -1]) @ embed.T.astype(cfg.dtype)
-    return logits.astype(jnp.float32), KVCache(new_k, new_v, start + s)
+    return (x, model_p["layers"]["block"], block,
+            lambda x: _layer_norm(x, model_p["final_layer_norm"], cfg.layer_norm_eps),
+            lambda h_out: h_out @ embed.T.astype(cfg.dtype))
 
 
-def _neox_forward_cached(cfg, params, input_ids, cache: KVCache, return_all=False,
-                         pad_offset=None, kv_valid=None):
+def _neox_decoder(cfg, params, input_ids, pos_ids):
     """GPT-NeoX decode: parallel residual, fused per-head [q|k|v], partial
-    rotary — mirrors models/neox.py. ``pad_offset``/``kv_valid``: left-padded
-    batches."""
-    if not cfg.scan_layers:
-        raise ValueError("generation requires scan_layers=True (stacked blocks)")
+    rotary — mirrors models/neox.py."""
     gp = params["gpt_neox"]
-    stacked = gp["layers"]["block"]
-
-    b, s = input_ids.shape
-    start = cache.length
-    positions_b = _row_positions(start, b, s)
-    rope_positions = positions_b
-    if pad_offset is not None:
-        rope_positions = jnp.maximum(positions_b - pad_offset[:, None], 0)
 
     x = jnp.take(gp["embed_in"]["embedding"], input_ids, axis=0).astype(cfg.dtype)
     rnd = cfg.rotary_ndims
-    cos, sin = rotary_embedding(rope_positions, rnd, cfg.rotary_emb_base, x.dtype)
+    cos, sin = rotary_embedding(pos_ids, rnd, cfg.rotary_emb_base, x.dtype)
 
-    def one_layer(carry, layer):
-        h, ck, cv = carry
-        p, i = layer
+    def block(p, h, attend):
         attn = p["attention"]
         hn = _layer_norm(h, p["input_layernorm"], cfg.layer_norm_eps)
         qkv = jnp.einsum(
@@ -552,8 +421,7 @@ def _neox_forward_cached(cfg, params, input_ids, cache: KVCache, return_all=Fals
         q, k_new, v_new = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
         q = jnp.concatenate([apply_rope(q[..., :rnd], cos, sin), q[..., rnd:]], -1)
         k_new = jnp.concatenate([apply_rope(k_new[..., :rnd], cos, sin), k_new[..., rnd:]], -1)
-        ck, cv, k_i, v_i = _cache_step(ck, cv, k_new, v_new, i, start)
-        out = _attend(q, k_i, v_i, positions_b, kv_valid)
+        out = attend(q, k_new, v_new)
         attn_out = (
             jnp.einsum("bsnd,ndh->bsh", out, attn["dense"]["kernel"].astype(out.dtype))
             + attn["dense"]["bias"].astype(out.dtype)
@@ -561,94 +429,18 @@ def _neox_forward_cached(cfg, params, input_ids, cache: KVCache, return_all=Fals
 
         def mlp(inp):
             hn2 = _layer_norm(inp, p["post_attention_layernorm"], cfg.layer_norm_eps)
-            mid = jax.nn.gelu(
-                hn2 @ p["dense_h_to_4h"]["kernel"].astype(hn2.dtype)
-                + p["dense_h_to_4h"]["bias"].astype(hn2.dtype),
-                approximate=False,
-            )
-            return (
-                mid @ p["dense_4h_to_h"]["kernel"].astype(mid.dtype)
-                + p["dense_4h_to_h"]["bias"].astype(mid.dtype)
-            )
+            mid = jax.nn.gelu(_dense(p["dense_h_to_4h"], hn2), approximate=False)
+            return _dense(p["dense_4h_to_h"], mid)
 
         if cfg.use_parallel_residual:
             # One residual for both sublayers; the MLP sees pre-attention h.
-            h = h + attn_out + mlp(h)
-        else:
-            h = h + attn_out
-            h = h + mlp(h)
-        return (h, ck, cv), None
+            return h + attn_out + mlp(h)
+        h = h + attn_out
+        return h + mlp(h)
 
-    layers = jnp.arange(cache.k.shape[0], dtype=jnp.int32)
-    (x, new_k, new_v), _ = jax.lax.scan(one_layer, (x, cache.k, cache.v), (stacked, layers))
-    x = _layer_norm(x, gp["final_layer_norm"], cfg.layer_norm_eps)
-    logits = (x if return_all else x[:, -1]) @ params["embed_out"]["kernel"].astype(cfg.dtype)
-    return logits.astype(jnp.float32), KVCache(new_k, new_v, start + s)
-
-
-def _mixtral_forward_cached(cfg, params, input_ids, cache: KVCache, return_all=False,
-                            pad_offset=None, kv_valid=None):
-    """Mixtral decode: Llama attention + routed sparse-MLP on raw params
-    (mirrors models/moe.py — dropless here since decode batches are tiny).
-    ``pad_offset``/``kv_valid``: left-padded batches."""
-    if not cfg.scan_layers:
-        raise ValueError("generation requires scan_layers=True (stacked blocks)")
-    model_p = params["model"]
-    stacked = model_p["layers"]["block"]
-    embed = model_p["embed_tokens"]["embedding"]
-
-    b, s = input_ids.shape
-    start = cache.length
-    positions = _row_positions(start, b, s)
-    rope_positions = positions
-    if pad_offset is not None:
-        rope_positions = jnp.maximum(positions - pad_offset[:, None], 0)
-
-    x = jnp.take(embed, input_ids, axis=0).astype(cfg.dtype)
-    cos, sin = rotary_embedding(rope_positions, cfg.head_dim, cfg.rope_theta, x.dtype)
-    k = cfg.num_experts_per_tok
-
-    def moe(p, h):
-        T = b * s
-        tokens = h.reshape(T, -1)
-        with jax.named_scope("moe.router"):
-            router_logits = tokens.astype(jnp.float32) @ p["router"].astype(jnp.float32)
-            probs = jax.nn.softmax(router_logits, axis=-1)
-            topv, topi = jax.lax.top_k(probs, k)  # (T, k)
-            topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
-        # Dense dispatch over experts: fine at decode sizes, exact (dropless).
-        def per_expert(e):
-            gate = jax.nn.silu(tokens @ p["w_gate"][e].astype(tokens.dtype))
-            up = tokens @ p["w_up"][e].astype(tokens.dtype)
-            return (gate * up) @ p["w_down"][e].astype(tokens.dtype)
-
-        with jax.named_scope("moe.experts"):
-            expert_out = jax.vmap(per_expert)(jnp.arange(cfg.num_local_experts))  # (E, T, H)
-            picked = jnp.take_along_axis(
-                jnp.transpose(expert_out, (1, 0, 2)), topi[..., None], axis=1
-            )  # (T, k, H)
-            out = jnp.sum(picked * topv[..., None].astype(picked.dtype), axis=1)
-        return out.reshape(b, s, -1)
-
-    def one_layer(carry, layer):
-        h, ck, cv = carry
-        p, i = layer
-        attn = p["self_attn"]
-        hn = rms_norm(h, p["input_layernorm"]["weight"].astype(h.dtype), cfg.rms_norm_eps)
-        q, k_new, v_new = _qkv_proj(attn, hn, cos, sin)
-        ck, cv, k_i, v_i = _cache_step(ck, cv, k_new, v_new, i, start)
-        out = _attend(q, k_i, v_i, positions, kv_valid)
-        h = h + _out_proj(out, attn["o_proj"]["kernel"])
-        hn = rms_norm(h, p["post_attention_layernorm"]["weight"].astype(h.dtype), cfg.rms_norm_eps)
-        h = h + moe(p["moe"], hn)
-        return (h, ck, cv), None
-
-    layers = jnp.arange(cache.k.shape[0], dtype=jnp.int32)
-    (x, new_k, new_v), _ = jax.lax.scan(one_layer, (x, cache.k, cache.v), (stacked, layers))
-    x = rms_norm(x, model_p["norm"]["weight"].astype(x.dtype), cfg.rms_norm_eps)
-    with jax.named_scope("lm_head"):
-        logits = (x if return_all else x[:, -1]) @ params["lm_head"]["kernel"].astype(cfg.dtype)
-    return logits.astype(jnp.float32), KVCache(new_k, new_v, start + s)
+    return (x, gp["layers"]["block"], block,
+            lambda x: _layer_norm(x, gp["final_layer_norm"], cfg.layer_norm_eps),
+            lambda h_out: h_out @ params["embed_out"]["kernel"].astype(cfg.dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -733,7 +525,8 @@ def _t5_self_bias(cfg, table, q_positions, t_max):
 
 def _t5_decode(cfg, params, input_ids, cache: KVCache, enc: EncDecState, return_all=False):
     """Cached T5 decoder: block_0 (bias owner) + lax.scan over the stacked
-    rest — exactly the T5Stack split (models/t5.py). No 1/sqrt(d) scaling
+    rest — exactly the T5Stack split (models/t5.py), which is why it keeps a
+    loop of its own beside :func:`_forward_cached`. No 1/sqrt(d) scaling
     (T5's initializer absorbs it); scores and softmax in fp32."""
     if not cfg.scan_layers:
         raise ValueError("generation requires scan_layers=True (stacked blocks)")
@@ -742,15 +535,16 @@ def _t5_decode(cfg, params, input_ids, cache: KVCache, enc: EncDecState, return_
     eps = cfg.layer_norm_epsilon
 
     b, s = input_ids.shape
-    t_max = cache.k.shape[2]
+    t_max = cache.t_max
     start = cache.length
-    positions = jnp.broadcast_to(start + jnp.arange(s, dtype=jnp.int32)[None, :], (b, s))
+    positions = _row_positions(start, b, s)
 
     y = jnp.take(shared, input_ids, axis=0).astype(cfg.dtype)
     bias_table = dec["block_0"]["self_attn"]["relative_attention_bias"]["embedding"]
     self_bias = _t5_self_bias(cfg, bias_table, positions, t_max)  # (B,H,Sq,T)
 
     def self_attend(q, ck, cv):
+        ck, cv = float_pages(ck, q.dtype), float_pages(cv, q.dtype)
         scores = jnp.einsum("bqhd,bkhd->bhqk", q, ck).astype(jnp.float32) + self_bias
         kv_pos = jnp.arange(t_max, dtype=jnp.int32)[None, :]
         causal = kv_pos[None, :, :] <= positions[:, :, None]  # (B,Sq,T)
@@ -764,7 +558,7 @@ def _t5_decode(cfg, params, input_ids, cache: KVCache, enc: EncDecState, return_
         q = _proj(hn, a["q"]["kernel"])
         k_new = _proj(hn, a["k"]["kernel"])
         v_new = _proj(hn, a["v"]["kernel"])
-        ck, cv, k_i, v_i = _cache_step(ck, cv, k_new, v_new, i, start)
+        ck, cv, k_i, v_i = kv_cache.cache_step(ck, cv, k_new, v_new, i, start)
         out = self_attend(q, k_i, v_i)
         h = h + _out_proj(out, a["o"]["kernel"])
 
@@ -784,7 +578,7 @@ def _t5_decode(cfg, params, input_ids, cache: KVCache, enc: EncDecState, return_
     def one_layer(carry, layer):
         return block(*carry, *layer), None
 
-    layers = jnp.arange(1, cache.k.shape[0], dtype=jnp.int32)
+    layers = jnp.arange(1, cache.n_layers, dtype=jnp.int32)
     (y, new_k, new_v), _ = jax.lax.scan(
         one_layer, carry, (dec["layers"]["block"], layers, enc.cross_k[1:], enc.cross_v[1:])
     )
@@ -809,38 +603,27 @@ def _whisper_encode(cfg, params, input_features) -> EncDecState:
     return EncDecState(k, v, None)
 
 
-def _whisper_decode(cfg, params, input_ids, cache: KVCache, enc: EncDecState, return_all=False):
-    """Cached Whisper decoder (mirrors models/whisper.py: pre-LN blocks,
-    learned positions, biased q/v projections, no K bias, tied head)."""
-    if not cfg.scan_layers:
-        raise ValueError("generation requires scan_layers=True (stacked blocks)")
+def _whisper_decoder(enc: EncDecState, cfg, params, input_ids, pos_ids):
+    """Whisper's decoder (mirrors models/whisper.py: pre-LN blocks, learned
+    positions, biased q/v projections, no K bias, tied head); each layer's
+    cross K/V ride the layer loop beside its params."""
     dec = params["decoder"]
-    stacked = dec["layers"]["block"]
     embed = dec["embed_tokens"]["embedding"]
     eps = cfg.layer_norm_eps
-    d = cfg.decoder_head_dim
-    scale = 1.0 / np.sqrt(d)
-
-    b, s = input_ids.shape
-    start = cache.length
-    positions = jnp.broadcast_to(start + jnp.arange(s, dtype=jnp.int32)[None, :], (b, s))
+    scale = 1.0 / np.sqrt(cfg.decoder_head_dim)
 
     y = jnp.take(embed, input_ids, axis=0).astype(cfg.dtype)
-    y = y + jnp.take(dec["embed_positions"]["embedding"], positions[0], axis=0).astype(cfg.dtype)
+    y = y + jnp.take(dec["embed_positions"]["embedding"], pos_ids[0], axis=0).astype(cfg.dtype)
 
     def proj_b(x, p):  # DenseGeneral with bias
         return _proj(x, p["kernel"]) + p["bias"].astype(x.dtype)
 
-    def one_layer(carry, layer):
-        h, ck, cv = carry
-        p, i, xk, xv = layer
+    def block(p, h, attend, xk, xv):
         a = p["self_attn"]
         hn = _layer_norm(h, p["self_attn_layer_norm"], eps)
         q = proj_b(hn, a["q_proj"])  # _attend applies the 1/sqrt(d) scale
         k_new = _proj(hn, a["k_proj"]["kernel"])  # Whisper: no K bias
-        v_new = proj_b(hn, a["v_proj"])
-        ck, cv, k_i, v_i = _cache_step(ck, cv, k_new, v_new, i, start)
-        out = _attend(q, k_i, v_i, positions)
+        out = attend(q, k_new, proj_b(hn, a["v_proj"]))
         h = h + _out_proj(out, a["out_proj"]["kernel"]) + a["out_proj"]["bias"].astype(h.dtype)
 
         c = p["encoder_attn"]
@@ -850,20 +633,18 @@ def _whisper_decode(cfg, params, input_ids, cache: KVCache, enc: EncDecState, re
         h = h + _out_proj(out, c["out_proj"]["kernel"]) + c["out_proj"]["bias"].astype(h.dtype)
 
         hn = _layer_norm(h, p["final_layer_norm"], eps)
-        mid = jax.nn.gelu(
-            hn @ p["fc1"]["kernel"].astype(hn.dtype) + p["fc1"]["bias"].astype(hn.dtype),
-            approximate=False,
-        )
-        h = h + mid @ p["fc2"]["kernel"].astype(mid.dtype) + p["fc2"]["bias"].astype(mid.dtype)
-        return (h, ck, cv), None
+        mid = jax.nn.gelu(_dense(p["fc1"], hn), approximate=False)
+        return h + mid @ p["fc2"]["kernel"].astype(mid.dtype) + p["fc2"]["bias"].astype(mid.dtype)
 
-    layers = jnp.arange(cache.k.shape[0], dtype=jnp.int32)
-    (y, new_k, new_v), _ = jax.lax.scan(
-        one_layer, (y, cache.k, cache.v), (stacked, layers, enc.cross_k, enc.cross_v)
-    )
-    y = _layer_norm(y, dec["layer_norm"], eps)
-    logits = (y if return_all else y[:, -1]) @ embed.T.astype(cfg.dtype)
-    return logits.astype(jnp.float32), KVCache(new_k, new_v, start + s)
+    return (y, dec["layers"]["block"], block,
+            lambda y: _layer_norm(y, dec["layer_norm"], eps),
+            lambda h_out: h_out @ embed.T.astype(cfg.dtype),
+            enc.cross_k, enc.cross_v)
+
+
+def _whisper_decode(cfg, params, input_ids, cache: KVCache, enc: EncDecState, return_all=False):
+    """Cached Whisper decoder, through the decoder-only families' loop."""
+    return _forward_cached(partial(_whisper_decoder, enc), cfg, params, input_ids, cache, return_all)
 
 
 # module class name -> (encode(cfg, params, enc_inputs) -> EncDecState,
@@ -881,10 +662,10 @@ def register_encdec_generation_plan(module_class_name: str, encode_fn, decode_fn
 # module class name -> forward_cached(cfg, params, ids, cache)
 GENERATION_PLANS: dict[str, Callable] = {
     "LlamaForCausalLM": _llama_forward_cached,
-    "GPT2LMHeadModel": _gpt2_forward_cached,
-    "OPTForCausalLM": _opt_forward_cached,
-    "GPTNeoXForCausalLM": _neox_forward_cached,
-    "MixtralForCausalLM": _mixtral_forward_cached,
+    "GPT2LMHeadModel": partial(_forward_cached, _gpt2_decoder),
+    "OPTForCausalLM": partial(_forward_cached, _opt_decoder),
+    "GPTNeoXForCausalLM": partial(_forward_cached, _neox_decoder),
+    "MixtralForCausalLM": _llama_forward_cached,
 }
 
 
@@ -1080,7 +861,7 @@ def generate(
             s = s_b
 
     t_max = s + max_new_tokens
-    max_pos = _cache_dims(cfg)[3]
+    max_pos = cache_spec(cfg).max_positions
     if t_max > max_pos:
         raise ValueError(
             f"{t_max} tokens exceeds max_position_embeddings={max_pos}"
@@ -1304,7 +1085,7 @@ def speculative_generate(
     if b != 1:
         raise ValueError("speculative_generate supports batch size 1")
     t_max = s + max_new_tokens + num_draft_tokens + 1
-    if t_max > min(_cache_dims(cfg)[3], _cache_dims(dcfg)[3]):
+    if t_max > min(cache_spec(cfg).max_positions, cache_spec(dcfg).max_positions):
         raise ValueError("sequence would exceed max positions")
 
     target_step = _plan_jit(fwd, cfg, static_return_all=True)
@@ -1359,12 +1140,8 @@ def speculative_generate(
         # proposals' slots already hold the right K/V) and the carried logits
         # refresh.
         rewind = jnp.asarray(out.shape[1] - 1, jnp.int32)
-        tlogits, tcache = target_step(
-            model.params, out[:, -1:], KVCache(tc.k, tc.v, rewind)
-        )
-        dlogits, dcache = draft_step(
-            draft_model.params, out[:, -1:], KVCache(dc.k, dc.v, rewind)
-        )
+        tlogits, tcache = target_step(model.params, out[:, -1:], tc._replace(length=rewind))
+        dlogits, dcache = draft_step(draft_model.params, out[:, -1:], dc._replace(length=rewind))
 
     # Pad to the full length if EOS ended the loop early.
     if out.shape[1] < s + max_new_tokens:
@@ -1416,7 +1193,7 @@ def beam_search(
     b, s = input_ids.shape
     k = num_beams
     t_max = s + max_new_tokens
-    max_pos = _cache_dims(cfg)[3]
+    max_pos = cache_spec(cfg).max_positions
     if t_max > max_pos:
         raise ValueError(f"{t_max} tokens exceeds max_position_embeddings={max_pos}")
 
@@ -1425,11 +1202,7 @@ def beam_search(
     logp = jax.nn.log_softmax(logits, axis=-1)  # (B, V)
     v = logp.shape[-1]
 
-    # Tile the cache across beams: (L, B, ...) → (L, B*K, ...).
-    def tile(x):
-        return jnp.repeat(x, k, axis=1)
-
-    cache = KVCache(tile(cache.k), tile(cache.v), cache.length)
+    cache = cache.take_batch(jnp.repeat(jnp.arange(b), k))  # a row's K beams side by side
     # Beam 0 carries the prompt's logp; others start dead so the first step
     # picks K distinct tokens from beam 0's distribution.
     scores = jnp.full((b, k), -jnp.inf).at[:, 0].set(0.0)
@@ -1470,11 +1243,7 @@ def beam_search(
         )
 
         flat_beam = (jnp.arange(b)[:, None] * k + beam_idx).reshape(-1)
-        cache = KVCache(
-            jnp.take(cache.k, flat_beam, axis=1),
-            jnp.take(cache.v, flat_beam, axis=1),
-            cache.length,
-        )
+        cache = cache.take_batch(flat_beam)
         if t + 1 < max_new_tokens:
             logits, cache = decode(params, emit.reshape(b * k, 1), cache)
             cand_logp = jax.nn.log_softmax(logits, axis=-1).reshape(b, k, v)

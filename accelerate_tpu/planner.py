@@ -1119,18 +1119,13 @@ class DisaggSlicePlan:
 
 
 def kv_bytes_per_token(cfg, dtype=None) -> int:
-    """Bytes one prompt token's committed K+V pages occupy across every
-    layer — the unit the handoff link is priced in. int8 pages carry one
-    f32 absmax scale per head per layer (QuantPages), included here so
-    the quantized handoff is priced on what actually moves."""
-    from .generation import _cache_dims
+    """Bytes one prompt token's committed K and V occupy across every layer
+    — the unit the handoff link is priced in, as the cache itself counts it
+    (an int8 cache's scales included), so the handoff is priced on what
+    actually moves."""
+    from . import kv_cache
 
-    layers, kv_heads, head_dim, _ = _cache_dims(cfg)
-    dt = np.dtype(dtype or getattr(cfg, "dtype", np.float32))
-    per_page = head_dim * dt.itemsize
-    if dt == np.int8:
-        per_page += 4  # the QuantPages f32 dequant scale
-    return 2 * layers * kv_heads * per_page
+    return kv_cache.kv_bytes_per_token(cfg, dtype)
 
 
 def plan_disagg_slices(

@@ -7,10 +7,11 @@ slowest row finishes. Under mixed-length traffic most of the chip burns on
 finished rows and every new prompt shape recompiles. This module is the
 vLLM/TGI-class fix, TPU-shaped:
 
-- **Slot-paged KV cache** — ONE ``(L, n_slots, T_max, Hkv, D)`` buffer pair
-  (generation.py :func:`init_slot_cache`) whose ``length`` is a per-slot
-  vector; a request occupies a slot for exactly its own lifetime and the
-  slot is reused mid-flight with no reshape and no recompile.
+- **Slot KV cache** — ONE dense buffer pair, ``T_max`` private rows a slot
+  (no pages, no sharing; kv_cache.py :func:`init_slot_cache`), whose
+  ``length`` is a per-slot vector; a request occupies a slot for exactly its
+  own lifetime and the slot is reused mid-flight with no reshape and no
+  recompile.
 - **Admission scheduler** — incoming requests queue; free slots fill every
   tick; rows that emit EOS (or exhaust their budget) retire immediately and
   hand their slot to the next request.
@@ -97,12 +98,10 @@ from .chaos import InjectedFaultError
 from .generation import (
     ENCDEC_GENERATION_PLANS,
     GENERATION_PLANS,
-    KVCache,
-    _cache_dims,
     _filter_logits,
-    init_slot_cache,
     sample_logits,
 )
+from .kv_cache import KVCache, cache_spec, init_slot_cache
 from .logging import get_logger
 from .utils.constants import PREEMPTION_EXIT_CODE, SERVING_CRASH_EXIT_CODE
 
@@ -275,7 +274,7 @@ def _build_decode_step(fwd, cfg, temperature, top_k, top_p, eos_token_id,
     are NOT (the weight-publication hot swap relies on rebinding them
     without invalidating live buffers). The donated cache stays ONE buffer a
     side through the step: the forward's layer loop carries it whole and
-    scatters each slot's new rows into it in place (``generation._cache_step``),
+    scatters each slot's new rows into it in place (``kv_cache.cache_step``),
     so a step copies no cache and holds no second one beside it.
 
     Both modes return the same 5-tuple
@@ -350,7 +349,7 @@ def _build_decode_step(fwd, cfg, temperature, top_k, top_p, eos_token_id,
                 rng=_select_keys(live, carry, state.rng),
                 history=state.history,
             )
-            return (KVCache(new_cache.k, new_cache.v, lengths), new_state,
+            return (new_cache._replace(length=lengths), new_state,
                     tok[:, None], live.astype(jnp.int32), bad)
 
         # ---- speculative path: draft k, verify k+1 in ONE forward ----
@@ -438,7 +437,7 @@ def _build_decode_step(fwd, cfg, temperature, top_k, top_p, eos_token_id,
             rng=_select_keys(live, carry, state.rng),
             history=hist,
         )
-        return (KVCache(new_cache.k, new_cache.v, lengths), new_state,
+        return (new_cache._replace(length=lengths), new_state,
                 out, e, bad)
 
     return jax.jit(decode, donate_argnums=(1, 2))
@@ -453,25 +452,11 @@ def _build_prefill_step(fwd, cfg, temperature, top_k, top_p, eos_token_id):
     def prefill(params, cache: KVCache, state: SlotState, chunk, slot, valid,
                 budget, rng, is_first, is_final):
         start = jnp.where(is_first, 0, cache.length[slot])
-        # tree.map: a float cache is a single array per side; quantized KV
-        # pages (QuantPages) are a data+scale subtree with the slot axis in
-        # the same position on both leaves.
-        sub_cache = KVCache(
-            jax.tree.map(
-                lambda a: jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=1),
-                cache.k),
-            jax.tree.map(
-                lambda a: jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=1),
-                cache.v),
-            start[None],  # (1,) per-row vector — the slot-paged fwd path
-        )
+        # The slot alone, its length a (1,) per-row vector: the slot cache's
+        # forward path, at the shape a batch-1 prefill has.
+        sub_cache = cache.take_slot(slot, start)
         logits_all, sub_cache = fwd(cfg, params, chunk, sub_cache, return_all=True)
-        k = jax.tree.map(
-            lambda a, s: jax.lax.dynamic_update_slice_in_dim(a, s, slot, axis=1),
-            cache.k, sub_cache.k)
-        v = jax.tree.map(
-            lambda a, s: jax.lax.dynamic_update_slice_in_dim(a, s, slot, axis=1),
-            cache.v, sub_cache.v)
+        cache = cache.put_slot(slot, sub_cache)
         # Advance by the VALID tokens only; a padded tail is overwritten by
         # the next write and never attended (causal bound at true length).
         lengths = cache.length.at[slot].set(start + valid)
@@ -510,7 +495,7 @@ def _build_prefill_step(fwd, cfg, temperature, top_k, top_p, eos_token_id):
             rng=state.rng.at[slot].set(carry),
             history=state.history.at[slot].set(hist2),
         )
-        return KVCache(k, v, lengths), new_state, tok, done0
+        return cache._replace(length=lengths), new_state, tok, done0
 
     return jax.jit(prefill, donate_argnums=(1, 2))
 
@@ -806,7 +791,7 @@ class ServingEngine:
 
         c = self.config
         self.n_slots = int(c.n_slots)
-        max_pos = _cache_dims(self.cfg)[3]
+        max_pos = cache_spec(self.cfg).max_positions
         self.t_max = int(c.max_len) if c.max_len else int(min(max_pos, 4096))
         if self.t_max > max_pos:
             raise ValueError(
@@ -1763,21 +1748,16 @@ class ServingEngine:
         sentinel must catch it. A separate lazily-jitted program — never
         compiled unless a poison fault actually fires, so the decode
         executable census is untouched."""
-        if not jnp.issubdtype(self._cache.k.dtype, jnp.floating):
+        if not self._cache.holds_nan:
             if _log_ok():
                 logger.warning_once(
                     "serving: poison fault skipped — cache dtype "
-                    f"{self._cache.k.dtype} has no NaN"
+                    f"{self._cache.dtype} has no NaN"
                 )
             return
         if self._poison_op is None:
-            def poison(cache: KVCache, slot):
-                return KVCache(
-                    cache.k.at[:, slot].set(jnp.nan),
-                    cache.v.at[:, slot].set(jnp.nan),
-                    cache.length,
-                )
-            self._poison_op = jax.jit(poison, donate_argnums=(0,))
+            self._poison_op = jax.jit(
+                lambda cache, slot: cache.fill_slot(slot, jnp.nan), donate_argnums=(0,))
         self._cache = self._poison_op(self._cache, np.int32(slot))
 
     def _spoil_history(self, slot: int) -> None:

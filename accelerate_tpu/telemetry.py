@@ -373,7 +373,7 @@ class TelemetryRecorder:
                         # leaves its output shardings to GSPMD, which on a
                         # mesh may return state leaves in another sharding
                         # than prepare() gave them, so the second call sees
-                        # new input shardings (bench.py warms up twice for
+                        # new input shardings (chip_smoke.py warms up twice for
                         # this). Counted and recorded, not warning-worthy.
                         entry["layout_recompiled"] = True
                         reason = "state shardings settle (expected once)"

@@ -7,7 +7,7 @@ mesh.
   (left-padded to the batch max prompt, every row running the batch max
   budget) — today's default serving story;
 - **serving** — the same request set through :class:`ServingEngine`
-  (slot-paged cache, chunked prefill, continuous admission).
+  (dense slot cache, chunked prefill, continuous admission).
 
 Asserts: every request completes; per-request continuations are BIT-EQUAL
 between the two paths; the engine's decode steady state is ONE executable

@@ -642,9 +642,9 @@ class ServingConfig(KwargsHandler):
     ``accelerator.build_serving_engine(model)`` can construct an engine
     wired to the compile manager and telemetry recorder.
 
-    - ``n_slots``: concurrent sequences — the slot-paged KV cache is
-      ``(L, n_slots, max_len, Hkv, D)``; one decode tick advances every
-      live slot. Size it to the HBM left after params: bigger = higher
+    - ``n_slots``: concurrent sequences — the KV cache is one dense buffer
+      pair holding ``max_len`` private rows for each slot (no pages, no
+      sharing between slots); one decode tick advances every live slot. Size it to the HBM left after params: bigger = higher
       aggregate tokens/s, until the decode step goes compute-bound.
     - ``max_len``: per-slot capacity (prompt + continuation); default
       ``min(max_position_embeddings, 4096)``. ``submit`` rejects requests
@@ -662,7 +662,7 @@ class ServingConfig(KwargsHandler):
       budget; ``submit``/``run`` override it per request.
     - ``cache_dtype``: KV-cache dtype override (default: model dtype).
       ``jnp.int8`` switches the slot cache to quantized KV pages
-      (``generation.QuantPages``: int8 data + per-page absmax scales) —
+      (``kv_cache.QuantPages``: int8 data + an absmax scale per row and head) —
       attention dequantizes in-kernel and disagg handoff moves ~4x fewer
       bytes; see docs/usage_guides/serving.md "Quantized KV pages".
     - ``seed``: seeds the idle slots' PRNG pool; each request's stream is

@@ -376,7 +376,7 @@ def broadcast(tensor, from_process: int = 0):
 
 
 # One collective costs the same for any payload up to ~1 MB (fixed dispatch
-# cost dominates; benchmarks/input_pipeline_bench.py), so small objects ride
+# cost dominates), so small objects ride
 # a single fixed-size broadcast with the length inline — halving the fixed
 # cost vs the naive length-round-then-data protocol. Larger payloads fall
 # back to a second, exact-size collective; the header makes the decision

@@ -10,7 +10,7 @@ Four phases, each fatal; exit 0 only if all passed:
               against ``blockwise_attention`` in float32 at "highest" matmul
               precision, at the trainer's shape and one GQA shape.
 3. trainer  - ``Accelerator`` -> ``Model.from_flax`` -> ``prepare`` ->
-              ``prepare_train_step`` on the 1.06B Llama ``bench.py`` runs
+              ``prepare_train_step`` on a 1.06B Llama (:func:`llama_1b`)
               (full width, seq 2048, bf16, FSDP over every chip): two warm-up
               steps then five. On four or more chips a second, shorter pass
               runs it under dp_shard x tp=2.
@@ -35,8 +35,6 @@ import time
 
 import numpy as np
 
-import bench
-
 # bf16 keeps 8 bits of mantissa (one rounding is 2^-9, about 0.2%). The
 # kernels round P and dS to bf16 before the MXU and each output once more, so
 # a correct kernel lands within about 1% of its tensor's largest value of the
@@ -51,7 +49,99 @@ LOGIT_RMS_TOL = 5e-2
 PROMPT_LENS = (128, 200, 333, 512, 640, 777, 900, 1024)
 NEW_TOKENS = 64
 SEQ = 2048
-PER_CHIP_BATCH, REMAT_POLICY = bench.TRAIN_SHAPES[SEQ]
+# What fits one 16 GB v5e at that length beside bf16 params, bf16 Adam moments
+# and gradients (PERF.md, PR 21).
+PER_CHIP_BATCH, REMAT_POLICY = 2, "dots"
+
+
+def require_tpu() -> None:
+    """A nonzero exit naming what was found, unless the backend is a TPU."""
+    import jax
+
+    from accelerate_tpu.utils import is_tpu_available
+
+    if not is_tpu_available():
+        sys.exit(
+            f"no TPU: JAX's default backend is {jax.default_backend()!r} "
+            f"({jax.devices()[0].device_kind}). This program measures the "
+            "chip and does not fall back to another backend."
+        )
+
+
+def llama_1b(seq: int, remat_policy: str):
+    """The ~1.06B-param Llama the trainer phase runs. Width is fixed; only
+    batch and remat policy move to make it fit."""
+    import jax.numpy as jnp
+
+    from accelerate_tpu.models import LlamaConfig
+
+    return LlamaConfig(
+        vocab_size=32000,
+        hidden_size=2048,
+        intermediate_size=5632,
+        num_hidden_layers=18,
+        num_attention_heads=16,
+        num_key_value_heads=16,
+        max_position_embeddings=seq,
+        dtype=jnp.bfloat16,
+        remat=True,
+        remat_policy=remat_policy,
+        attention_impl="flash",
+    )
+
+
+def build_trainer(cfg, per_chip_batch: int, seq: int, *, parallelism_config=None,
+                  kwargs_handlers=None):
+    """The normal training path for ``cfg``: ``Accelerator`` (bf16, FSDP) ->
+    ``Model.from_flax`` -> ``prepare`` -> ``prepare_train_step``, on one fixed
+    seeded batch of ``per_chip_batch`` sequences per chip.
+
+    Params and Adam moments are bf16: 1B of fp32 masters + fp32 moments +
+    gradients does not fit a 16 GB chip. Returns ``(acc, model, step,
+    batch)``; the state is ``acc.train_state``."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from accelerate_tpu import Accelerator, Model
+    from accelerate_tpu.models import LlamaForCausalLM, cross_entropy_loss
+    from accelerate_tpu.state import AcceleratorState, GradientState, PartialState
+    from accelerate_tpu.utils import FullyShardedDataParallelPlugin, set_seed
+
+    AcceleratorState._reset_state()
+    GradientState._reset_state()
+    PartialState._reset_state()
+    set_seed(0)
+
+    module = LlamaForCausalLM(cfg)
+    batch = per_chip_batch * jax.device_count()
+    ids = np.random.default_rng(0).integers(
+        0, cfg.vocab_size, size=(batch, seq + 1), dtype=np.int32)
+
+    acc = Accelerator(
+        mixed_precision="bf16",
+        fsdp_plugin=FullyShardedDataParallelPlugin(),
+        parallelism_config=parallelism_config,
+        kwargs_handlers=kwargs_handlers,
+    )
+    model = Model.from_flax(module, jax.random.key(0), ids[:, :-1])
+    model.params = jax.tree.map(lambda p: p.astype(jnp.bfloat16), model.params)
+    tx = optax.adamw(3e-4, weight_decay=0.1, mu_dtype=jnp.bfloat16)
+    model, _ = acc.prepare(model, tx)
+
+    def loss_fn(params, b):
+        logits = module.apply({"params": params}, b["x"])
+        return cross_entropy_loss(logits, b["y"])
+
+    step = acc.prepare_train_step(loss_fn, max_grad_norm=1.0)
+    sharding = NamedSharding(acc.mesh, PartitionSpec(("dp_replicate", "dp_shard")))
+    b = {
+        "x": jax.device_put(ids[:, :-1], sharding),
+        "y": jax.device_put(ids[:, 1:], sharding),
+    }
+    return acc, model, step, b
+
 
 _TRACE_EVENTS = (
     "/jax/core/compile/jaxpr_trace_duration",
@@ -221,8 +311,8 @@ def trainer_phase(s: Smoke, name: str, parallelism_config=None, steps: int = 5):
                                attention_impl="flash", num_key_value_heads=4)
         per_chip_batch, seq = 2, 128
     else:
-        cfg, per_chip_batch, seq = bench.llama_1b(SEQ, REMAT_POLICY), PER_CHIP_BATCH, SEQ
-    acc, model, step, batch = bench.build_trainer(
+        cfg, per_chip_batch, seq = llama_1b(SEQ, REMAT_POLICY), PER_CHIP_BATCH, SEQ
+    acc, model, step, batch = build_trainer(
         cfg, per_chip_batch, seq, parallelism_config=parallelism_config)
     s.say(f"{name}: {model.num_parameters() / 1e9:.3f}B params, hidden {cfg.hidden_size} x "
           f"{cfg.num_hidden_layers} layers, seq {seq}, per-chip batch {per_chip_batch} "
@@ -364,7 +454,7 @@ def main() -> int:
 
     cache_dir = place_compile_cache()
     if not args.rehearse:
-        bench.require_tpu()
+        require_tpu()
     s = Smoke(args.rehearse)
 
     with s.phase("device"):

@@ -1,5 +1,5 @@
-"""The chip entry points refuse to run without a chip, and the control flow
-of ``chip_smoke.py`` stays runnable (``--rehearse``) between chip runs."""
+"""``chip_smoke.py`` refuses to run without a chip, and its control flow stays
+runnable (``--rehearse``) between chip runs."""
 
 import json
 import os
@@ -20,7 +20,7 @@ def _run(script, *args, timeout):
     )
 
 
-@pytest.mark.parametrize("script", ["bench.py", "chip_smoke.py"])
+@pytest.mark.parametrize("script", ["chip_smoke.py"])
 def test_no_chip_is_a_failure_not_a_cpu_run(script):
     """Off-chip: nonzero exit, the missing TPU named, and no result row —
     never a CPU number under a device metric's name."""

@@ -92,9 +92,9 @@ def test_plan_disagg_prices_handoff(llama):
     cfg, _ = llama
     kvb = kv_bytes_per_token(cfg, dtype=np.float32)
     # 2 (K and V) * layers * kv_heads * head_dim * itemsize.
-    from accelerate_tpu.generation import _cache_dims
+    from accelerate_tpu.kv_cache import cache_spec
 
-    layers, kv_heads, head_dim, _ = _cache_dims(cfg)
+    layers, kv_heads, head_dim, _ = cache_spec(cfg)
     assert kvb == 2 * layers * kv_heads * head_dim * 4
     bw = BandwidthTable()
     plan = plan_disagg_slices(8, prefill_decode_flop_ratio=2.0, bw=bw,
@@ -119,9 +119,9 @@ def test_plan_disagg_prices_int8_pages_at_half_bf16(llama):
     assert kvb_int8 == kv_bytes_per_token(cfg, dtype=np.int8)
     # "~half": exactly (head_dim + 4) / (2 * head_dim) — the +4-byte f32
     # absmax scale per page keeps it just over 0.5.
-    from accelerate_tpu.generation import _cache_dims
+    from accelerate_tpu.kv_cache import cache_spec
 
-    _, _, head_dim, _ = _cache_dims(cfg)
+    head_dim = cache_spec(cfg).head_dim
     assert kvb_int8 / kvb_bf16 == (head_dim + 4) / (2 * head_dim)
     assert kvb_int8 / kvb_bf16 == pytest.approx(0.5, rel=0.15)
     assert kvb_int8 < kvb_bf16 < kv_bytes_per_token(cfg, dtype=np.float32)

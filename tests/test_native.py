@@ -132,7 +132,7 @@ def test_prefetch_overlaps_producer_with_step():
     producer whose cost is a large fraction of the step time adds (almost)
     nothing to wall-clock; without it, the producer serializes. Margins are
     deliberately wide — this is a regression gate on the overlap mechanism,
-    not a microbenchmark (numbers: benchmarks/input_pipeline_bench.py)."""
+    not a microbenchmark."""
     import time
 
     from accelerate_tpu.data_loader import _PrefetchIterator

@@ -3,8 +3,8 @@
 CI runs the whole suite on the virtual CPU mesh, which exercises the Pallas
 kernels in *interpreter* mode only. A compiled-lowering regression (Mosaic
 tiling, SMEM prefetch, scalar-prefetch offsets) is invisible to that suite.
-This tier is the compiled-mode health check, kept separable from `bench.py`
-so kernel status costs a few minutes of chip time.
+This tier is the compiled-mode health check, kept separable from the
+benchmark so kernel status costs a few minutes of chip time.
 
 Run via `make test_tpu` (sets ACCELERATE_TEST_USE_TPU=1, serial). Without
 that variable the tier skips; with it, a chip that cannot be reached is a

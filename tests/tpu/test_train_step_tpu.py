@@ -4,7 +4,7 @@ The cheap end-to-end canary: a ~125M Llama fused train step (bf16 compute,
 Pallas flash attention, remat) must compile and produce a finite decreasing
 loss on hardware. Catches on-chip-only failures (Mosaic lowering inside the
 full model, compile-time OOM, donation) in about a minute. The 1B model is
-``chip_smoke.py``'s and ``bench.py``'s job.
+``chip_smoke.py``'s job.
 """
 
 import numpy as np
