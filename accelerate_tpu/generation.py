@@ -229,8 +229,18 @@ def _forward_cached(decoder, cfg, params, input_ids, cache: KVCache, return_all=
 
 
 def _moe_mlp(cfg, p, h):
-    """Mixtral's routed sparse MLP on raw params (mirrors models/moe.py —
-    dropless here since decode batches are tiny)."""
+    """Mixtral's routed sparse MLP on raw params: softmax router, top-k, gates
+    renormalised over the k; dropless (every routed (token, expert) product
+    is computed, no capacity). The T tokens are contracted with the stacked
+    expert weights ``(E, H, F)`` / ``(E, F, H)`` where they lie — the expert
+    axis is a dimension of the dots, never an index — so under the layer
+    scan the layer's slice of the stack fuses into each dot and every expert
+    is read once a layer, which is the floor when a full batch reaches all
+    experts. Indexing ``w[e]`` under a ``vmap`` instead is a gather that XLA
+    expands into a copy of all three tensors, every layer of every step. All
+    E experts are computed for every token; the products of the experts a
+    token is not routed to are masked out (``where``, not a gate of zero: an
+    overflow there must not reach the sum as 0 x inf)."""
     b, s = h.shape[:2]
     tokens = h.reshape(b * s, -1)
     with jax.named_scope("moe.router"):
@@ -238,19 +248,19 @@ def _moe_mlp(cfg, p, h):
         probs = jax.nn.softmax(router_logits, axis=-1)
         topv, topi = jax.lax.top_k(probs, cfg.num_experts_per_tok)  # (T, k)
         topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
-
-    # Dense dispatch over experts: fine at decode sizes, exact (dropless).
-    def per_expert(e):
-        gate = jax.nn.silu(tokens @ p["w_gate"][e].astype(tokens.dtype))
-        up = tokens @ p["w_up"][e].astype(tokens.dtype)
-        return (gate * up) @ p["w_down"][e].astype(tokens.dtype)
+        # (T, E): a token's gate on each expert it is routed to, zero elsewhere
+        routed = topi[..., None] == jnp.arange(cfg.num_local_experts)  # (T, k, E)
+        gates = jnp.sum(jnp.where(routed, topv[..., None], 0.0), axis=1)
 
     with jax.named_scope("moe.experts"):
-        expert_out = jax.vmap(per_expert)(jnp.arange(cfg.num_local_experts))  # (E, T, H)
-        picked = jnp.take_along_axis(
-            jnp.transpose(expert_out, (1, 0, 2)), topi[..., None], axis=1
-        )  # (T, k, H)
-        out = jnp.sum(picked * topv[..., None].astype(picked.dtype), axis=1)
+        dt = tokens.dtype
+        gate = jax.nn.silu(jnp.einsum("th,ehf->tef", tokens, p["w_gate"].astype(dt)))
+        up = jnp.einsum("th,ehf->tef", tokens, p["w_up"].astype(dt))
+        # the gate folded into the activation: one contraction over (e, f)
+        # then sums the routed experts, and no (E, T, H) array exists
+        act = jnp.where(routed.any(axis=1)[..., None],
+                        gate * up * gates[..., None].astype(dt), 0)
+        out = jnp.einsum("tef,efh->th", act, p["w_down"].astype(dt))
     return out.reshape(b, s, -1)
 
 

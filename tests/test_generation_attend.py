@@ -4,7 +4,9 @@ cache holds them. First against a reference written here with the explicit
 the serving cells' programs, compiled for a described v5e chip: no array of
 the repeated shape is left, and no slice-sized copy stands in its place. The
 same compiles show that a decode step writes the cache in place: it holds no
-second copy of the cache and puts no layer's slice back into a stack.
+second copy of the cache and puts no layer's slice back into a stack; and that
+Mixtral's programs read a layer's experts where the stacked parameter holds
+them: nothing of an expert tensor's size is copied, gathered or broadcast.
 
 The topology is described in a fixture (never while a module is imported);
 ``tests/chipbench/test_chipbench_aot.py`` is the other file that does so."""
@@ -132,10 +134,12 @@ def test_the_steady_cell_compiles_without_the_gqa_repeat(cell_programs, case):
 
 # One layer's K and V slices in bf16 for the steady cell: the cache rides the layer
 # loop's carry and is written in place, so beside the donated cache a decode step
-# holds less than that. Mixtral's step still copies a layer's experts (ROADMAP S7).
+# holds less than that. Mixtral's step contracts over the stacked experts in place
+# (135.5 MB when this was written): a quarter of one of a layer's three expert
+# tensors in bf16, where a single copy of one would be the whole of it.
 @pytest.mark.parametrize("cell_name,temporaries_under", [
     ("mistral_serve_steady", 2 * 2 * 24 * 2048 * 8 * 128),
-    ("mixtral_serve_decode", 4_000_000_000),
+    ("mixtral_serve_decode", 2 * 8 * 4096 * 14336 // 4),
 ], ids=["steady", "mixtral"])
 @pytest.mark.parametrize("case", ["temporaries", "no_cache_sized_copy_or_put_back"])
 def test_the_decode_program_writes_the_cache_in_place(cell_programs, cell_name,
@@ -155,3 +159,23 @@ def test_the_decode_program_writes_the_cache_in_place(cell_programs, cell_name,
         moved = [name for n, op, name in _arrays(programs["decode"].as_text(), scheduled_only=True)
                  if n == cache and (op == "copy" or "dynamic-update-slice" in name)]
         assert not moved
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_mixtral_reads_a_layers_experts_where_they_lie(cell_programs, program):
+    cell, programs = cell_programs("mixtral_serve_decode")
+    cfg = cell.config
+    experts = cfg["num_local_experts"] * cfg["hidden_size"] * cfg["intermediate_size"]
+    # w_gate[e] under a vmap was a gather that XLA spelled as a loop of
+    # dynamic-slice -> dynamic-update-slice into a zero broadcast, all of this size
+    moving = ("copy", "gather", "broadcast", "dynamic-slice", "dynamic-update-slice")
+    moved = [name for n, op, name in _arrays(programs[program].as_text(), scheduled_only=True)
+             if n == experts and any(word in op or word in name for word in moving)]
+    assert not moved
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_the_steady_cell_runs_no_expert_code(cell_programs, program):
+    # the scope ``moe.experts`` and the parameter ``...moe__w_gate`` name it in Mixtral's text
+    assert "moe" in cell_programs("mixtral_serve_decode")[1][program].as_text()
+    assert "moe" not in cell_programs("mistral_serve_steady")[1][program].as_text()
