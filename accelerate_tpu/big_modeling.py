@@ -314,6 +314,9 @@ def _llama_like_spec(cfg, block_cls, norm_cls):
     tied or Dense head."""
     import flax.linen as nn
 
+    from .models.llama import require_single_pass
+
+    require_single_pass(cfg, "layer streaming")
     embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
                      param_dtype=jnp.float32)
     block = block_cls(cfg)

@@ -195,6 +195,13 @@ def _forward_cached(decoder, cfg, params, input_ids, cache: KVCache, return_all=
     cache: it writes the new K/V rows and attends over the layer
     (``cache_step`` then ``_attend``), so no block holds the buffers.
 
+    A model that runs its stack more than once over one set of weights
+    (``cache_spec(cfg).passes``, static) gets an outer ``lax.scan`` over the
+    passes around the same layer body: pass ``u`` writes and reads planes
+    ``u * L .. u * L + L - 1`` of the cache, which rides the carry of both
+    loops, and ``norm`` closes every pass, its output opening the next. With
+    one pass the program is the layer loop alone.
+
     Left-padded batches (the transformers convention): ``pad_offset`` (B,)
     counts each row's leading pads — the position ids the family embeds or
     rotates by shift down by it so row content starts at position 0 — and
@@ -221,9 +228,18 @@ def _forward_cached(decoder, cfg, params, input_ids, cache: KVCache, return_all=
 
         return (block(p, h, attend, *x_i), ck, cv), None
 
-    layers = jnp.arange(cache.n_layers, dtype=jnp.int32)
-    (x, new_k, new_v), _ = jax.lax.scan(one_layer, (x, cache.k, cache.v), (stacked, layers, *xs))
-    x = norm(x)
+    def one_pass(carry, planes):
+        (h, ck, cv), _ = jax.lax.scan(one_layer, carry, (stacked, planes, *xs))
+        return (norm(h), ck, cv), None
+
+    passes = cache_spec(cfg).passes
+    planes = jnp.arange(cache.n_layers, dtype=jnp.int32)
+    carry = (x, cache.k, cache.v)
+    if passes == 1:
+        (x, new_k, new_v), _ = one_pass(carry, planes)
+    else:
+        (x, new_k, new_v), _ = jax.lax.scan(
+            jax.named_scope("ut_pass")(one_pass), carry, planes.reshape(passes, -1))
     logits = head(x if return_all else x[:, -1])
     return logits.astype(jnp.float32), KVCache(new_k, new_v, start + s)
 
@@ -287,9 +303,13 @@ def _llama_decoder(cfg, params, input_ids, pos_ids):
         out = _out_proj(attend(q, k_new, v_new), attn["o_proj"]["kernel"])
         if "bias" in attn["o_proj"]:
             out = out + attn["o_proj"]["bias"].astype(out.dtype)
+        if "input_layernorm_2" in p:  # sandwich norm: the branch's output normed too
+            out = _chassis_norm(cfg, p["input_layernorm_2"], out)
         h = h + scale_residual(out, res_mult)
         hn = _chassis_norm(cfg, p["post_attention_layernorm"], h)
         ffn = _moe_mlp(cfg, p["moe"], hn) if "moe" in p else _mlp(cfg, p["mlp"], hn)
+        if "post_attention_layernorm_2" in p:
+            ffn = _chassis_norm(cfg, p["post_attention_layernorm_2"], ffn)
         return h + scale_residual(ffn, res_mult)
 
     def head(h_out):

@@ -1,7 +1,9 @@
 """The KV cache and everything that knows how it is laid out.
 
-One dense buffer a side: ``k`` and ``v`` are ``(L, B, T_max, Hkv, D)`` — layers,
-then slots (batch rows), then each slot's ``T_max`` private rows. There are no
+One dense buffer a side: ``k`` and ``v`` are ``(L, B, T_max, Hkv, D)`` — planes
+(one per layer, or one per pass and layer where a model runs its stack more
+than once: pass ``u``'s layer ``l`` is plane ``u * layers + l``), then slots
+(batch rows), then each slot's ``T_max`` private rows. There are no
 pages and no sharing: a slot owns its rows from 0 to ``T_max`` whether it has
 written them or not. An int8 cache holds each side as a :class:`QuantPages`
 pair of leaves (data and one scale per row and head) with the same leading
@@ -67,10 +69,11 @@ class CacheSpec(NamedTuple):
     """What a config asks of its cache. For encoder-decoder configs these
     describe the DECODER self-attention cache."""
 
-    layers: int
+    layers: int  # planes of the buffer: passes x the model's layers
     kv_heads: int
     head_dim: int
     max_positions: int  # T5's relative positions are unbounded: 2**30
+    passes: int = 1  # times the stack runs over its one set of weights
 
 
 def cache_spec(cfg) -> CacheSpec:
@@ -88,7 +91,8 @@ def cache_spec(cfg) -> CacheSpec:
         or cfg.n_head
     )
     max_pos = getattr(cfg, "max_position_embeddings", None) or cfg.n_positions
-    return CacheSpec(layers, kv_heads, cfg.head_dim, max_pos)
+    passes = getattr(cfg, "total_ut_steps", 1)  # each pass keeps its own K and V
+    return CacheSpec(passes * layers, kv_heads, cfg.head_dim, max_pos, passes)
 
 
 class KVCache(NamedTuple):

@@ -124,6 +124,9 @@ def llama_config_from_hf(hf: Any) -> "LlamaConfig":
     )
 
 
+_SANDWICH_NORMS = ("input_layernorm_2", "post_attention_layernorm_2")
+
+
 def llama_params_from_hf(cfg, sd: dict) -> dict:
     h, nh, nkv, d = cfg.hidden_size, cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     tree: dict = {"model": {}}
@@ -149,9 +152,15 @@ def llama_params_from_hf(cfg, sd: dict) -> dict:
                 "self_attn/k_proj/bias": _np(sd[p + "self_attn.k_proj.bias"]).reshape(nkv, d),
                 "self_attn/v_proj/bias": _np(sd[p + "self_attn.v_proj.bias"]).reshape(nkv, d),
             } if cfg.attention_bias else {}),
+            **({
+                f"{name}/weight": _np(sd[f"{p}{name}.weight"]) for name in _SANDWICH_NORMS
+            } if cfg.sandwich_norm else {}),
         })
     _place_layers(tree, _stack_layers(layers), cfg.scan_layers,
                   "model/layers/block", "model/layers_{i}", cfg.num_hidden_layers)
+    if cfg.early_exit_gate:
+        _set(tree, "model/early_exit_gate/kernel", _t(sd["model.early_exit_gate.weight"]))
+        _set(tree, "model/early_exit_gate/bias", _np(sd["model.early_exit_gate.bias"]))
     return tree
 
 
@@ -168,6 +177,11 @@ def llama_params_to_hf(cfg, params) -> dict:
         "self_attn/o_proj/kernel", "mlp/gate_proj/kernel", "mlp/up_proj/kernel",
         "mlp/down_proj/kernel", "input_layernorm/weight", "post_attention_layernorm/weight",
     ]
+    if cfg.sandwich_norm:
+        paths += [f"{name}/weight" for name in _SANDWICH_NORMS]
+    if cfg.early_exit_gate:
+        sd["model.early_exit_gate.weight"] = _get(params, "model/early_exit_gate/kernel").T
+        sd["model.early_exit_gate.bias"] = _get(params, "model/early_exit_gate/bias")
     for i, layer in enumerate(_collect_layers(
         params, cfg.scan_layers, "model/layers/block", "model/layers_{i}",
         cfg.num_hidden_layers, paths,
@@ -182,6 +196,9 @@ def llama_params_to_hf(cfg, params) -> dict:
         sd[p + "mlp.down_proj.weight"] = layer["mlp/down_proj/kernel"].T
         sd[p + "input_layernorm.weight"] = layer["input_layernorm/weight"]
         sd[p + "post_attention_layernorm.weight"] = layer["post_attention_layernorm/weight"]
+        if cfg.sandwich_norm:
+            for name in _SANDWICH_NORMS:
+                sd[f"{p}{name}.weight"] = layer[f"{name}/weight"]
     return {k: np.asarray(v) for k, v in sd.items()}
 
 
@@ -201,6 +218,32 @@ def gemma_config_from_hf(hf: Any) -> "LlamaConfig":
         hidden_act="gelu_tanh",
         rms_norm_plus_one=True,
         scale_embeddings=True,
+    )
+
+
+def ouro_config_from_hf(hf: Any) -> "LlamaConfig":
+    """Ouro (looped LM) rides the Llama family: the stack run
+    ``total_ut_steps`` times over one set of weights, sandwich norms
+    (``input_layernorm_2``, ``post_attention_layernorm_2``) and one exit gate
+    (``early_exit_gate``). ``early_exit_threshold`` under 1 is refused by
+    ``LlamaConfig`` itself; a window or a rope scaling is refused here, since
+    neither is computed."""
+    import dataclasses as _dc
+
+    g = (lambda k, d=None: hf.get(k, d)) if isinstance(hf, dict) else (
+        lambda k, d=None: getattr(hf, k, d)
+    )
+    if g("rope_scaling") is not None or (g("use_sliding_window") and g("sliding_window")):
+        raise NotImplementedError(
+            "ouro: rope_scaling and sliding_window are not computed; refusing rather than "
+            f"dropping them (rope_scaling={g('rope_scaling')!r}, "
+            f"sliding_window={g('sliding_window')!r})")
+    return _dc.replace(
+        llama_config_from_hf(hf),
+        total_ut_steps=int(g("total_ut_steps", 1)),
+        sandwich_norm=True,
+        early_exit_gate=True,
+        early_exit_threshold=float(g("early_exit_threshold", 1.0)),
     )
 
 
@@ -908,6 +951,7 @@ _FAMILIES = {
     "mistral": ("LlamaForCausalLM", llama_config_from_hf, llama_params_from_hf),
     "qwen2": ("LlamaForCausalLM", llama_config_from_hf, llama_params_from_hf),
     "gemma": ("LlamaForCausalLM", gemma_config_from_hf, llama_params_from_hf),
+    "ouro": ("LlamaForCausalLM", ouro_config_from_hf, llama_params_from_hf),
     "phi3": ("LlamaForCausalLM", phi3_config_from_hf, phi3_params_from_hf),
     "mixtral": ("MixtralForCausalLM", mixtral_config_from_hf, mixtral_params_from_hf),
     "gpt2": ("GPT2LMHeadModel", gpt2_config_from_hf, gpt2_params_from_hf),

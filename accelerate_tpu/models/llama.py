@@ -71,6 +71,18 @@ class LlamaConfig:
     hidden_act: str = "silu"            # "gelu_tanh" for Gemma's GeGLU
     rms_norm_plus_one: bool = False     # norm scale stored as (weight + 1)
     scale_embeddings: bool = False      # multiply embeddings by sqrt(hidden)
+    # A stack run several times over one set of weights (a looped, or
+    # universal, transformer; all default off → plain Llama). The same
+    # layers run ``total_ut_steps`` times, the final norm closes every pass
+    # and its output opens the next; a pass attends over its own keys and
+    # values, so the cache holds passes x layers planes (kv_cache.cache_spec).
+    total_ut_steps: int = 1
+    sandwich_norm: bool = False         # a second norm on each branch's output
+    early_exit_gate: bool = False       # Linear(H, 1) on every pass's output
+    # Exit at the first pass whose cumulative exit probability reaches this.
+    # 1 runs every pass, and is all that is computed: under 1 the rows of
+    # one batch would leave after different passes.
+    early_exit_threshold: float = 1.0
     dtype: Any = jnp.bfloat16          # compute dtype (params stay fp32 masters)
     scan_layers: bool = True
     remat: bool = False
@@ -96,6 +108,13 @@ class LlamaConfig:
             raise ValueError(
                 f"partial_rotary_factor {self.partial_rotary_factor} of head_dim "
                 f"{self.head_dim} gives odd rotary_dim {self.rotary_dim}"
+            )
+        if self.total_ut_steps < 1:
+            raise ValueError(f"total_ut_steps must be at least 1, got {self.total_ut_steps}")
+        if self.early_exit_threshold < 1:
+            raise ValueError(
+                f"early_exit_threshold {self.early_exit_threshold} is not supported: only 1 "
+                "(every one of the total_ut_steps passes runs, for every token) is computed"
             )
 
     @property
@@ -135,6 +154,16 @@ class LlamaConfig:
             hidden_size=2048, intermediate_size=5504, num_hidden_layers=16,
             num_attention_heads=16, num_key_value_heads=16, **kw,
         )
+
+
+def require_single_pass(cfg, what: str) -> None:
+    """Refuse, rather than run the stack once, where a walker of the layers
+    other than ``LlamaModel`` and the cached forward is handed a config that
+    asks for several passes."""
+    if getattr(cfg, "total_ut_steps", 1) != 1:
+        raise NotImplementedError(
+            f"{what} runs the layer stack once; total_ut_steps={cfg.total_ut_steps} is "
+            "computed by LlamaForCausalLM and the cached generation path only")
 
 
 def rms_norm(x, weight, eps):
@@ -334,18 +363,16 @@ class LlamaBlock(nn.Module):
     def __call__(self, x, positions):
         cfg = self.config
         rm = cfg.residual_multiplier
-        h = x + scale_residual(
-            LlamaAttention(cfg, name="self_attn")(
-                make_norm(cfg, "input_layernorm")(x), positions
-            ),
-            rm,
+        attn = LlamaAttention(cfg, name="self_attn")(
+            make_norm(cfg, "input_layernorm")(x), positions
         )
-        return h + scale_residual(
-            LlamaMLP(cfg, name="mlp")(
-                make_norm(cfg, "post_attention_layernorm")(h)
-            ),
-            rm,
-        )
+        if cfg.sandwich_norm:
+            attn = make_norm(cfg, "input_layernorm_2")(attn)
+        h = x + scale_residual(attn, rm)
+        ffn = LlamaMLP(cfg, name="mlp")(make_norm(cfg, "post_attention_layernorm")(h))
+        if cfg.sandwich_norm:
+            ffn = make_norm(cfg, "post_attention_layernorm_2")(ffn)
+        return h + scale_residual(ffn, rm)
 
 
 class _ScannedBlock(nn.Module):
@@ -406,17 +433,38 @@ class LlamaModel(nn.Module):
                 length=cfg.num_hidden_layers,
                 metadata_params={nn.PARTITION_NAME: "layers"},
             )(cfg, name="layers")
-            (x, _), _ = scanned((x, positions), None)
+
+            def stack(x):
+                return scanned((x, positions), None)[0][0]
         else:
-            for i in range(cfg.num_hidden_layers):
-                blk = LlamaBlock
-                if cfg.remat:
-                    blk = nn.remat(blk, **remat_kwargs)
-                x = blk(cfg, name=f"layers_{i}")(x, positions)
-        return make_norm(cfg, "norm")(x)
+            blk = nn.remat(LlamaBlock, **remat_kwargs) if cfg.remat else LlamaBlock
+            blocks = [blk(cfg, name=f"layers_{i}") for i in range(cfg.num_hidden_layers)]
+
+            def stack(x):
+                for b in blocks:
+                    x = b(x, positions)
+                return x
+        # The same modules in every pass: one set of weights. The last pass's
+        # output is the model's (early_exit_threshold 1); the training
+        # objective over all passes' exits (arXiv:2510.25741) is not built.
+        norm = make_norm(cfg, "norm")
+        gate = nn.Dense(1, dtype=cfg.dtype, param_dtype=jnp.float32,
+                        name="early_exit_gate") if cfg.early_exit_gate else None
+        for _ in range(cfg.total_ut_steps):
+            x = norm(stack(x))
+            if gate is not None:  # one (B, S) row of logits a pass, on request
+                self.sow("intermediates", "exit_gate_logits", gate(x)[..., 0])
+        return x
 
 
 class LlamaForCausalLM(nn.Module):
+    """Logits of the last pass over the stack (there is one, unless
+    ``total_ut_steps`` says more). A looped model's published training
+    objective, the expected loss over every pass's exit (arXiv:2510.25741),
+    is not computed here: the module serves inference and the cached
+    forward's parity tests; the exit gate's logits are sown under
+    ``intermediates`` for whoever wants the exit distribution."""
+
     config: LlamaConfig
 
     @nn.compact

@@ -38,6 +38,7 @@ from .llama import (
     RMSNorm,
     _pin_last_dim_replicated,
     cross_entropy_loss,
+    require_single_pass,
 )
 
 
@@ -179,6 +180,7 @@ class MixtralModel(nn.Module):
     @nn.compact
     def __call__(self, input_ids):
         cfg = self.config
+        require_single_pass(cfg, "MixtralModel")
         x = nn.Embed(
             cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype, param_dtype=jnp.float32,
             name="embed_tokens",

@@ -361,8 +361,9 @@ def _llama_stage_fn(config) -> Callable:
     """Stable (per-config) stage function so eager pipeline calls hit the
     compile cache; honors ``config.remat`` per layer like the unpipelined
     ``LlamaModel`` path."""
-    from ..models.llama import LlamaBlock
+    from ..models.llama import LlamaBlock, require_single_pass
 
+    require_single_pass(config, "pipeline parallelism")
     block = LlamaBlock(config)
 
     def one_layer(carry, layer_params):

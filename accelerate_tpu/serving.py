@@ -101,7 +101,7 @@ from .generation import (
     _filter_logits,
     sample_logits,
 )
-from .kv_cache import KVCache, cache_spec, init_slot_cache
+from .kv_cache import KVCache, cache_spec, init_slot_cache, kv_bytes_per_token
 from .logging import get_logger
 from .utils.constants import PREEMPTION_EXIT_CODE, SERVING_CRASH_EXIT_CODE
 
@@ -849,6 +849,11 @@ class ServingEngine:
         self._state = _commit_params(jax.device_put(init_slot_state(
             self.n_slots, seed=c.seed,
             history=self._spec_ngram), place))
+        # The cache as its owner describes it (stats()["cache"]).
+        self._cache_shape = {
+            "planes": self._cache.n_layers,
+            "bytes_per_token": kv_bytes_per_token(self.cfg, dtype=self._cache.dtype),
+        }
         # Weight publication (publish.py): params are double-buffered by
         # monotonic version. ``_params`` always aliases the PRIMARY version;
         # in-flight requests keep decoding whatever version they bound at
@@ -896,6 +901,8 @@ class ServingEngine:
             "prefill_chunks": 0, "prefill_pad_tokens": 0, "tokens_out": 0,
             "prompt_tokens_in": 0,
             "slot_allocs": 0, "slot_reuses": 0, "occupancy_sum": 0,
+            # Cache rows the decoding slots held, summed over decode steps.
+            "live_rows_sum": 0,
             "peak_occupancy": 0, "queue_depth_sum": 0, "queue_samples": 0,
             "steady_recompiles": 0, "prefill_steady_recompiles": 0,
             # Seconds in each phase of the tick and in whole ticks
@@ -1505,6 +1512,9 @@ class ServingEngine:
                         self._on_poisoned_slot(slot, req)
                         continue
                     cnt = int(emitted_np[slot])
+                    # rows this step attended over: the prompt and every token
+                    # written so far, the one it wrote among them
+                    self._stats["live_rows_sum"] += req.tokens.size + len(req.out)
                     self._emit(req, toks_np[slot, :cnt], t_fetch)
                     if k_spec > 0:
                         req.spec_drafted += k_spec
@@ -2448,6 +2458,18 @@ class ServingEngine:
                 if s["decode_steps"] else None
             ),
             "peak_occupancy": s["peak_occupancy"],
+            # The KV cache: planes of the buffer (passes x layers), bytes one
+            # token's K and V take over all of them, and the mean over decode
+            # steps of the rows the decoding slots held (what a step had to
+            # read, where the buffer is n_slots x max_len rows).
+            "cache": {
+                **self._cache_shape,
+                "live_rows_mean": (
+                    round(s["live_rows_sum"] / s["decode_steps"], 3)
+                    if s["decode_steps"] else None
+                ),
+            },
+            "passes": cache_spec(self.cfg).passes,
             "mean_queue_depth": (
                 round(s["queue_depth_sum"] / s["queue_samples"], 3)
                 if s["queue_samples"] else None

@@ -94,8 +94,8 @@ def test_plan_disagg_prices_handoff(llama):
     # 2 (K and V) * layers * kv_heads * head_dim * itemsize.
     from accelerate_tpu.kv_cache import cache_spec
 
-    layers, kv_heads, head_dim, _ = cache_spec(cfg)
-    assert kvb == 2 * layers * kv_heads * head_dim * 4
+    spec = cache_spec(cfg)
+    assert kvb == 2 * spec.layers * spec.kv_heads * spec.head_dim * 4
     bw = BandwidthTable()
     plan = plan_disagg_slices(8, prefill_decode_flop_ratio=2.0, bw=bw,
                               kv_bytes_per_token=kvb)
@@ -452,3 +452,27 @@ def test_lane_quarantine_survives_on_remaining_lane(llama):
     assert s["disagg"]["degraded"] is False
     assert s["decode_executables"] == 1
     assert s["steady_recompiles"] == 0
+
+
+def test_router_ships_every_plane_of_a_looped_model_and_prices_them():
+    """A stack run three times over two layers keeps six cache planes a
+    token: the handoff ships all of them (the router's tokens are the
+    colocated engine's) and the slice plan prices the link with the cache's
+    own ``kv_bytes_per_token``, passes included. (Last in the file: the handoff
+    programs are jitted once a process, and an earlier test reads their census.)"""
+    from accelerate_tpu.models import LlamaConfig, LlamaForCausalLM
+
+    set_seed(0)
+    cfg = LlamaConfig.tiny(dtype=jnp.float32, attention_impl="native", total_ut_steps=3,
+                           sandwich_norm=True, early_exit_gate=True)
+    model = Model.from_flax(LlamaForCausalLM(cfg), jax.random.key(0),
+                            np.ones((1, 8), np.int32))
+    colo, dis = _engines(model, n_prefill_lanes=1)
+    assert dis._cache.n_layers == 3 * cfg.num_hidden_layers
+    assert dis.slice_plan.kv_bytes_per_token == (
+        2 * 4 * 3 * cfg.num_hidden_layers * cfg.num_key_value_heads * cfg.head_dim)
+    prompts = _prompts(cfg, [13, 5, 9], seed=5)
+    budgets = [5, 7, 4]
+    for c, d in zip(colo.run(prompts, max_new_tokens=budgets),
+                    dis.run(prompts, max_new_tokens=budgets)):
+        np.testing.assert_array_equal(c, d)

@@ -238,6 +238,86 @@ def test_mixtral_greedy_generate_matches_naive_loop():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(out))
 
 
+@pytest.fixture(scope="module")
+def looped():
+    """The Llama chassis with its two layers run three times over one set of
+    weights, sandwich norms and the exit gate: six cache planes."""
+    set_seed(4)
+    cfg = LlamaConfig.tiny(dtype=jnp.float32, attention_impl="native", total_ut_steps=3,
+                           sandwich_norm=True, early_exit_gate=True)
+    module = LlamaForCausalLM(cfg)
+    ids = np.random.default_rng(4).integers(0, cfg.vocab_size, (2, 9), dtype=np.int32)
+    model = Model.from_flax(module, jax.random.key(4), ids)
+    # norm scales away from 1, so that a norm left out or applied twice shows
+    leaves, tree = jax.tree.flatten(model.params)
+    keys = jax.random.split(jax.random.key(5), len(leaves))
+    model.params = jax.tree.unflatten(
+        tree, [x + 0.1 * jax.random.normal(k, x.shape) for x, k in zip(leaves, keys)])
+    return cfg, module, model, jnp.asarray(ids)
+
+
+def test_looped_greedy_generate_matches_naive_loop(looped):
+    cfg, module, model, ids = looped
+    got = generate(model, ids, max_new_tokens=5)
+    out = ids
+    for _ in range(5):
+        logits = module.apply({"params": model.params}, out)
+        tok = jnp.argmax(logits[:, -1].astype(jnp.float32), -1).astype(jnp.int32)
+        out = jnp.concatenate([out, tok[:, None]], axis=1)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(out))
+
+
+def test_looped_cached_forward_in_chunks_matches_full_forward(looped):
+    """Prefill in two chunks, then token by token: every position's logits
+    are the module's, so each pass read its own planes and no other's."""
+    cfg, module, model, ids = looped
+    cache = init_cache(cfg, 2, 16)
+    assert cache.n_layers == 3 * cfg.num_hidden_layers
+    parts = []
+    for lo, hi in ((0, 4), (4, 7), (7, 8), (8, 9)):
+        logits, cache = _llama_forward_cached(cfg, model.params, ids[:, lo:hi], cache,
+                                              return_all=True)
+        parts.append(logits)
+    full = module.apply({"params": model.params}, ids)
+    np.testing.assert_allclose(np.asarray(jnp.concatenate(parts, 1)), np.asarray(full),
+                               rtol=2e-5, atol=2e-5)
+    assert int(cache.length) == 9
+
+
+def test_looped_module_differs_from_one_pass_and_sows_a_gate_logit_a_pass(looped):
+    import dataclasses
+
+    cfg, module, model, ids = looped
+    full, sown = module.apply({"params": model.params}, ids, mutable=["intermediates"])
+    gates = sown["intermediates"]["model"]["exit_gate_logits"]
+    assert len(gates) == 3 and all(g.shape == ids.shape for g in gates)
+    once = LlamaForCausalLM(dataclasses.replace(cfg, total_ut_steps=1)).apply(
+        {"params": model.params}, ids)
+    assert float(jnp.max(jnp.abs(full - once))) > 1e-2
+
+
+@pytest.mark.parametrize("knobs,key", [
+    ({"early_exit_threshold": 0.5}, "early_exit_threshold"),
+    ({"total_ut_steps": 0}, "total_ut_steps"),
+])
+def test_a_looped_knob_that_is_not_computed_is_refused_at_config_time(knobs, key):
+    with pytest.raises(ValueError, match=key):
+        LlamaConfig.tiny(**knobs)
+
+
+def test_walkers_that_run_the_stack_once_refuse_a_config_that_asks_for_more():
+    from accelerate_tpu.big_modeling import _llama_spec
+    from accelerate_tpu.models import MixtralConfig, MixtralForCausalLM
+    from accelerate_tpu.parallel.pp import _llama_stage_fn
+
+    cfg = LlamaConfig.tiny(total_ut_steps=2)
+    for walk in (lambda: _llama_spec(cfg), lambda: _llama_stage_fn(cfg),
+                 lambda: MixtralForCausalLM(MixtralConfig.tiny(total_ut_steps=2)).init(
+                     jax.random.key(0), np.ones((1, 4), np.int32))):
+        with pytest.raises(NotImplementedError, match="total_ut_steps"):
+            walk()
+
+
 def test_beam_search_beam1_equals_greedy(llama):
     from accelerate_tpu import beam_search
 

@@ -7,10 +7,15 @@ same compiles show that a decode step writes the cache in place: it holds no
 second copy of the cache and puts no layer's slice back into a stack; and that
 Mixtral's programs read a layer's experts where the stacked parameter holds
 them: nothing of an expert tensor's size is copied, gathered or broadcast.
+The four programs of the two cells that were there before the layer loop
+learned to run a stack more than once are held to their lowered text of that
+day, and the looped cell's programs to one cache, one layer body and no copy
+of the cache.
 
 The topology is described in a fixture (never while a module is imported);
 ``tests/chipbench/test_chipbench_aot.py`` is the other file that does so."""
 
+import hashlib
 import math
 import re
 
@@ -64,7 +69,8 @@ def test_attend_equals_the_explicit_repeat(hq, hkv, sq, quantized, masked):
 @pytest.fixture(scope="module")
 def cell_programs():
     """``get(cell_name) -> (cell, {"decode": compiled, "prefill": compiled})``, each
-    cell compiled once for a described v5e chip."""
+    cell compiled once for a described v5e chip; ``get.lowered[cell_name]`` holds
+    the sha256 of each program's lowered StableHLO text, taken on the way."""
     import os
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -81,14 +87,27 @@ def cell_programs():
     before = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    compiled = {}
+    compiled, texts = {}, []
+    compile_lowered = jax.stages.Lowered.compile
+
+    def noting_the_text(lowered, *args, **kwargs):
+        texts.append(lowered.as_text())
+        return compile_lowered(lowered, *args, **kwargs)
 
     def get(name):
         if name not in compiled:
             cell = spec.load_cell(name)
-            compiled[name] = cell, aot.serving_programs(cell, topo.devices[0])
+            del texts[:]
+            jax.stages.Lowered.compile = noting_the_text
+            try:
+                compiled[name] = cell, aot.serving_programs(cell, topo.devices[0])
+            finally:
+                jax.stages.Lowered.compile = compile_lowered
+            get.lowered[name] = {program: hashlib.sha256(text.encode()).hexdigest()
+                                 for program, text in zip(compiled[name][1], texts)}
         return compiled[name]
 
+    get.lowered = {}
     yield get
     jax.config.update("jax_enable_compilation_cache", before)
     compilation_cache.reset_cache()
@@ -179,3 +198,66 @@ def test_the_steady_cell_runs_no_expert_code(cell_programs, program):
     # the scope ``moe.experts`` and the parameter ``...moe__w_gate`` name it in Mixtral's text
     assert "moe" in cell_programs("mixtral_serve_decode")[1][program].as_text()
     assert "moe" not in cell_programs("mistral_serve_steady")[1][program].as_text()
+
+
+# -- a stack run more than once: the programs that were there stay, to the byte ------
+
+# sha256 of the lowered StableHLO text (no locations in it) of the four programs, taken
+# on the commit before ``_forward_cached`` gained its loop over passes. With one pass
+# the traced program is that one. A PR that means to change one of these programs
+# replaces its line, and says which; one that does not has touched their path.
+LOWERED_BEFORE_PASSES = {
+    ("mistral_serve_steady", "decode"):
+        "53b4d14f865801697db8aac3eca60c6dc4f6d5955cf26e1a4f64781a383b8589",
+    ("mistral_serve_steady", "prefill"):
+        "f48f7bc1f733a4f268b3a7c1d4db0ce99a7444efa761acc805fc5560f209de64",
+    ("mixtral_serve_decode", "decode"):
+        "13fc764d41a7b5637e73c40fad5e53c96803164c330aa2e0ee801b0980d93ce9",
+    ("mixtral_serve_decode", "prefill"):
+        "33421fcc02a5ec145869358ad6519b4bbae66b3eab354af47b1d3f58f488f859",
+}
+
+
+@pytest.mark.parametrize("cell_name,program", sorted(LOWERED_BEFORE_PASSES))
+def test_the_one_pass_cells_lower_to_the_programs_they_were(cell_programs, cell_name, program):
+    cell_programs(cell_name)
+    assert cell_programs.lowered[cell_name][program] == LOWERED_BEFORE_PASSES[cell_name, program]
+
+
+# The looped cell: 48 layers run 4 times over 192 cache planes. Beside its arguments
+# (5.34 GB of weights and the 6.44 GB slot cache) a decode step holds the q, k and v
+# projection stacks a second time, 0.40 GB each: XLA lays them out by head once a
+# step, outside both loops, where the one-pass programs copy a layer's slice out in
+# every layer (``_proj``'s spelling; PERF.md, section 5). Nothing else of that size.
+@pytest.mark.parametrize("case", ["holds_the_cache_once", "no_cache_sized_copy_or_put_back",
+                                  "one_layer_body_not_four"])
+def test_the_looped_cell_s_decode_program(cell_programs, case):
+    from chipbench import aot
+
+    cell, programs = cell_programs("ouro_serve_reason")
+    cfg, eng = cell.config, cell.workload["engine"]
+    planes = cfg["total_ut_steps"] * cfg["num_hidden_layers"]
+    side = (planes * eng["n_slots"] * eng["max_len"] * cfg["num_key_value_heads"]
+            * cfg["head_dim"])
+    decode = programs["decode"]
+    if case == "holds_the_cache_once":
+        m = aot.memory_of(decode)
+        assert planes == 192 and 2 * 2 * side == 6_442_450_944
+        assert m["aliased"] >= 2 * 2 * side                       # donated, written in place
+        assert 11.7e9 < m["arguments"] < 11.9e9                   # the weights once, the cache once
+        qkv = 3 * 2 * cfg["num_hidden_layers"] * cfg["hidden_size"] ** 2
+        assert m["temporaries"] < qkv + 2**24                     # 1.21 GB: those three, no more
+        assert m["device_bytes"] < 13.1e9
+    elif case == "no_cache_sized_copy_or_put_back":
+        moved = [name for n, op, name in _arrays(decode.as_text(), scheduled_only=True)
+                 if n == side and (op == "copy" or "dynamic-update-slice" in name)]
+        assert not moved
+    else:
+        # two nested loops, and the MLP's three matmuls once in the text: an unroll
+        # of the passes would hold them (and compile them) four times
+        text = decode.as_text()
+        assert len(re.findall(r" while\(", text)) == 2
+        wide = [name for n, op, name in _arrays(text, scheduled_only=True)
+                if n == eng["n_slots"] * cfg["intermediate_size"] and op == "fusion"]
+        assert 1 <= len(wide) <= 2, wide
+        assert "ut_pass" in text and text.count("ut_pass") >= 1

@@ -48,7 +48,9 @@ def _step_on_private_slices(ck, cv, k_new, v_new, layer, start):
     return put_back(ck, k_i), put_back(cv, v_i), k_i, v_i
 
 
-DECODER_ONLY = ["llama", "gpt2", "opt", "neox", "mixtral"]
+# "looped": the Llama chassis with its stack run three times over one set of
+# weights (two layers, so six cache planes), sandwich norms and the exit gate
+DECODER_ONLY = ["llama", "gpt2", "opt", "neox", "mixtral", "looped"]
 ENCODER_DECODER = ["t5", "whisper"]
 
 
@@ -61,6 +63,8 @@ def _family(name):
         "opt": (M.OPTConfig, M.OPTForCausalLM, {}),
         "neox": (M.GPTNeoXConfig, M.GPTNeoXForCausalLM, {}),
         "mixtral": (M.MixtralConfig, M.MixtralForCausalLM, {}),
+        "looped": (M.LlamaConfig, M.LlamaForCausalLM,
+                   {"total_ut_steps": 3, "sandwich_norm": True, "early_exit_gate": True}),
         "t5": (M.T5Config, M.T5ForConditionalGeneration, {"num_layers": 3}),
         "whisper": (M.WhisperConfig, M.WhisperForConditionalGeneration, {}),
     }[name]
@@ -103,9 +107,9 @@ def models():
 
 def _filled_cache(cfg, batch, t_max, quantized, per_slot, seed):
     """A cache that already holds rows, at another length in every slot."""
-    layers, kv_heads, head_dim, _ = KC.cache_spec(cfg)
+    spec = KC.cache_spec(cfg)
     kk, kv = jax.random.split(jax.random.key(seed))
-    shape = (layers, batch, t_max, kv_heads, head_dim)
+    shape = (spec.layers, batch, t_max, spec.kv_heads, spec.head_dim)
 
     def side(key):
         x = jax.random.normal(key, shape, jnp.float32)
@@ -268,3 +272,39 @@ def test_bytes_per_token_is_what_the_cache_allocates(family, dtype):
     cache = KC.init_slot_cache(cfg, 3, 8, dtype)
     allocated = sum(leaf.nbytes for leaf in jax.tree.leaves((cache.k, cache.v)))
     assert kv_bytes_per_token(cfg, dtype) * 3 * 8 == allocated
+
+
+def _published_looped_config():
+    """The benchmark's looped configuration as the program's config."""
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs", "ouro-2.6b.json")) as f:
+        hf = json.load(f)
+    from accelerate_tpu.models.hub import ouro_config_from_hf
+
+    return ouro_config_from_hf(hf)
+
+
+@pytest.mark.parametrize("size", ["tiny", "published"])
+def test_a_looped_model_s_token_takes_passes_x_layers_planes(size):
+    """``2 (K, V) x 2 bytes x U x L x Hkv x D``, and the planner and the disagg
+    router price a handoff with that number and no other."""
+    from accelerate_tpu import disagg, planner
+
+    cfg = _family("looped")[0] if size == "tiny" else _published_looped_config()
+    spec = KC.cache_spec(cfg)
+    assert spec.passes == cfg.total_ut_steps and spec.layers == spec.passes * cfg.num_hidden_layers
+    want = 2 * 2 * cfg.total_ut_steps * cfg.num_hidden_layers * spec.kv_heads * spec.head_dim
+    if size == "published":
+        assert (spec.layers, want) == (192, 1_572_864)   # 1.5 MiB a token
+    assert KC.kv_bytes_per_token(cfg, jnp.bfloat16) == want
+    assert planner.kv_bytes_per_token(cfg, jnp.bfloat16) == want
+    assert planner.BandwidthTable().kv_bytes_per_token(cfg, jnp.bfloat16) == want
+    assert disagg.kv_bytes_per_token is planner.kv_bytes_per_token
+    plan = planner.plan_disagg_slices(
+        8, prefill_decode_flop_ratio=2.0, kv_bytes_per_token=want)
+    assert plan.kv_bytes_per_token == want   # the number the priced handoff used
+    assert plan.handoff_s_per_ktoken == pytest.approx(
+        1000.0 * want / (plan.handoff_gbps * 1e9), rel=1e-4)

@@ -9,6 +9,7 @@ the native flax forward.
 
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 torch = pytest.importorskip("torch")
@@ -66,6 +67,65 @@ def test_llama_roundtrip_to_hf(rng):
     back = llama_params_to_hf(cfg, llama_params_from_hf(cfg, sd))
     for k, v in back.items():
         np.testing.assert_array_equal(v, sd[k], err_msg=k)
+
+
+def _tiny_ouro_checkpoint(seed=0, **cfg_kw):
+    """A config.json as published for the looped family and a state dict
+    under its parameter names, made here: no such model is in transformers."""
+    hf_cfg = dict(model_type="ouro", vocab_size=64, hidden_size=32, intermediate_size=64,
+                  num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=4,
+                  head_dim=8, max_position_embeddings=64, rms_norm_eps=1e-6,
+                  rope_theta=1000000, rope_scaling=None, sliding_window=None,
+                  use_sliding_window=False, tie_word_embeddings=False, total_ut_steps=3,
+                  early_exit_threshold=1, **cfg_kw)
+    rng = np.random.default_rng(seed)
+    h, f, v = 32, 64, 64
+    sd = {"model.embed_tokens.weight": (v, h), "model.norm.weight": (h,),
+          "lm_head.weight": (v, h), "model.early_exit_gate.weight": (1, h),
+          "model.early_exit_gate.bias": (1,)}
+    for i in range(2):
+        p = f"model.layers.{i}."
+        sd.update({p + f"self_attn.{n}_proj.weight": (h, h) for n in "qkvo"})
+        sd.update({p + "mlp.gate_proj.weight": (f, h), p + "mlp.up_proj.weight": (f, h),
+                   p + "mlp.down_proj.weight": (h, f)})
+        sd.update({p + n + ".weight": (h,) for n in (
+            "input_layernorm", "input_layernorm_2", "post_attention_layernorm",
+            "post_attention_layernorm_2")})
+    return hf_cfg, {k: rng.normal(size=shape).astype(np.float32) for k, shape in sd.items()}
+
+
+def test_ouro_checkpoint_loads_roundtrips_and_runs():
+    hf_cfg, sd = _tiny_ouro_checkpoint()
+    cfg, params, cls = load_pretrained((hf_cfg, sd), dtype=jnp.float32)
+    assert cls.__name__ == "LlamaForCausalLM"
+    assert (cfg.total_ut_steps, cfg.sandwich_norm, cfg.early_exit_gate,
+            cfg.early_exit_threshold) == (3, True, True, 1.0)
+    block = params["model"]["layers"]["block"]
+    assert block["input_layernorm_2"]["weight"].shape == (2, 32)
+    assert block["post_attention_layernorm_2"]["weight"].shape == (2, 32)
+    assert params["model"]["early_exit_gate"]["kernel"].shape == (32, 1)
+    back = llama_params_to_hf(cfg, params)
+    assert set(back) == set(sd)
+    for k, v in back.items():
+        np.testing.assert_array_equal(v, sd[k], err_msg=k)
+    # the tree is the module's own: it runs, and all three passes count
+    module = cls(cfg)
+    ids = np.arange(12, dtype=np.int32)[None] % 64
+    want = jax.eval_shape(module.init, jax.random.key(0), ids)["params"]
+    assert jax.tree.map(lambda x: x.shape, want) == jax.tree.map(lambda x: x.shape, params)
+    assert np.isfinite(np.asarray(module.apply({"params": params}, ids))).all()
+
+
+@pytest.mark.parametrize("kw,error,match", [
+    ({"early_exit_threshold": 0.9}, ValueError, "early_exit_threshold"),
+    ({"rope_scaling": {"type": "linear", "factor": 2.0}}, NotImplementedError, "rope_scaling"),
+    ({"use_sliding_window": True, "sliding_window": 4096}, NotImplementedError,
+     "sliding_window"),
+])
+def test_ouro_config_refuses_what_is_not_computed(kw, error, match):
+    hf_cfg, sd = _tiny_ouro_checkpoint()
+    with pytest.raises(error, match=match):
+        load_pretrained((dict(hf_cfg, **kw), sd), dtype=jnp.float32)
 
 
 def test_gpt2_logit_parity(rng):

@@ -55,7 +55,7 @@ SERVING_STATS_KEYS = {
     "ttft_terms", "token_gap", "tick_phases",
     "ticks", "decode_steps", "prefill_chunks", "prefill_pad_tokens",
     "prefill_ladder", "n_slots", "mean_occupancy", "peak_occupancy",
-    "mean_queue_depth", "slot_allocs", "slot_reuses", "steady_recompiles",
+    "cache", "passes", "mean_queue_depth", "slot_allocs", "slot_reuses", "steady_recompiles",
     "prefill_steady_recompiles", "decode_executables", "prefill_executables", "weights_version",
     "canary", "window", "faults", "journal", "sdc", "speculation",
 }

@@ -212,6 +212,34 @@ def test_occupancy_and_token_accounting(llama):
     assert stats["ttft_p50_s"] is not None and stats["ttft_p95_s"] >= stats["ttft_p50_s"]
 
 
+@pytest.mark.parametrize("lengths,budgets,rows_summed", [
+    # one request: its first token comes from the prompt's last chunk, then 5
+    # decode steps over 5 + 1 .. 5 + 5 rows
+    ([5], [6], sum(5 + j for j in range(1, 6))),
+    # two at once: every request's decode steps, whichever tick they fell in
+    ([4, 9], [3, 6], sum(4 + j for j in range(1, 3)) + sum(9 + j for j in range(1, 6))),
+], ids=["one_request", "two_slots"])
+def test_cache_counters_say_what_the_decode_steps_had_to_read(llama, lengths, budgets,
+                                                               rows_summed):
+    """``stats()["cache"]``: the buffer's planes and a token's bytes from the
+    cache's owner, and the mean over decode steps of the rows the decoding
+    slots held, from lengths the host already has; ``stats()["passes"]``."""
+    from accelerate_tpu.kv_cache import cache_spec, kv_bytes_per_token
+
+    cfg, model = llama
+    engine = ServingEngine(model, ServingConfig(n_slots=2, max_len=64, prefill_chunks=[8]))
+    engine.run(_prompts(cfg, lengths, seed=2), max_new_tokens=budgets)
+    stats = engine.stats()
+    assert stats["passes"] == 1
+    assert stats["cache"]["planes"] == cache_spec(cfg).layers == cfg.num_hidden_layers
+    assert stats["cache"]["bytes_per_token"] == kv_bytes_per_token(cfg)
+    assert stats["cache"]["live_rows_mean"] == pytest.approx(
+        rows_summed / stats["decode_steps"], abs=1e-3)
+    engine.reset_metrics()   # the window opens: the sum restarts with the steps
+    assert engine.stats()["cache"]["live_rows_mean"] is None
+    assert engine.stats()["cache"]["planes"] == cfg.num_hidden_layers
+
+
 def test_incremental_submit_poll(llama):
     """The front-end contract: submissions land mid-flight, poll() delivers
     each result exactly once."""
