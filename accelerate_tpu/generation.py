@@ -182,7 +182,7 @@ def _attend(q, k, v, q_positions, kv_valid=None):
 
 
 def _forward_cached(decoder, cfg, params, input_ids, cache: KVCache, return_all=False,
-                    pad_offset=None, kv_valid=None):
+                    pad_offset=None, kv_valid=None, attn_bound=None):
     """Run ``input_ids`` (appended at cache.length) through all layers,
     returning (logits, new_cache) — last-token logits, or every position's
     with ``return_all`` (speculative verification needs them). The one loop
@@ -194,6 +194,17 @@ def _forward_cached(decoder, cfg, params, input_ids, cache: KVCache, return_all=
     block. ``attend(q, k_new, v_new)`` is a layer's whole dealing with the
     cache: it writes the new K/V rows and attends over the layer
     (``cache_step`` then ``_attend``), so no block holds the buffers.
+
+    A step of one token a row with nothing masked but the causal bound, over
+    a cache the decode kernel takes (``kv_cache.decode_block_rows``: float, on
+    one device, tile-shaped), goes through ``kv_cache.cache_attend`` instead:
+    lowered for a TPU, attention reads rows ``0 .. bound - 1`` of each row's
+    plane where the cache holds them; lowered for anything else it is the two
+    calls above. ``attn_bound`` (B,) int32 is that bound: by default each
+    row's position + 1, which is all the causal mask lets through; a caller
+    that knows some rows are not decoding (``serving``'s free, done and
+    prefilling slots) passes 0 for them, and they read nothing. It bounds
+    the read only: every row's write offset is ``cache.length`` as ever.
 
     A model that runs its stack more than once over one set of weights
     (``cache_spec(cfg).passes``, static) gets an outer ``lax.scan`` over the
@@ -216,6 +227,9 @@ def _forward_cached(decoder, cfg, params, input_ids, cache: KVCache, return_all=
     if pad_offset is not None:
         pos_ids = jnp.maximum(positions - pad_offset[:, None], 0)
     x, stacked, block, norm, head, *xs = decoder(cfg, params, input_ids, pos_ids)
+    by_bound = s == 1 and kv_valid is None and kv_cache.decode_block_rows(cache.k) is not None
+    if by_bound and attn_bound is None:
+        attn_bound = positions[:, 0] + 1
 
     def one_layer(carry, layer):
         h, ck, cv = carry  # hidden state, the whole (L,B,T,Hkv,D) cache
@@ -223,6 +237,11 @@ def _forward_cached(decoder, cfg, params, input_ids, cache: KVCache, return_all=
 
         def attend(q, k_new, v_new):
             nonlocal ck, cv
+            if by_bound:
+                ck, cv, out = kv_cache.cache_attend(
+                    ck, cv, q, k_new, v_new, i, start, attn_bound,
+                    lambda q, k_i, v_i: _attend(q, k_i, v_i, positions))
+                return out
             ck, cv, k_i, v_i = kv_cache.cache_step(ck, cv, k_new, v_new, i, start)
             return _attend(q, k_i, v_i, positions, kv_valid)
 

@@ -9,7 +9,9 @@ written them or not. An int8 cache holds each side as a :class:`QuantPages`
 pair of leaves (data and one scale per row and head) with the same leading
 axes. Nothing outside this module indexes a cache leaf by axis number or asks
 whether a side is one leaf or two: the forwards, the serving engines and the
-planner go through :class:`KVCache`'s operations and :func:`cache_step`.
+planner go through :class:`KVCache`'s operations, :func:`cache_step` and, for
+a step of one query row a slot, :func:`cache_attend`, which hands the decode
+kernel (``ops/decode_attention.py``) the buffers in this order of axes.
 """
 
 from __future__ import annotations
@@ -20,6 +22,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec
+
+from .ops import decode_attention as _decode
 
 
 class QuantPages(NamedTuple):
@@ -250,3 +254,57 @@ def cache_step(ck, cv, k_new, v_new, layer, start):
     ck, cv = _cache_write(ck, k_new, layer, start), _cache_write(cv, v_new, layer, start)
     k_i, v_i = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, False), (ck, cv))
     return ck, cv, k_i, v_i
+
+
+def decode_block_rows(side) -> int | None:
+    """Rows to a block of the decode kernel's reads over a cache side ``side``
+    (``cache.k``; an array or a tracer of one), or ``None`` where a step of
+    one query row a slot keeps :func:`cache_step`: int8 pages (dequantized
+    whole beside the dot), shapes the kernel does not tile
+    (``decode_attention.block_rows``), and a buffer laid over the devices of a
+    mesh (:func:`slots_partition`; a Mosaic call has no partitioning rule)."""
+    if isinstance(side, QuantPages) or not jnp.issubdtype(side.dtype, jnp.floating):
+        return None
+    if jax.typeof(side).sharding.mesh.size > 1:
+        return None
+    t_max, kv_heads, head_dim = side.shape[2:]
+    return _decode.block_rows(t_max, kv_heads, head_dim, side.dtype)
+
+
+def decode_reads(cache: KVCache) -> int | None:
+    """For a cache where it is placed: the block of rows by which a decode
+    step's attention reads it (:func:`cache_attend` with the kernel: a cache
+    that :func:`decode_block_rows` takes, on a TPU), or ``None`` where such a
+    step reads every row of every slot."""
+    side = jax.tree.leaves(cache.k)[0]
+    on_tpu = _decode.INTERPRET or next(iter(side.devices())).platform == "tpu"
+    return decode_block_rows(cache.k) if on_tpu else None
+
+
+def cache_attend(ck, cv, q, k_new, v_new, layer, start, bound, attend):
+    """A layer's turn at a cache that :func:`decode_block_rows` takes, for a
+    step of one query row a slot ``q`` (B, 1, Hq, D): returns
+    ``(ck, cv, out)``. Where the program is lowered for a TPU, the new rows
+    are written in place (:func:`_cache_write`) and the kernel is handed the
+    whole buffers, the plane ``layer`` and ``bound`` (B,): it reads rows
+    ``0 .. bound[b] - 1`` of slot ``b`` where they lie, nothing of a slot whose
+    bound is 0 (whose output row is zeros), and no layer's slice is made.
+    Lowered for anything else it is :func:`cache_step` and then
+    ``attend(q, k_layer, v_layer)`` over the slice, ``bound`` unused. The
+    choice is the lowering's, not the process's
+    (``jax.lax.platform_dependent``): a program compiled for a described chip
+    is the program that chip runs."""
+
+    def kernel_over_the_stack(ck, cv):
+        ck, cv = _cache_write(ck, k_new, layer, start), _cache_write(cv, v_new, layer, start)
+        return ck, cv, _decode.decode_attention(q, ck, cv, layer, bound,
+                                                interpret=_decode.INTERPRET)
+
+    def dots_over_the_slice(ck, cv):
+        ck, cv, k_i, v_i = cache_step(ck, cv, k_new, v_new, layer, start)
+        return ck, cv, attend(q, k_i, v_i)
+
+    if _decode.INTERPRET:
+        return kernel_over_the_stack(ck, cv)
+    return jax.lax.platform_dependent(ck, cv, tpu=kernel_over_the_stack,
+                                      default=dots_over_the_slice)

@@ -84,6 +84,7 @@ Usage::
 
 from __future__ import annotations
 
+import inspect
 import itertools
 import os
 import time
@@ -101,7 +102,8 @@ from .generation import (
     _filter_logits,
     sample_logits,
 )
-from .kv_cache import KVCache, cache_spec, init_slot_cache, kv_bytes_per_token
+from .kv_cache import KVCache, cache_spec, decode_reads, init_slot_cache, kv_bytes_per_token
+from .ops.decode_attention import rows_read
 from .logging import get_logger
 from .utils.constants import PREEMPTION_EXIT_CODE, SERVING_CRASH_EXIT_CODE
 
@@ -264,13 +266,23 @@ def _ngram_draft(history, last_token, k: int):
                      last_token[:, None])
 
 
+def _takes_attn_bound(fwd) -> bool:
+    """Whether a cached forward has ``_forward_cached``'s ``attn_bound``: the
+    built-in plans do; a caller's own ``forward_cached`` of the older five
+    arguments is called as ever and bounds its reads by the lengths."""
+    return "attn_bound" in inspect.signature(fwd).parameters
+
+
 def _build_decode_step(fwd, cfg, temperature, top_k, top_p, eos_token_id,
                        speculate_k: int = 0):
     """ONE jitted decode program for the whole engine lifetime: every slot
     advances one token — or, with ``speculate_k > 0``, up to ``k+1`` tokens
     verified in one batched ``(n_slots, k+1)`` forward (rows that are free
     or done compute masked garbage — the fixed shape is what buys zero
-    steady-state recompiles). Cache and state buffers are donated; params
+    steady-state recompiles; where the forward's attention reads by a per-row
+    bound, ``kv_cache.cache_attend``, they are given the bound 0, read nothing
+    and come out as zeros: their write offset stays their ``cache.length``).
+    Cache and state buffers are donated; params
     are NOT (the weight-publication hot swap relies on rebinding them
     without invalidating live buffers). The donated cache stays ONE buffer a
     side through the step: the forward's layer loop carries it whole and
@@ -308,12 +320,15 @@ def _build_decode_step(fwd, cfg, temperature, top_k, top_p, eos_token_id,
     before attention reads it — the same mechanism that parks done rows)."""
     k_spec = int(speculate_k)
     greedy = temperature is None or temperature <= 0
+    bounded = _takes_attn_bound(fwd)
 
     def decode(params, cache: KVCache, state: SlotState, run_mask):
         live = state.active & ~state.done & run_mask
         if k_spec == 0:
+            # a live row attends over its rows and the one it writes now
+            bound = {"attn_bound": jnp.where(live, cache.length + 1, 0)} if bounded else {}
             logits, new_cache = fwd(cfg, params, state.last_token[:, None],
-                                    cache)
+                                    cache, **bound)
             # fwd advanced every row's write offset; only live rows really did.
             lengths = jnp.where(live, new_cache.length, cache.length)
             pairs = jax.vmap(jax.random.split)(state.rng)  # (N, 2) keys
@@ -787,6 +802,7 @@ class ServingEngine:
                 known = ", ".join(sorted(GENERATION_PLANS))
                 raise ValueError(f"No generation plan for {name!r}; built-in: {known}")
         self._fwd = fwd
+        self._bounded = _takes_attn_bound(fwd)
         self.cfg = model.module.config
 
         c = self.config
@@ -902,7 +918,7 @@ class ServingEngine:
             "prompt_tokens_in": 0,
             "slot_allocs": 0, "slot_reuses": 0, "occupancy_sum": 0,
             # Cache rows the decoding slots held, summed over decode steps.
-            "live_rows_sum": 0,
+            "live_rows_sum": 0, "read_rows_sum": 0,
             "peak_occupancy": 0, "queue_depth_sum": 0, "queue_samples": 0,
             "steady_recompiles": 0, "prefill_steady_recompiles": 0,
             # Seconds in each phase of the tick and in whole ticks
@@ -1470,6 +1486,9 @@ class ServingEngine:
         self._stats["peak_occupancy"] = max(self._stats["peak_occupancy"], live)
         tr = self.tracing
         k_spec = self._speculate_k
+        # rows of a block where the step's attention reads by each slot's
+        # bound, None where it reads every row of every slot
+        read_block = decode_reads(self._cache) if self._bounded and k_spec == 0 else None
         for version, mask in self._decode_groups():
             with self._phase("serving.decode_dispatch"):
                 t0 = time.perf_counter() if (tr is not None
@@ -1481,6 +1500,8 @@ class ServingEngine:
                     self._params_for(version), self._cache, self._state, mask
                 )
                 self._stats["decode_steps"] += 1
+                if read_block is None:
+                    self._stats["read_rows_sum"] += self.n_slots * self.t_max
                 if self.telemetry is not None:
                     # PR-1 recompile-watchdog cross-check: sample the decode
                     # step's executable cache exactly like a train step's —
@@ -1514,7 +1535,10 @@ class ServingEngine:
                     cnt = int(emitted_np[slot])
                     # rows this step attended over: the prompt and every token
                     # written so far, the one it wrote among them
-                    self._stats["live_rows_sum"] += req.tokens.size + len(req.out)
+                    live_rows = req.tokens.size + len(req.out)
+                    self._stats["live_rows_sum"] += live_rows
+                    if read_block is not None:
+                        self._stats["read_rows_sum"] += rows_read(live_rows, read_block)
                     self._emit(req, toks_np[slot, :cnt], t_fetch)
                     if k_spec > 0:
                         req.spec_drafted += k_spec
@@ -2459,13 +2483,20 @@ class ServingEngine:
             ),
             "peak_occupancy": s["peak_occupancy"],
             # The KV cache: planes of the buffer (passes x layers), bytes one
-            # token's K and V take over all of them, and the mean over decode
+            # token's K and V take over all of them, and the means over decode
             # steps of the rows the decoding slots held (what a step had to
-            # read, where the buffer is n_slots x max_len rows).
+            # read, where the buffer is n_slots x max_len rows) and of the
+            # rows its attention did read: each decoding slot's rows rounded
+            # up to the decode kernel's block where that runs, the whole
+            # buffer where the dots over a layer's slice do.
             "cache": {
                 **self._cache_shape,
                 "live_rows_mean": (
                     round(s["live_rows_sum"] / s["decode_steps"], 3)
+                    if s["decode_steps"] else None
+                ),
+                "read_rows_mean": (
+                    round(s["read_rows_sum"] / s["decode_steps"], 3)
                     if s["decode_steps"] else None
                 ),
             },

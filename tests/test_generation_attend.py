@@ -7,6 +7,10 @@ same compiles show that a decode step writes the cache in place: it holds no
 second copy of the cache and puts no layer's slice back into a stack; and that
 Mixtral's programs read a layer's experts where the stacked parameter holds
 them: nothing of an expert tensor's size is copied, gathered or broadcast.
+And that a decode step's attention is the kernel of ``ops/decode_attention.py``
+over the whole cache stack: the Mosaic call is in every cell's decode program,
+no layer's K or V slice and no array of logits over ``T_max`` is; the prefill
+programs hold no such call and have not changed by a byte.
 The four programs of the two cells that were there before the layer loop
 learned to run a stack more than once are held to their lowered text of that
 day, and the looped cell's programs to one cache, one layer body and no copy
@@ -66,6 +70,13 @@ def test_attend_equals_the_explicit_repeat(hq, hkv, sq, quantized, masked):
 # -- the compiled programs of the serving cells ------------------------------------
 
 
+# A Mosaic kernel rides in the lowered text as its serialized module, which names
+# its source file by absolute path and every operation by line: left out of the
+# hashes below, so that they say the same in any checkout (the kernel is held by
+# ``tests/test_decode_attention.py``; a text with no kernel in it is hashed whole).
+_KERNEL_BODY = re.compile(r'(\\22body\\22: \\22)[A-Za-z0-9+/=]+')
+
+
 @pytest.fixture(scope="module")
 def cell_programs():
     """``get(cell_name) -> (cell, {"decode": compiled, "prefill": compiled})``, each
@@ -103,8 +114,9 @@ def cell_programs():
                 compiled[name] = cell, aot.serving_programs(cell, topo.devices[0])
             finally:
                 jax.stages.Lowered.compile = compile_lowered
-            get.lowered[name] = {program: hashlib.sha256(text.encode()).hexdigest()
-                                 for program, text in zip(compiled[name][1], texts)}
+            get.lowered[name] = {program: hashlib.sha256(
+                _KERNEL_BODY.sub(r"\1", text).encode()).hexdigest()
+                for program, text in zip(compiled[name][1], texts)}
         return compiled[name]
 
     get.lowered = {}
@@ -200,19 +212,57 @@ def test_the_steady_cell_runs_no_expert_code(cell_programs, program):
     assert "moe" not in cell_programs("mistral_serve_steady")[1][program].as_text()
 
 
+# -- decode attention: the kernel over the stack, chosen by what the program is lowered for
+
+
+@pytest.mark.parametrize("case", ["decode_holds_the_kernel_once", "no_layer_slice_of_the_cache",
+                                  "no_logits_over_t_max", "prefill_holds_no_kernel"])
+@pytest.mark.parametrize("cell_name", ["mistral_serve_steady", "mixtral_serve_decode",
+                                       "ouro_serve_reason"])
+def test_a_decode_step_reads_the_cache_through_the_kernel(cell_programs, cell_name, case):
+    """These compiles run in a process that holds a CPU: the choice between the
+    kernel and the dots over a slice is made for the platform the program is
+    lowered for, so the text here is the chip's program."""
+    cell, programs = cell_programs(cell_name)
+    cfg, eng = cell.config, cell.workload["engine"]
+    b, t = eng["n_slots"], eng["max_len"]
+    hq, hkv, d = cfg["num_attention_heads"], cfg["num_key_value_heads"], cfg["head_dim"]
+    decode = programs["decode"].as_text()
+    kernels = [line for line in decode.splitlines()
+               if "custom-call(" in line and "tpu_custom_call" in line]
+    if case == "decode_holds_the_kernel_once":
+        # one call in the one layer body, named for the trace to find it
+        assert len(kernels) == 1 and "%decode_attention" in kernels[0]
+        # handed both whole stacks, not a slice of them
+        planes = cfg.get("total_ut_steps", 1) * cfg["num_hidden_layers"]
+        assert kernels[0].count(f"bf16[{planes},{b},{t},{hkv},{d}]") >= 2
+    elif case == "no_layer_slice_of_the_cache":
+        # the lift-out of a layer's K or V (PERF.md, section 5: 4.3 of a 17.3 ms step)
+        assert f"[1,{b},{t},{hkv},{d}]" not in decode and f"bf16[{b},{t},{hkv},{d}]" not in decode
+    elif case == "no_logits_over_t_max":
+        # _attend's scores and weights over every row of every slot, as XLA shaped them
+        shapes = {f"[{b},{hkv},{hq // hkv},{t}]", f"[{b},{hkv},{hq // hkv},1,{t}]",
+                  f"[{b},{t},{hq}]", f"[{b},{hq},{t}]"}
+        assert not [shape for shape in shapes if shape in decode]
+    else:
+        assert "tpu_custom_call" not in programs["prefill"].as_text()
+
+
 # -- a stack run more than once: the programs that were there stay, to the byte ------
 
 # sha256 of the lowered StableHLO text (no locations in it) of the four programs, taken
 # on the commit before ``_forward_cached`` gained its loop over passes. With one pass
 # the traced program is that one. A PR that means to change one of these programs
 # replaces its line, and says which; one that does not has touched their path.
+# The two decode lines are PR 35's (attention through ``kv_cache.cache_attend``); the
+# two prefill lines are still that day's: a prompt chunk never reaches the kernel.
 LOWERED_BEFORE_PASSES = {
     ("mistral_serve_steady", "decode"):
-        "53b4d14f865801697db8aac3eca60c6dc4f6d5955cf26e1a4f64781a383b8589",
+        "0b59a7366456bad7d9e258f8aa799a614251623bf2f93add9f66796667c29a64",
     ("mistral_serve_steady", "prefill"):
         "f48f7bc1f733a4f268b3a7c1d4db0ce99a7444efa761acc805fc5560f209de64",
     ("mixtral_serve_decode", "decode"):
-        "13fc764d41a7b5637e73c40fad5e53c96803164c330aa2e0ee801b0980d93ce9",
+        "d7dfd881202f36f25c173352c45756da81d386d1a9a9e7d34a4a767fbcea7759",
     ("mixtral_serve_decode", "prefill"):
         "33421fcc02a5ec145869358ad6519b4bbae66b3eab354af47b1d3f58f488f859",
 }

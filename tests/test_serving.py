@@ -240,6 +240,30 @@ def test_cache_counters_say_what_the_decode_steps_had_to_read(llama, lengths, bu
     assert engine.stats()["cache"]["planes"] == cfg.num_hidden_layers
 
 
+@pytest.mark.parametrize("forced", [False, True], ids=["dots_over_the_slice", "kernel_forced"])
+def test_cache_counters_say_what_the_decode_steps_did_read(llama, monkeypatch, forced):
+    """``read_rows_mean`` beside ``live_rows_mean``: off the chip a decode
+    step's attention reads the whole buffer, ``n_slots x max_len`` rows; where
+    the decode kernel runs (forced here, under the interpreter, in blocks of 4
+    rows) it reads each decoding slot's rows rounded up to a block and nothing
+    of the slot that is free."""
+    from accelerate_tpu.ops import decode_attention
+
+    if forced:
+        monkeypatch.setattr(decode_attention, "INTERPRET", True)
+        monkeypatch.setattr(decode_attention, "block_rows", lambda t_max, *_: 4)
+    cfg, model = llama
+    engine = ServingEngine(model, ServingConfig(n_slots=2, max_len=64, prefill_chunks=[8]))
+    engine.run(_prompts(cfg, [5], seed=2), max_new_tokens=[6])
+    cache = engine.stats()["cache"]
+    assert engine.stats()["decode_steps"] == 5
+    assert cache["live_rows_mean"] == pytest.approx(sum(5 + j for j in range(1, 6)) / 5)
+    # 6, 7, 8, 9 and 10 rows, in blocks of 4: 8 + 8 + 8 + 12 + 12
+    assert cache["read_rows_mean"] == pytest.approx(48 / 5 if forced else 2 * 64)
+    engine.reset_metrics()
+    assert engine.stats()["cache"]["read_rows_mean"] is None
+
+
 def test_incremental_submit_poll(llama):
     """The front-end contract: submissions land mid-flight, poll() delivers
     each result exactly once."""

@@ -203,3 +203,29 @@ def test_fp8_lowering_has_f8_types():
         .lower()
     )
     assert "f8e4m3" in txt or "f8e5m2" in txt, "no float8 types in lowered HLO"
+
+
+@pytest.mark.parametrize("hq,hkv,t_max", [(32, 8, 2048), (16, 16, 512)], ids=["gqa", "mha"])
+def test_decode_attention_compiled_matches_attend(hq, hkv, t_max):
+    """The decode kernel over the whole cache stack, compiled, at the serving
+    cells' widths: equal to ``_attend`` over the layer's slice on the slots
+    that decode, zeros on the one that does not, with bounds at a block's
+    edge, inside a block and at ``T_max``."""
+    from accelerate_tpu.generation import _attend
+    from accelerate_tpu.ops.decode_attention import block_rows, decode_attention
+
+    planes, slots, d = 3, 5, 128
+    block = block_rows(t_max, hkv, d, jnp.bfloat16)
+    kq, kk, kv = jax.random.split(jax.random.key(0), 3)
+    q = jax.random.normal(kq, (slots, 1, hq, d), jnp.bfloat16)
+    ck = jax.random.normal(kk, (planes, slots, t_max, hkv, d), jnp.bfloat16)
+    cv = jax.random.normal(kv, (planes, slots, t_max, hkv, d), jnp.bfloat16)
+    bound = jnp.asarray([0, 1, block, block + 37, t_max], jnp.int32)
+    ck, cv = ck.at[:, 0].set(jnp.nan), cv.at[:, 0].set(jnp.nan)   # never read
+
+    out = jax.jit(decode_attention)(q, ck, cv, jnp.int32(2), bound)
+    want = _attend(q, ck[2], cv[2], (bound - 1)[:, None])
+
+    out, want = np.asarray(out, np.float32), np.asarray(want, np.float32)
+    assert not out[0].any()
+    np.testing.assert_allclose(out[1:], want[1:], rtol=3e-2, atol=3e-2)
