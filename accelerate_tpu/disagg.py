@@ -170,6 +170,8 @@ class DisaggServingEngine(ServingEngine):
                          compile_manager=compile_manager, telemetry=telemetry,
                          fault_tolerance=fault_tolerance, chaos=chaos,
                          tracing=tracing, journal=journal, profiler=profiler)
+        # a prompt chunk runs on its lane's mesh, never inside the decode step
+        self._decode_chunk = None
         dc = self.disagg_config
         # Degradation state: quarantined lanes leave the pool for good; once
         # EVERY lane is gone the engine latches degraded and prefills
@@ -879,7 +881,7 @@ class DisaggServingEngine(ServingEngine):
         if size is not None:
             self._decode_executables_baseline = size
         if self._prefill_executables_warm is not None:
-            self._prefill_executables_warm = _cache_size(self._prefill)
+            self._prefill_executables_warm = self._chunk_executables()
         self._rstats["resizes"] += 1
         if tr is not None:
             tr.end(h_commit, self._stats["ticks"], rebound=rebound,
@@ -1066,7 +1068,7 @@ class DisaggServingEngine(ServingEngine):
         prompt_len = min(sum(self.ladder), self.t_max - 2)
         prompt = np.ones((prompt_len,), np.int32)
         self.run([prompt] * len(self._lanes), max_new_tokens=2)
-        self._prefill_executables_warm = _cache_size(self._prefill)
+        self._prefill_executables_warm = self._chunk_executables()
         self.reset_metrics()
 
     def reset_metrics(self) -> None:

@@ -181,8 +181,20 @@ def _attend(q, k, v, q_positions, kv_valid=None):
     return out.reshape(b, hkv, g, sq, d).transpose(0, 3, 1, 2, 4).reshape(b, sq, hq, d)
 
 
+class PromptChunk(NamedTuple):
+    """A prompt chunk that rides a decode step (:func:`_forward_cached`'s
+    ``chunk``): ``ids`` (1, C) int32 written into slot ``slot`` at rows
+    ``start ..``, of which the first ``valid`` are the prompt's (all ()
+    int32)."""
+
+    ids: jax.Array
+    slot: jax.Array
+    start: jax.Array
+    valid: jax.Array
+
+
 def _forward_cached(decoder, cfg, params, input_ids, cache: KVCache, return_all=False,
-                    pad_offset=None, kv_valid=None, attn_bound=None):
+                    pad_offset=None, kv_valid=None, attn_bound=None, chunk=None):
     """Run ``input_ids`` (appended at cache.length) through all layers,
     returning (logits, new_cache) — last-token logits, or every position's
     with ``return_all`` (speculative verification needs them). The one loop
@@ -217,6 +229,17 @@ def _forward_cached(decoder, cfg, params, input_ids, cache: KVCache, return_all=
     counts each row's leading pads — the position ids the family embeds or
     rotates by shift down by it so row content starts at position 0 — and
     ``kv_valid`` (B, T_max) masks the pad slots out of attention forever.
+
+    ``chunk`` (a :class:`PromptChunk`) rides a step of one token a row: the
+    B rows and the chunk's C tokens go through the family's embedding,
+    blocks and norm as one row of B + C tokens, so every weight is read
+    once for both. Only ``attend`` tells them apart: the B rows reach the
+    cache as above; then the chunk's rows are written into its slot
+    (``kv_cache.slot_step``), over the row of no use that the slot's own
+    row of the B has just written at its length, and attend over that
+    slot's plane. The head runs on the B rows and the chunk's row
+    ``valid - 1``: the logits are (B + 1, V). The returned length is the B
+    rows' (``cache.length + 1``); the chunk's slot's is the caller's.
     """
     if not cfg.scan_layers:
         raise ValueError("generation requires scan_layers=True (stacked blocks)")
@@ -226,7 +249,14 @@ def _forward_cached(decoder, cfg, params, input_ids, cache: KVCache, return_all=
     pos_ids = positions
     if pad_offset is not None:
         pos_ids = jnp.maximum(positions - pad_offset[:, None], 0)
-    x, stacked, block, norm, head, *xs = decoder(cfg, params, input_ids, pos_ids)
+    ids = input_ids
+    if chunk is not None:
+        if s != 1 or return_all or kv_valid is not None or pad_offset is not None:
+            raise ValueError("a prompt chunk rides a plain step of one token a row")
+        chunk_pos = chunk.start + jnp.arange(chunk.ids.shape[1], dtype=jnp.int32)
+        ids = jnp.concatenate([input_ids[:, 0], chunk.ids[0]])[None]
+        pos_ids = jnp.concatenate([positions[:, 0], chunk_pos])[None]
+    x, stacked, block, norm, head, *xs = decoder(cfg, params, ids, pos_ids)
     by_bound = s == 1 and kv_valid is None and kv_cache.decode_block_rows(cache.k) is not None
     if by_bound and attn_bound is None:
         attn_bound = positions[:, 0] + 1
@@ -235,7 +265,7 @@ def _forward_cached(decoder, cfg, params, input_ids, cache: KVCache, return_all=
         h, ck, cv = carry  # hidden state, the whole (L,B,T,Hkv,D) cache
         p, i, *x_i = layer  # layer params, layer index
 
-        def attend(q, k_new, v_new):
+        def attend_rows(q, k_new, v_new):
             nonlocal ck, cv
             if by_bound:
                 ck, cv, out = kv_cache.cache_attend(
@@ -244,6 +274,16 @@ def _forward_cached(decoder, cfg, params, input_ids, cache: KVCache, return_all=
                 return out
             ck, cv, k_i, v_i = kv_cache.cache_step(ck, cv, k_new, v_new, i, start)
             return _attend(q, k_i, v_i, positions, kv_valid)
+
+        def attend(q, k_new, v_new):
+            nonlocal ck, cv
+            if chunk is None:
+                return attend_rows(q, k_new, v_new)
+            (q, q_c), (k_new, k_c), (v_new, v_c) = (
+                (a[0, :b, None], a[:, b:]) for a in (q, k_new, v_new))
+            out = attend_rows(q, k_new, v_new)  # first: the chunk writes over its slot's row
+            ck, cv, k_s, v_s = kv_cache.slot_step(ck, cv, k_c, v_c, i, chunk.slot, chunk.start)
+            return jnp.concatenate([out[:, 0], _attend(q_c, k_s, v_s, chunk_pos[None])[0]])[None]
 
         return (block(p, h, attend, *x_i), ck, cv), None
 
@@ -259,8 +299,11 @@ def _forward_cached(decoder, cfg, params, input_ids, cache: KVCache, return_all=
     else:
         (x, new_k, new_v), _ = jax.lax.scan(
             jax.named_scope("ut_pass")(one_pass), carry, planes.reshape(passes, -1))
-    logits = head(x if return_all else x[:, -1])
-    return logits.astype(jnp.float32), KVCache(new_k, new_v, start + s)
+    if chunk is not None:
+        x = jnp.concatenate([x[0, :b], jax.lax.dynamic_slice_in_dim(x[0], b + chunk.valid - 1, 1)])
+    elif not return_all:
+        x = x[:, -1]
+    return head(x).astype(jnp.float32), KVCache(new_k, new_v, start + s)
 
 
 def _moe_mlp(cfg, p, h):

@@ -9,9 +9,10 @@ written them or not. An int8 cache holds each side as a :class:`QuantPages`
 pair of leaves (data and one scale per row and head) with the same leading
 axes. Nothing outside this module indexes a cache leaf by axis number or asks
 whether a side is one leaf or two: the forwards, the serving engines and the
-planner go through :class:`KVCache`'s operations, :func:`cache_step` and, for
-a step of one query row a slot, :func:`cache_attend`, which hands the decode
-kernel (``ops/decode_attention.py``) the buffers in this order of axes.
+planner go through :class:`KVCache`'s operations, :func:`cache_step`, for
+a step of one query row a slot :func:`cache_attend`, which hands the decode
+kernel (``ops/decode_attention.py``) the buffers in this order of axes, and
+for a prompt chunk that rides such a step :func:`slot_step`.
 """
 
 from __future__ import annotations
@@ -222,23 +223,25 @@ def kv_bytes_per_token(cfg, dtype=None) -> int:
 
 
 @jax.named_scope("cache_write")
-def _cache_write(buf, new, layer, start):
+def _cache_write(buf, new, layer, start, slot=None):
     """Write ``new`` (B, S, Hkv, D) into the whole cache buffer ``buf``
     (L, B, T, Hkv, D), in place, at layer ``layer`` and row offset ``start``:
     a scalar (one ``dynamic_update_slice`` at ``(layer, 0, start, 0, 0)``) or
     a per-row vector (a scatter at ``[layer, row, start[row] + s]``, the
-    slot cache's path) — ``start.ndim`` decides, at trace time. Only the new
+    slot cache's path; with ``slot``, the one row of ``new`` goes to that
+    slot) — ``start.ndim`` decides, at trace time. Only the new
     rows move: ``buf`` rides the layer loop's carry, so the result aliases
     it. A ``QuantPages`` cache quantizes the new pages here, writing data
     and scale leaves at the same offsets."""
     if isinstance(buf, QuantPages):
         q = quantize_kv_page(new)
-        return QuantPages(_cache_write(buf.data, q.data, layer, start),
-                          _cache_write(buf.scale, q.scale, layer, start))
+        return QuantPages(_cache_write(buf.data, q.data, layer, start, slot),
+                          _cache_write(buf.scale, q.scale, layer, start, slot))
     new = new.astype(buf.dtype)
     if getattr(start, "ndim", 0) == 1:
         b, s = new.shape[:2]
-        rows = jnp.arange(b, dtype=jnp.int32)[:, None]
+        rows = (jnp.arange(b, dtype=jnp.int32)[:, None] if slot is None
+                else jnp.reshape(slot, (1, 1)))
         cols = start[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
         return buf.at[layer, rows, cols].set(new)
     return jax.lax.dynamic_update_slice(buf, new[None], (layer, 0, start, 0, 0))
@@ -254,6 +257,20 @@ def cache_step(ck, cv, k_new, v_new, layer, start):
     ck, cv = _cache_write(ck, k_new, layer, start), _cache_write(cv, v_new, layer, start)
     k_i, v_i = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, False), (ck, cv))
     return ck, cv, k_i, v_i
+
+
+def slot_step(ck, cv, k_new, v_new, layer, slot, start):
+    """One slot's turn at one layer for a prompt chunk that rides a decode
+    step: write its (1, C, Hkv, D) rows at rows ``start ..`` of slot
+    ``slot`` of plane ``layer``, in place, and read that slot's plane back,
+    ``(1, T_max, Hkv, D)`` a side, for attention. Returns ``(ck, cv,
+    k_slot, v_slot)``; no other slot is read or moved."""
+    ck, cv = (_cache_write(a, new, layer, start[None], slot)
+              for a, new in ((ck, k_new), (cv, v_new)))
+    k_s, v_s = jax.tree.map(
+        lambda a: jax.lax.dynamic_slice(a, (layer, slot, 0, 0, 0), (1, 1) + a.shape[2:])[0],
+        (ck, cv))
+    return ck, cv, k_s, v_s
 
 
 def decode_block_rows(side) -> int | None:

@@ -19,7 +19,8 @@ vLLM/TGI-class fix, TPU-shaped:
   compile manager's seq buckets when available) and written a chunk per
   tick, so a long prompt never head-of-line-blocks decode latency and every
   possible prompt length compiles at most ``len(ladder)`` prefill
-  executables.
+  executables. The tick's chunk rides its decode step, one program
+  (:func:`_build_decode_chunk_step`), so the weights are read once a tick.
 - **Zero-recompile decode** — the steady-state decode step is ONE jitted
   ``(params, cache, slot_state) -> (cache, slot_state, tokens, bad)``
   program with donated cache buffers (``bad`` is the nonfinite-logits
@@ -99,6 +100,7 @@ from .chaos import InjectedFaultError
 from .generation import (
     ENCDEC_GENERATION_PLANS,
     GENERATION_PLANS,
+    PromptChunk,
     _filter_logits,
     sample_logits,
 )
@@ -273,6 +275,108 @@ def _takes_attn_bound(fwd) -> bool:
     return "attn_bound" in inspect.signature(fwd).parameters
 
 
+def _takes_chunk(fwd) -> bool:
+    """Whether a cached forward takes ``_forward_cached``'s ``chunk``: a
+    prompt chunk that rides a decode step (:func:`_build_decode_chunk_step`)."""
+    return "chunk" in inspect.signature(fwd).parameters
+
+
+def _advance_live_rows(cache, new_cache, state, logits, live, *, temperature,
+                       top_k, top_p, eos_token_id):
+    """A decode step of one token a slot after its forward (``logits``
+    (N, V), ``new_cache`` the forward's): sample every live row, advance
+    its length, count and stream, and flag what is done or nonfinite.
+    Returns the decode program's 5-tuple. Shared by ``decode`` (k = 0) and
+    ``decode_chunk``."""
+    # fwd advanced every row's write offset; only live rows really did.
+    lengths = jnp.where(live, new_cache.length, cache.length)
+    pairs = jax.vmap(jax.random.split)(state.rng)  # (N, 2) keys
+    carry, sub = pairs[:, 0], pairs[:, 1]
+    # Per-slot sampling over a (1, V) row — the same shape a batch-1
+    # generate() samples, so per-request streams match it exactly.
+    tok = jax.vmap(
+        lambda row, key: sample_logits(
+            row[None], key, temperature=temperature, top_k=top_k,
+            top_p=top_p
+        )[0]
+    )(logits, sub)
+    tok = jnp.where(live, tok, state.last_token)
+    # Nonfinite-logits sentinel: flag live rows whose logits went
+    # NaN/inf (a poisoned KV page). Computed on the PRE-update live
+    # mask so parked rows' masked garbage never flags, and fetched
+    # with the same host sync as (tok, done) — no extra dispatch
+    # stall.
+    bad = live & ~jnp.isfinite(logits).all(axis=-1)
+    generated = state.generated + live.astype(jnp.int32)
+    newly_done = live & (generated >= state.budget)
+    if eos_token_id is not None:
+        newly_done = newly_done | (live & (tok == eos_token_id))
+    new_state = SlotState(
+        last_token=tok,
+        active=state.active,
+        done=state.done | newly_done,
+        generated=generated,
+        budget=state.budget,
+        # Masked rows' streams must freeze (another version's
+        # dispatch owns their advance this tick); free/done slots'
+        # streams are dead until realloc rewrites them either way.
+        rng=_select_keys(live, carry, state.rng),
+        history=state.history,
+    )
+    return (new_cache._replace(length=lengths), new_state,
+            tok[:, None], live.astype(jnp.int32), bad)
+
+
+def _arm_chunk_slot(cache, state, last_logits, chunk, slot, valid, budget, rng,
+                    is_first, is_final, start, *, temperature, top_k, top_p,
+                    eos_token_id):
+    """A prompt chunk's slot after its forward wrote rows ``start ..``:
+    commit ``start + valid`` as its length, sample the request's first token
+    from ``last_logits()`` (the row of its last real prompt position; a
+    thunk, so that it is read where the prefill program always read it) and,
+    on the final chunk, arm the slot for decode. Returns ``(cache, state,
+    tok, done0)``. Shared by ``prefill`` and ``decode_chunk``."""
+    # Advance by the VALID tokens only; a padded tail is overwritten by
+    # the next write and never attended (causal bound at true length).
+    lengths = cache.length.at[slot].set(start + valid)
+
+    carry, sub_key = jax.random.split(rng)
+    last = last_logits()
+    tok = sample_logits(
+        last[None], sub_key, temperature=temperature, top_k=top_k, top_p=top_p
+    )[0]
+    done0 = budget <= 1
+    if eos_token_id is not None:
+        done0 = done0 | (tok == eos_token_id)
+    done0 = is_final & done0
+    # Seed the slot's n-gram history: shift the chunk's VALID tokens in
+    # (first chunk resets the window to -1 padding first), and on the
+    # final chunk shift in the sampled first token so the armed-slot
+    # invariant history[:, -1] == last_token holds entering decode.
+    h = state.history.shape[1]
+    hist0 = jnp.where(is_first,
+                      jnp.full((h,), -1, jnp.int32),
+                      state.history[slot])
+    hbuf = jnp.concatenate([hist0, chunk[0].astype(jnp.int32)])
+    hist1 = jax.lax.dynamic_slice_in_dim(hbuf, valid, h)
+    hist2 = jnp.where(is_final,
+                      jnp.concatenate([hist1[1:], tok[None]]), hist1)
+    new_state = SlotState(
+        # Intermediate chunks park a garbage token here; the final chunk
+        # (the only one decode can observe — active stays False until
+        # then) overwrites it with the real first token.
+        last_token=state.last_token.at[slot].set(tok),
+        active=state.active.at[slot].set(is_final),
+        done=state.done.at[slot].set(done0),
+        generated=state.generated.at[slot].set(
+            jnp.where(is_final, 1, 0).astype(jnp.int32)),
+        budget=state.budget.at[slot].set(budget),
+        rng=state.rng.at[slot].set(carry),
+        history=state.history.at[slot].set(hist2),
+    )
+    return cache._replace(length=lengths), new_state, tok, done0
+
+
 def _build_decode_step(fwd, cfg, temperature, top_k, top_p, eos_token_id,
                        speculate_k: int = 0):
     """ONE jitted decode program for the whole engine lifetime: every slot
@@ -321,6 +425,8 @@ def _build_decode_step(fwd, cfg, temperature, top_k, top_p, eos_token_id,
     k_spec = int(speculate_k)
     greedy = temperature is None or temperature <= 0
     bounded = _takes_attn_bound(fwd)
+    sampling = dict(temperature=temperature, top_k=top_k, top_p=top_p,
+                    eos_token_id=eos_token_id)
 
     def decode(params, cache: KVCache, state: SlotState, run_mask):
         live = state.active & ~state.done & run_mask
@@ -329,43 +435,7 @@ def _build_decode_step(fwd, cfg, temperature, top_k, top_p, eos_token_id,
             bound = {"attn_bound": jnp.where(live, cache.length + 1, 0)} if bounded else {}
             logits, new_cache = fwd(cfg, params, state.last_token[:, None],
                                     cache, **bound)
-            # fwd advanced every row's write offset; only live rows really did.
-            lengths = jnp.where(live, new_cache.length, cache.length)
-            pairs = jax.vmap(jax.random.split)(state.rng)  # (N, 2) keys
-            carry, sub = pairs[:, 0], pairs[:, 1]
-            # Per-slot sampling over a (1, V) row — the same shape a batch-1
-            # generate() samples, so per-request streams match it exactly.
-            tok = jax.vmap(
-                lambda row, key: sample_logits(
-                    row[None], key, temperature=temperature, top_k=top_k,
-                    top_p=top_p
-                )[0]
-            )(logits, sub)
-            tok = jnp.where(live, tok, state.last_token)
-            # Nonfinite-logits sentinel: flag live rows whose logits went
-            # NaN/inf (a poisoned KV page). Computed on the PRE-update live
-            # mask so parked rows' masked garbage never flags, and fetched
-            # with the same host sync as (tok, done) — no extra dispatch
-            # stall.
-            bad = live & ~jnp.isfinite(logits).all(axis=-1)
-            generated = state.generated + live.astype(jnp.int32)
-            newly_done = live & (generated >= state.budget)
-            if eos_token_id is not None:
-                newly_done = newly_done | (live & (tok == eos_token_id))
-            new_state = SlotState(
-                last_token=tok,
-                active=state.active,
-                done=state.done | newly_done,
-                generated=generated,
-                budget=state.budget,
-                # Masked rows' streams must freeze (another version's
-                # dispatch owns their advance this tick); free/done slots'
-                # streams are dead until realloc rewrites them either way.
-                rng=_select_keys(live, carry, state.rng),
-                history=state.history,
-            )
-            return (new_cache._replace(length=lengths), new_state,
-                    tok[:, None], live.astype(jnp.int32), bad)
+            return _advance_live_rows(cache, new_cache, state, logits, live, **sampling)
 
         # ---- speculative path: draft k, verify k+1 in ONE forward ----
         n = state.last_token.shape[0]
@@ -463,6 +533,8 @@ def _build_prefill_step(fwd, cfg, temperature, top_k, top_p, eos_token_id):
     inside it. Writes a ``(1, C)`` prompt chunk into ``slot`` at that slot's
     own offset; on the final chunk it samples the request's first token
     (TTFT) and arms the slot for decode."""
+    sampling = dict(temperature=temperature, top_k=top_k, top_p=top_p,
+                    eos_token_id=eos_token_id)
 
     def prefill(params, cache: KVCache, state: SlotState, chunk, slot, valid,
                 budget, rng, is_first, is_final):
@@ -472,47 +544,42 @@ def _build_prefill_step(fwd, cfg, temperature, top_k, top_p, eos_token_id):
         sub_cache = cache.take_slot(slot, start)
         logits_all, sub_cache = fwd(cfg, params, chunk, sub_cache, return_all=True)
         cache = cache.put_slot(slot, sub_cache)
-        # Advance by the VALID tokens only; a padded tail is overwritten by
-        # the next write and never attended (causal bound at true length).
-        lengths = cache.length.at[slot].set(start + valid)
-
-        carry, sub_key = jax.random.split(rng)
-        last = logits_all[0, valid - 1]  # the last REAL prompt position
-        tok = sample_logits(
-            last[None], sub_key, temperature=temperature, top_k=top_k, top_p=top_p
-        )[0]
-        done0 = budget <= 1
-        if eos_token_id is not None:
-            done0 = done0 | (tok == eos_token_id)
-        done0 = is_final & done0
-        # Seed the slot's n-gram history: shift the chunk's VALID tokens in
-        # (first chunk resets the window to -1 padding first), and on the
-        # final chunk shift in the sampled first token so the armed-slot
-        # invariant history[:, -1] == last_token holds entering decode.
-        h = state.history.shape[1]
-        hist0 = jnp.where(is_first,
-                          jnp.full((h,), -1, jnp.int32),
-                          state.history[slot])
-        hbuf = jnp.concatenate([hist0, chunk[0].astype(jnp.int32)])
-        hist1 = jax.lax.dynamic_slice_in_dim(hbuf, valid, h)
-        hist2 = jnp.where(is_final,
-                          jnp.concatenate([hist1[1:], tok[None]]), hist1)
-        new_state = SlotState(
-            # Intermediate chunks park a garbage token here; the final chunk
-            # (the only one decode can observe — active stays False until
-            # then) overwrites it with the real first token.
-            last_token=state.last_token.at[slot].set(tok),
-            active=state.active.at[slot].set(is_final),
-            done=state.done.at[slot].set(done0),
-            generated=state.generated.at[slot].set(
-                jnp.where(is_final, 1, 0).astype(jnp.int32)),
-            budget=state.budget.at[slot].set(budget),
-            rng=state.rng.at[slot].set(carry),
-            history=state.history.at[slot].set(hist2),
-        )
-        return cache._replace(length=lengths), new_state, tok, done0
+        return _arm_chunk_slot(cache, state, lambda: logits_all[0, valid - 1], chunk,
+                               slot, valid, budget, rng, is_first, is_final, start,
+                               **sampling)
 
     return jax.jit(prefill, donate_argnums=(1, 2))
+
+
+def _build_decode_chunk_step(fwd, cfg, temperature, top_k, top_p, eos_token_id):
+    """ONE jitted program for a tick that advances a prompt chunk: the k = 0
+    decode step of every slot and the chunk of the request in ``slot``, in
+    one forward (``_forward_cached``'s ``chunk``), so every weight is read
+    once a tick and not once for each. The slot's own decode row reads
+    nothing (it is not live: its request is still prefilling) and the chunk
+    is then committed as :func:`_build_prefill_step` commits it; a request
+    it arms decodes from the next tick. Each ladder rung is one executable.
+    Returns the decode program's 5-tuple and the chunk's ``(tok, done0)``:
+    one fetch brings all of them."""
+    sampling = dict(temperature=temperature, top_k=top_k, top_p=top_p,
+                    eos_token_id=eos_token_id)
+    bounded = _takes_attn_bound(fwd)
+
+    def decode_chunk(params, cache: KVCache, state: SlotState, run_mask, chunk,
+                     slot, valid, budget, rng, is_first, is_final):
+        live = state.active & ~state.done & run_mask
+        start = jnp.where(is_first, 0, cache.length[slot])
+        bound = {"attn_bound": jnp.where(live, cache.length + 1, 0)} if bounded else {}
+        logits, new_cache = fwd(cfg, params, state.last_token[:, None], cache,
+                                chunk=PromptChunk(chunk, slot, start, valid), **bound)
+        cache, state, toks, emitted, bad = _advance_live_rows(
+            cache, new_cache, state, logits[:-1], live, **sampling)
+        cache, state, tok, done0 = _arm_chunk_slot(
+            cache, state, lambda: logits[-1], chunk, slot, valid, budget, rng,
+            is_first, is_final, start, **sampling)
+        return cache, state, toks, emitted, bad, tok, done0
+
+    return jax.jit(decode_chunk, donate_argnums=(1, 2))
 
 
 def _release_slot_op(state: SlotState, slot) -> SlotState:
@@ -574,9 +641,9 @@ def _cache_size(fn) -> Optional[int]:
 TICK = "serving.tick"
 TICK_PHASES = (
     "serving.admit",              # preemption latch, deadlines, admission, queue sample
-    "serving.prefill",            # a prompt chunk: host build and dispatch
-    "serving.first_token_fetch",  # the blocking fetch of a final chunk's token
-    "serving.decode_dispatch",    # version groups and the decode call
+    "serving.prefill",            # a prompt chunk: host build, and dispatch where it runs alone
+    "serving.first_token_fetch",  # the blocking fetch of a lone final chunk's token
+    "serving.decode_dispatch",    # version groups and the decode call (a riding chunk's too)
     "serving.decode_fetch",       # the fused device_get: host blocked on the device
     "serving.bookkeeping",        # per-slot loop after the fetch, retire, compile watch
     "serving.end_tick",           # journal, chaos draw, hang guard, SDC canary
@@ -699,6 +766,17 @@ class _Request:
         self.token_t = []
         self.spec_drafted = 0
         self.spec_accepted = 0
+
+
+class _Chunk(NamedTuple):
+    """One prompt chunk on its way to the device: ``ids`` (1, size)."""
+
+    req: _Request
+    ids: np.ndarray
+    valid: int
+    is_first: bool
+    is_final: bool
+    t0: Optional[float]  # perf_counter at its build, where a tracer or TTFT needs it
 
 
 TTFT_TERMS = ("queue_wait_s", "prefill_blocked_s", "prefill_own_s")
@@ -840,6 +918,13 @@ class ServingEngine:
         self._prefill = _build_prefill_step(
             fwd, self.cfg, c.temperature, c.top_k, c.top_p, eos
         )
+        # A tick that advances a prompt chunk runs it inside the decode step
+        # (one program, the weights read once) where the forward takes a
+        # chunk and the step decodes one token a slot; None: two programs.
+        self._decode_chunk = (
+            _build_decode_chunk_step(fwd, self.cfg, c.temperature, c.top_k, c.top_p, eos)
+            if self._speculate_k == 0 and _takes_chunk(fwd) else None)
+        self._chunks_per_tick = max(1, int(c.prefill_chunks_per_tick))
         # Cache, slot state, and params all enter the jitted programs
         # committed in place: the jit cache keys on placement commitment,
         # and commitment is infectious — with committed params, the cache
@@ -914,7 +999,8 @@ class ServingEngine:
         self._queue_depth_window: deque[int] = deque(maxlen=wn)
         self._stats = {
             "submitted": 0, "completed": 0, "ticks": 0, "decode_steps": 0,
-            "prefill_chunks": 0, "prefill_pad_tokens": 0, "tokens_out": 0,
+            "prefill_chunks": 0, "prefill_chunks_fused": 0,
+            "prefill_pad_tokens": 0, "tokens_out": 0,
             "prompt_tokens_in": 0,
             "slot_allocs": 0, "slot_reuses": 0, "occupancy_sum": 0,
             # Cache rows the decoding slots held, summed over decode steps.
@@ -951,8 +1037,9 @@ class ServingEngine:
         self._phase_t = 0.0
         self._tick_t0 = 0.0
         self._tick_acc = dict.fromkeys(TICK_PHASES, 0.0)
-        # _cache_size(self._prefill) when warmup() ended; None until then,
-        # and then prefill programs compile on demand and nothing is watched.
+        # _chunk_executables() when warmup() ended; None until then, and
+        # then the programs that carry a chunk compile on demand and nothing
+        # is watched.
         self._prefill_executables_warm: Optional[int] = None
         # Decode canary (sdc.py DecodeCanary): attached via
         # attach_sdc_canary(); every tick-end hook is a single None check.
@@ -1138,7 +1225,9 @@ class ServingEngine:
         """One scheduler round: sweep deadlines (and the preemption latch),
         admit into free slots, advance one prompt chunk (up to
         ``prefill_chunks_per_tick``), then one decode step for every live
-        slot. Raises :class:`ServingStalledError` via the hang guard if
+        slot. The tick's last chunk rides the decode step, one program for
+        both, where :meth:`_rides` says it can; the others run alone before
+        it. Raises :class:`ServingStalledError` via the hang guard if
         ``max_idle_ticks`` rounds pass with pending requests and zero
         progress."""
         with self._phase(TICK):
@@ -1146,12 +1235,19 @@ class ServingEngine:
                 snap = self._begin_tick()
                 self._admit()
                 self._sample_queue_depth()
-            for _ in range(max(1, int(self.config.prefill_chunks_per_tick))):
+            ride = None
+            for i in range(self._chunks_per_tick):
                 if not self._prefilling:
                     break
-                self._prefill_one(self._prefilling[0])
-            if self._decoding:
-                self._decode_tick()
+                req = self._prefilling[0]
+                last = i == self._chunks_per_tick - 1 or sum(
+                    len(r.chunks) - r.next_chunk for r in self._prefilling) == 1
+                if last and self._rides(req):
+                    ride = self._chunk_ready(req)
+                    break
+                self._prefill_one(req)
+            if ride is not None or self._decoding:
+                self._decode_tick(ride)
             with self._phase("serving.end_tick"):
                 self._end_tick(snap)
 
@@ -1340,63 +1436,106 @@ class ServingEngine:
             self._grant(self._queue.popleft(), self._free.pop())
 
     def _prefill_one(self, req: _Request) -> None:
-        """Advance ``req`` by one prompt chunk: host bookkeeping here, device
-        work in :meth:`_prefill_dispatch` (the hook the disagg router
-        overrides to run the chunk on the prefill mesh and stream its KV
-        page across)."""
-        size, valid = req.chunks[req.next_chunk]
-        is_first = req.next_chunk == 0
-        is_final = req.next_chunk == len(req.chunks) - 1
-        tr = self.tracing
-        with self._phase("serving.prefill", None if tr is None else {
-                "request_id": req.id, "size": size, "final": is_final}):
-            chunk = np.zeros((1, size), np.int32)
-            chunk[0, :valid] = req.tokens[req.consumed:req.consumed + valid]
-            t0 = time.perf_counter() if tr is not None or is_first else None
-            if is_first:
-                req.first_dispatch_t = t0
+        """Advance ``req`` by one prompt chunk in a program of its own: host
+        bookkeeping here, device work in :meth:`_prefill_dispatch` (the hook
+        the disagg router overrides to run the chunk on the prefill mesh and
+        stream its KV page across)."""
+        with self._prefill_phase(req):
             try:
-                if self.chaos is not None:
-                    fault = self.chaos.draw("prefill_dispatch",
-                                            self._stats["ticks"], unit=req.id)
-                    if fault is not None:
-                        raise InjectedFaultError(fault)
-                tok, done0 = self._prefill_dispatch(req, chunk, valid,
-                                                    is_first, is_final)
+                ch = self._next_chunk(req)
+                tok, done0 = self._prefill_dispatch(req, ch.ids, ch.valid,
+                                                    ch.is_first, ch.is_final)
             except RuntimeError as e:
                 # InjectedFaultError or a real XLA runtime failure — recovery
                 # is identical. Programming errors (TypeError etc.) still
                 # propagate.
                 self._on_prefill_failure(req, e)
                 return
-            req.next_chunk += 1
-            req.consumed += valid
-            self._stats["prefill_chunks"] += 1
-            self._stats["prefill_pad_tokens"] += size - valid
-            self._watch_prefill_recompiles()
-            if tr is not None:
-                tr.prefill_chunk(req.id, self._stats["ticks"], t0,
-                                 time.perf_counter(), size=size, valid=valid,
-                                 lane=req.lane, slot=req.slot,
-                                 index=req.next_chunk - 1, final=is_final)
-            if is_final:
-                self._prefilling.remove(req)
+            self._chunk_sent(ch)
+            if ch.is_final:
                 # The TTFT moment is noted as it always was, when the final
                 # chunk has been dispatched and before its token is fetched.
                 req.first_token_t = time.perf_counter()
                 with self._phase("serving.first_token_fetch"):
                     first = int(tok)
-                self._emit(req, (first,), req.first_token_t)
-                # noted here and not at the finish, so that a request still
-                # decoding when a window closes counts
-                self._ttft_terms.append(_ttft_terms(req))
-                if tr is not None:
-                    tr.first_token(req.id, self._stats["ticks"],
-                                   req.first_token_t)
-                if bool(done0):
-                    self._retire(req)
-                else:
-                    self._decoding[req.slot] = req
+                self._first_token(req, first, bool(done0))
+
+    def _prefill_phase(self, req: _Request) -> _Phase:
+        """``serving.prefill`` for ``req``'s next chunk: the span says whose."""
+        size, _ = req.chunks[req.next_chunk]
+        return self._phase("serving.prefill", None if self.tracing is None else {
+            "request_id": req.id, "size": size,
+            "final": req.next_chunk == len(req.chunks) - 1})
+
+    def _next_chunk(self, req: _Request) -> _Chunk:
+        """The host half of ``req``'s next chunk: its tokens, padded to the
+        rung, and the chaos draw at ``prefill_dispatch`` (an
+        :class:`InjectedFaultError` fails the chunk before any dispatch)."""
+        size, valid = req.chunks[req.next_chunk]
+        is_first = req.next_chunk == 0
+        ids = np.zeros((1, size), np.int32)
+        ids[0, :valid] = req.tokens[req.consumed:req.consumed + valid]
+        t0 = time.perf_counter() if self.tracing is not None or is_first else None
+        if is_first:
+            req.first_dispatch_t = t0
+        if self.chaos is not None:
+            fault = self.chaos.draw("prefill_dispatch",
+                                    self._stats["ticks"], unit=req.id)
+            if fault is not None:
+                raise InjectedFaultError(fault)
+        return _Chunk(req, ids, valid, is_first,
+                      req.next_chunk == len(req.chunks) - 1, t0)
+
+    def _chunk_sent(self, ch: _Chunk) -> None:
+        """A chunk has been dispatched: the request moves past it."""
+        req, size = ch.req, ch.ids.shape[1]
+        req.next_chunk += 1
+        req.consumed += ch.valid
+        self._stats["prefill_chunks"] += 1
+        self._stats["prefill_pad_tokens"] += size - ch.valid
+        self._watch_prefill_recompiles()
+        if self.tracing is not None:
+            self.tracing.prefill_chunk(
+                req.id, self._stats["ticks"], ch.t0, time.perf_counter(),
+                size=size, valid=ch.valid, lane=req.lane, slot=req.slot,
+                index=req.next_chunk - 1, final=ch.is_final)
+
+    def _first_token(self, req: _Request, first: int, done0: bool) -> None:
+        """A final chunk's token has been fetched: the request leaves the
+        prefill queue, its first token is out, and it decodes or is done."""
+        self._prefilling.remove(req)
+        self._emit(req, (first,), req.first_token_t)
+        # noted here and not at the finish, so that a request still
+        # decoding when a window closes counts
+        self._ttft_terms.append(_ttft_terms(req))
+        if self.tracing is not None:
+            self.tracing.first_token(req.id, self._stats["ticks"],
+                                     req.first_token_t)
+        if done0:
+            self._retire(req)
+        else:
+            self._decoding[req.slot] = req
+
+    def _rides(self, req: _Request) -> bool:
+        """Whether ``req``'s next chunk can ride this tick's decode step: the
+        engine has the fused program, and the step is one dispatch under
+        the request's own weights (not a canary's or a swap's mixed-version
+        tick)."""
+        if self._decode_chunk is None:
+            return False
+        groups = self._decode_groups()
+        return len(groups) == 1 and groups[0][0] == req.weights_version
+
+    def _chunk_ready(self, req: _Request) -> Optional[_Chunk]:
+        """The host half of a chunk that rides the decode step, in
+        ``serving.prefill``; None where it failed there (a chaos fault),
+        and the decode step then runs alone."""
+        with self._prefill_phase(req):
+            try:
+                return self._next_chunk(req)
+            except RuntimeError as e:
+                self._on_prefill_failure(req, e)
+                return None
 
     def _emit(self, req: _Request, toks, t: float) -> None:
         """``toks`` of one fetch, stamped ``t``, join the request's output
@@ -1413,11 +1552,17 @@ class ServingEngine:
         if self._journal is not None and not self._journal_suppressed(req.id):
             self._journal_tokens.setdefault(req.id, []).extend(new)
 
+    def _chunk_executables(self) -> Optional[int]:
+        """Executables of the programs that carry a prompt chunk: a rung
+        each of ``prefill`` and of ``decode_chunk`` where it runs."""
+        sizes = [_cache_size(p) for p in (self._prefill, self._decode_chunk) if p is not None]
+        return None if None in sizes else sum(sizes)
+
     def _watch_prefill_recompiles(self) -> None:
         """After ``warmup()`` every rung of the ladder has its program; one
         more is a compile in steady state."""
         warm = self._prefill_executables_warm
-        size = _cache_size(self._prefill) if warm is not None else None
+        size = self._chunk_executables() if warm is not None else None
         if size is not None and size > warm:
             self._stats["prefill_steady_recompiles"] += size - warm
             self._prefill_executables_warm = size
@@ -1460,7 +1605,10 @@ class ServingEngine:
             groups.append((v, mask))
         return groups
 
-    def _decode_tick(self) -> None:
+    def _decode_tick(self, ride: Optional[_Chunk] = None) -> None:
+        """One decode step for every live slot; with ``ride`` (a chunk that
+        :meth:`_rides` admitted) the step is the fused program, and the one
+        fetch brings the chunk's first token beside the decode tokens."""
         flip_slot = None
         if self.chaos is not None and self._decoding:
             fault = self.chaos.draw("decode_tick", self._stats["ticks"])
@@ -1481,14 +1629,18 @@ class ServingEngine:
                     # but verification keeps the OUTPUT bit-equal — the
                     # property the chaos smoke asserts.
                     self._spoil_history(min(self._decoding))
+        # a tick whose chunk rides with no slot decoding is a prefill: it
+        # counts as no decode step
         live = len(self._decoding)
-        self._stats["occupancy_sum"] += live
-        self._stats["peak_occupancy"] = max(self._stats["peak_occupancy"], live)
+        if live:
+            self._stats["occupancy_sum"] += live
+            self._stats["peak_occupancy"] = max(self._stats["peak_occupancy"], live)
         tr = self.tracing
         k_spec = self._speculate_k
         # rows of a block where the step's attention reads by each slot's
         # bound, None where it reads every row of every slot
         read_block = decode_reads(self._cache) if self._bounded and k_spec == 0 else None
+        first = ()  # a riding chunk's (tok, done0), fetched with the step's tokens
         for version, mask in self._decode_groups():
             with self._phase("serving.decode_dispatch"):
                 t0 = time.perf_counter() if (tr is not None
@@ -1496,13 +1648,28 @@ class ServingEngine:
                 if tr is not None:
                     group_rids = [r.id for s, r in self._decoding.items()
                                   if r.weights_version == version and mask[s]]
-                self._cache, self._state, toks, emitted, bad = self._decode(
-                    self._params_for(version), self._cache, self._state, mask
-                )
-                self._stats["decode_steps"] += 1
-                if read_block is None:
-                    self._stats["read_rows_sum"] += self.n_slots * self.t_max
-                if self.telemetry is not None:
+                if ride is None:
+                    self._cache, self._state, toks, emitted, bad = self._decode(
+                        self._params_for(version), self._cache, self._state, mask
+                    )
+                else:
+                    req = ride.req
+                    (self._cache, self._state, toks, emitted, bad,
+                     *first) = self._decode_chunk(
+                        self._params_for(version), self._cache, self._state, mask,
+                        ride.ids, np.int32(req.slot), np.int32(ride.valid),
+                        np.int32(req.budget), req.rng, ride.is_first, ride.is_final,
+                    )
+                    self._stats["prefill_chunks_fused"] += 1
+                    self._chunk_sent(ride)
+                    if ride.is_final:
+                        # the TTFT moment: the final chunk's dispatch, as ever
+                        req.first_token_t = time.perf_counter()
+                if live:
+                    self._stats["decode_steps"] += 1
+                    if read_block is None:
+                        self._stats["read_rows_sum"] += self.n_slots * self.t_max
+                if self.telemetry is not None and ride is None:
                     # PR-1 recompile-watchdog cross-check: sample the decode
                     # step's executable cache exactly like a train step's —
                     # any mid-flight growth lands as a "recompile" event in
@@ -1517,8 +1684,8 @@ class ServingEngine:
             # extra stall). Under a mixed-version tick this runs once per
             # group, reading only the rows that group's mask advanced.
             with self._phase("serving.decode_fetch"):
-                toks_np, emitted_np, done_np, bad_np = jax.device_get(
-                    (toks, emitted, self._state.done, bad))
+                toks_np, emitted_np, done_np, bad_np, *first = jax.device_get(
+                    (toks, emitted, self._state.done, bad, *first))
             with self._phase("serving.bookkeeping"):
                 t_fetch = time.perf_counter()  # this fetch's tokens' stamp
                 if flip_slot is not None and mask[flip_slot]:
@@ -1555,7 +1722,7 @@ class ServingEngine:
                     # Per-tick verify-time attribution: the whole speculative
                     # dispatch IS the k+1-position verification forward.
                     self._stats["spec_verify_s"] += time.perf_counter() - t0
-                if tr is not None:
+                if tr is not None and live:
                     tr.decode_tick(self._stats["ticks"], t0,
                                    time.perf_counter(),
                                    weights_version=version, occupancy=live,
@@ -1563,7 +1730,10 @@ class ServingEngine:
                                    request_ids=group_rids,
                                    drafted=group_drafted,
                                    accepted=group_accepted)
-                self._watch_decode_recompiles()
+                if ride is None:
+                    self._watch_decode_recompiles()
+                elif ride.is_final:
+                    self._first_token(ride.req, int(first[0]), bool(first[1]))
 
     def _watch_decode_recompiles(self) -> None:
         size = _cache_size(self._decode)
@@ -2381,11 +2551,21 @@ class ServingEngine:
         # The synthetic request must not reach the WAL: a journaled warmup
         # row would replay as a phantom request at the next recover().
         jr, self._journal = self._journal, None
+        fused, per_tick = self._decode_chunk, self._chunks_per_tick
         try:
+            # One chunk a tick, so that every rung goes through the program
+            # a tick runs it in: the fused one where the engine fuses. Where
+            # a tick carries more than one chunk, all but its last run
+            # alone: their rungs are walked once more, unfused.
+            self._chunks_per_tick = 1
             self.run([prompt], max_new_tokens=2)
+            if fused is not None and per_tick > 1:
+                self._decode_chunk = None
+                self.run([prompt], max_new_tokens=2)
         finally:
             self._journal = jr
-        self._prefill_executables_warm = _cache_size(self._prefill)
+            self._decode_chunk, self._chunks_per_tick = fused, per_tick
+        self._prefill_executables_warm = self._chunk_executables()
         self.reset_metrics()
 
     def reset_metrics(self) -> None:
@@ -2426,12 +2606,15 @@ class ServingEngine:
     # -- reporting ---------------------------------------------------------
 
     def executable_counts(self) -> dict:
-        """Dispatch-cache sizes of the two jitted programs — the numbers the
+        """Dispatch-cache sizes of the jitted programs — the numbers the
         zero-recompile acceptance bar constrains (decode: exactly 1;
-        prefill: <= len(ladder))."""
+        prefill and decode_chunk, the decode step a chunk rides: each <=
+        len(ladder); decode_chunk None where the engine does not fuse)."""
         return {
             "decode": _cache_size(self._decode),
             "prefill": _cache_size(self._prefill),
+            "decode_chunk": (_cache_size(self._decode_chunk)
+                             if self._decode_chunk is not None else None),
         }
 
     def stats(self) -> dict:
@@ -2474,6 +2657,8 @@ class ServingEngine:
             "ticks": s["ticks"],
             "decode_steps": s["decode_steps"],
             "prefill_chunks": s["prefill_chunks"],
+            # of them, those that rode a decode step (one program a tick)
+            "prefill_chunks_fused": s["prefill_chunks_fused"],
             "prefill_pad_tokens": s["prefill_pad_tokens"],
             "prefill_ladder": list(self.ladder),
             "n_slots": self.n_slots,
@@ -2511,6 +2696,7 @@ class ServingEngine:
             "prefill_steady_recompiles": s["prefill_steady_recompiles"],
             "decode_executables": execs["decode"],
             "prefill_executables": execs["prefill"],
+            "decode_chunk_executables": execs["decode_chunk"],
             "weights_version": self._weights_version,
             "canary": self.canary_status(),
             "sdc": self.sdc_stats(),

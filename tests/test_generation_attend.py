@@ -119,10 +119,52 @@ def cell_programs():
                 for program, text in zip(compiled[name][1], texts)}
         return compiled[name]
 
+    fused = {}
+
+    def get_fused(name):
+        if name not in fused:
+            fused[name] = _decode_chunk_program(get(name)[0], topo.devices[0])
+        return fused[name]
+
     get.lowered = {}
+    get.fused = get_fused
     yield get
     jax.config.update("jax_enable_compilation_cache", before)
     compilation_cache.reset_cache()
+
+
+def _decode_chunk_program(cell, device):
+    """The cell's decode step with its largest prefill rung riding it
+    (``serving._build_decode_chunk_step``), compiled for ``device`` from shapes
+    alone, as ``chipbench.aot.serving_programs`` compiles the other two."""
+    from jax.sharding import SingleDeviceSharding
+
+    from accelerate_tpu import serving
+    from accelerate_tpu.generation import GENERATION_PLANS, init_slot_cache
+    from chipbench import weights
+
+    place = SingleDeviceSharding(device)
+
+    def shape(s, dtype):
+        return jax.ShapeDtypeStruct(tuple(s), dtype, sharding=place)
+
+    def abstract(fn):
+        return jax.tree.map(lambda x: shape(x.shape, x.dtype), jax.eval_shape(fn))
+
+    eng = cell.workload["engine"]
+    n_slots, max_len = int(eng["n_slots"]), int(eng["max_len"])
+    module = cell.family.program_module(cell.config, max_len)
+    params = weights.nest({k: shape(s, jnp.bfloat16)
+                           for k, (s, _) in cell.family.weight_specs(cell.config).items()})
+    step = serving._build_decode_chunk_step(GENERATION_PLANS[type(module).__name__],
+                                            module.config, 0.0, None, None, None)
+    scalar, flag = shape((), jnp.int32), shape((), jnp.bool_)
+    return step.lower(
+        params, abstract(lambda: init_slot_cache(module.config, n_slots, max_len, jnp.bfloat16)),
+        abstract(lambda: serving.init_slot_state(n_slots, seed=0, history=16)),
+        shape((n_slots,), jnp.bool_), shape((1, max(serving.default_prefill_ladder(max_len))),
+                                            jnp.int32),
+        scalar, scalar, scalar, abstract(lambda: jax.random.key(0)), flag, flag).compile()
 
 
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%(\S+) = \w+\[([0-9,]*)\]\S* ([\w\-]+)\(")
@@ -311,3 +353,66 @@ def test_the_looped_cell_s_decode_program(cell_programs, case):
                 if n == eng["n_slots"] * cfg["intermediate_size"] and op == "fusion"]
         assert 1 <= len(wide) <= 2, wide
         assert "ut_pass" in text and text.count("ut_pass") >= 1
+
+
+# -- a prompt chunk that rides the decode step: one program, the weights read once ----
+
+
+@pytest.mark.parametrize("case", ["holds_the_kernel_once", "no_slot_lifted_out_or_put_back",
+                                  "no_logits_over_the_chunk", "temporaries_under_prefill"])
+@pytest.mark.parametrize("cell_name", ["mistral_serve_steady", "mixtral_serve_decode",
+                                       "ouro_serve_reason"])
+def test_the_decode_step_a_chunk_rides(cell_programs, cell_name, case):
+    """At each cell's shapes and its largest rung (256 rows a chunk)."""
+    from accelerate_tpu.serving import default_prefill_ladder
+    from chipbench import aot
+
+    cell, programs = cell_programs(cell_name)
+    fused = cell_programs.fused(cell_name)
+    text = fused.as_text()
+    cfg, eng = cell.config, cell.workload["engine"]
+    b, t = eng["n_slots"], eng["max_len"]
+    hkv, d, vocab = cfg["num_key_value_heads"], cfg["head_dim"], cfg["vocab_size"]
+    planes = cfg.get("total_ut_steps", 1) * cfg["num_hidden_layers"]
+    c = max(default_prefill_ladder(t))
+    if case == "holds_the_kernel_once":
+        # the decode rows read through the kernel over both whole stacks
+        kernels = [line for line in text.splitlines()
+                   if "custom-call(" in line and "tpu_custom_call" in line]
+        assert len(kernels) == 1 and "%decode_attention" in kernels[0]
+        assert kernels[0].count(f"bf16[{planes},{b},{t},{hkv},{d}]") >= 2
+    elif case == "no_slot_lifted_out_or_put_back":
+        # prefill's take_slot (one slot's every plane, as its text shows) and put_slot
+        # (the stack rebuilt around it); a chunk reads its slot's plane of one layer
+        # where it lies
+        slot = f"[{planes},1,{t},{hkv},{d}]"
+        assert slot in programs["prefill"].as_text() and slot not in text
+        moved = [name for n, op, name in _arrays(text, scheduled_only=True)
+                 if n == planes * b * t * hkv * d
+                 and (op == "copy" or "dynamic-update-slice" in name)]
+        assert not moved
+    elif case == "no_logits_over_the_chunk":
+        # the head on the decode rows and the chunk's last prompt row alone
+        assert f"f32[{b + 1},{vocab}]" in text
+        assert not [s for s in (f"[{c},{vocab}]", f"[1,{c},{vocab}]", f"[{b + c},{vocab}]",
+                                f"[1,{b + c},{vocab}]") if s in text]
+    else:
+        limit = aot.memory_of(programs["prefill"])["temporaries"]
+        if "num_local_experts" in cfg:
+            # the gate and up products, (tokens, E, F) each: at 256 tokens the chip's
+            # compiler keeps both on chip for prefill; at 32 + 256 one of them goes to
+            # HBM (PERF.md, section 6)
+            limit += (b + c) * cfg["num_local_experts"] * cfg["intermediate_size"] * 2
+        assert aot.memory_of(fused)["temporaries"] < limit
+
+
+def test_the_decode_step_a_chunk_rides_reads_the_experts_where_they_lie(cell_programs):
+    cell, _ = cell_programs("mixtral_serve_decode")
+    cfg = cell.config
+    experts = cfg["num_local_experts"] * cfg["hidden_size"] * cfg["intermediate_size"]
+    moving = ("copy", "gather", "broadcast", "dynamic-slice", "dynamic-update-slice")
+    text = cell_programs.fused("mixtral_serve_decode").as_text()
+    assert "moe" in text
+    moved = [name for n, op, name in _arrays(text, scheduled_only=True)
+             if n == experts and any(word in op or word in name for word in moving)]
+    assert not moved

@@ -322,9 +322,11 @@ def test_compaction_commit_crash_duplicates_replay_exactly_once(
     exactly-once, bit-equal."""
     cfg, model = llama
     wal = str(tmp_path / "wal")
+    # segments of 3 records: where they fall depends on the ticks the
+    # schedule takes, and at 3 a compaction lands while req-3's admit is live
     mk = lambda: ServingConfig(  # noqa: E731
         n_slots=2, max_len=32, prefill_chunks=[4, 8],
-        journal_dir=wal, journal_segment_records=4)
+        journal_dir=wal, journal_segment_records=3)
     prompts = _prompts(cfg, [5, 7, 6, 8])
 
     real_remove = os.remove

@@ -173,6 +173,7 @@ def test_single_decode_executable_steady_state(llama):
     stats = engine.stats()
     assert stats["decode_executables"] == 1
     assert stats["prefill_executables"] <= 2
+    assert stats["decode_chunk_executables"] <= 2
     assert stats["steady_recompiles"] == 0
 
 
@@ -190,9 +191,9 @@ def test_prefill_executables_flat_with_mesh_placed_params(llama):
     engine = ServingEngine(
         placed, ServingConfig(n_slots=2, max_len=64, prefill_chunks=[4, 8])
     )
-    engine.warmup()  # walks rung 8 first, then rung 4
+    engine.warmup()  # walks rung 8 first, then rung 4, each riding a decode step
     engine.run(_prompts(cfg, [8, 12]), max_new_tokens=3)  # rung 8 again
-    assert engine.stats()["prefill_executables"] == 2
+    assert engine.stats()["decode_chunk_executables"] == 2
 
 
 def test_occupancy_and_token_accounting(llama):

@@ -20,6 +20,7 @@ from accelerate_tpu import (
     ServingEngine,
     TraceRecorder,
 )
+from accelerate_tpu.generation import _llama_forward_cached
 from accelerate_tpu.serving import TICK, TICK_PHASES, TTFT_TERMS
 from accelerate_tpu.utils import set_seed
 
@@ -40,6 +41,13 @@ def _prompts(cfg, lengths, seed=3):
     rng = np.random.default_rng(seed)
     return [rng.integers(1, cfg.vocab_size, (n,), dtype=np.int32)
             for n in lengths]
+
+
+def _two_programs(cfg, params, ids, cache, return_all=False, attn_bound=None):
+    """The Llama plan without ``chunk``: an engine given it runs a prompt chunk
+    in a program of its own, and fetches a final chunk's token alone."""
+    return _llama_forward_cached(cfg, params, ids, cache, return_all=return_all,
+                                 attn_bound=attn_bound)
 
 
 def _engine(model, cls=ServingEngine, **kw):
@@ -70,8 +78,9 @@ def _assert_phases_add_up(block, ticks):
 
 @pytest.mark.parametrize("cls,kw", [
     (ServingEngine, {}),
+    (ServingEngine, {"forward_cached": _two_programs}),
     (DisaggServingEngine, {"disagg": DisaggConfig(n_prefill_lanes=2)}),
-], ids=["colocated", "disagg"])
+], ids=["colocated", "colocated_two_programs", "disagg"])
 def test_the_phases_of_a_tick_add_up_to_its_wall_time(llama, cls, kw):
     cfg, model = llama
     engine = _engine(model, cls, **kw)
@@ -81,8 +90,13 @@ def test_the_phases_of_a_tick_add_up_to_its_wall_time(llama, cls, kw):
     assert all(r["status"] == "ok" for r in rows.values())
     stats = engine.stats()
     _assert_phases_add_up(stats["tick_phases"], stats["ticks"])
-    # a run with prefill and decode spends time in every phase
-    assert all(v > 0 for v in stats["tick_phases"]["phases_s"].values())
+    # a run with prefill and decode spends time in every phase; where the
+    # chunks ride the decode step, their first tokens come in its one fetch
+    phases = stats["tick_phases"]["phases_s"]
+    fused = stats["prefill_chunks_fused"]
+    assert fused == (stats["prefill_chunks"] if cls is ServingEngine and not kw else 0)
+    assert (phases.pop("serving.first_token_fetch") == 0) == bool(fused)
+    assert all(v > 0 for v in phases.values())
 
 
 def test_ttft_terms_add_up_per_row_and_have_their_tails(llama):
@@ -201,7 +215,8 @@ def test_with_a_recorder_every_phase_span_hangs_under_its_tick(llama):
     ticks = [s for s in spans.values() if s.name == TICK]
     phases = [s for s in spans.values() if s.kind == "tick_phase" and s.name != TICK]
     assert len(ticks) == engine.stats()["ticks"] and all(t.parent is None for t in ticks)
-    assert {s.name for s in phases} == set(TICK_PHASES)
+    # every chunk rode a decode step: no final chunk's token was fetched alone
+    assert {s.name for s in phases} == set(TICK_PHASES) - {"serving.first_token_fetch"}
     for s in phases:
         tick = spans[s.parent]
         assert tick.name == TICK
@@ -258,7 +273,7 @@ def test_the_tick_domain_trace_with_phase_spans_still_replays_bit_identically(ll
     b, _, rows_b = _traced_run(llama)
     ja = json.dumps(a.tick_trace(), sort_keys=True)
     assert ja == json.dumps(b.tick_trace(), sort_keys=True)
-    assert '"serving.first_token_fetch"' in ja
+    assert '"serving.decode_fetch"' in ja
     # and greedy output does not depend on who is watching
     cfg, model = llama
     plain = _engine(model).run(_prompts(cfg, [6, 19, 9], seed=5), max_new_tokens=3)
@@ -274,14 +289,16 @@ def test_a_prefill_rung_the_warm_up_never_saw_counts_once(llama, caplog):
     engine = _engine(model)
     engine.warmup()
     stats = engine.stats()
-    assert stats["prefill_steady_recompiles"] == 0 and stats["prefill_executables"] == 2
+    # the warm-up's chunks rode the decode step: that program holds the rungs
+    assert stats["prefill_steady_recompiles"] == 0 and stats["decode_chunk_executables"] == 2
+    assert stats["prefill_executables"] == 0
     engine.run(_prompts(cfg, [5, 19]), max_new_tokens=2)
     assert engine.stats()["prefill_steady_recompiles"] == 0
     engine.ladder = [4, 8, 16]   # a rung that warmup() never built
     with caplog.at_level("WARNING"):
         engine.run(_prompts(cfg, [33, 33]), max_new_tokens=2)
     stats = engine.stats()
-    assert stats["prefill_steady_recompiles"] == 1 and stats["prefill_executables"] == 3
+    assert stats["prefill_steady_recompiles"] == 1 and stats["decode_chunk_executables"] == 3
     assert stats["steady_recompiles"] == 0    # decode's watch keeps its meaning
     assert sum("prefill compiled mid-flight" in r.getMessage() for r in caplog.records) == 1
     # an engine that was never warmed compiles on demand, and nothing is counted
