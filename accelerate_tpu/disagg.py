@@ -299,7 +299,7 @@ class DisaggServingEngine(ServingEngine):
             for _ in range(4):
                 # No live rows: lengths pass through unchanged, k/v garbage
                 # lands where inserts overwrite or attention never reaches.
-                self._cache, self._state, _, _, _ = self._decode(
+                self._cache, self._state, *_ = self._decode(
                     self._params, self._cache, self._state, self._full_mask)
 
         if _log_ok():
@@ -955,8 +955,7 @@ class DisaggServingEngine(ServingEngine):
                                   np.int32(1), carry, hist)
                 state = _release_step(state, np.int32(0))
         for _ in range(4 if mesh is not None else 1):
-            cache, state, _, _, _ = self._decode(params, cache, state,
-                                                 self._full_mask)
+            cache, state, *_ = self._decode(params, cache, state, self._full_mask)
         return cache, state
 
     def _drain_decode_tick(self) -> None:
@@ -976,12 +975,11 @@ class DisaggServingEngine(ServingEngine):
                     if r.weights_version == v:
                         mask[slot] = True
                 with self._phase("serving.decode_dispatch"):
-                    L.cache, L.state, toks, emitted, bad = self._decode(
+                    L.cache, L.state, *out = self._decode(
                         L.params_by_version[v], L.cache, L.state, mask)
                     self._stats["decode_steps"] += 1
                 with self._phase("serving.decode_fetch"):
-                    toks_np, emitted_np, done_np, bad_np = jax.device_get(
-                        (toks, emitted, L.state.done, bad))
+                    toks_np, emitted_np, done_np, bad_np = jax.device_get(out)
                 with self._phase("serving.bookkeeping"):
                     t_fetch = time.perf_counter()
                     for slot, req in list(L.decoding.items()):

@@ -22,9 +22,9 @@ vLLM/TGI-class fix, TPU-shaped:
   executables. The tick's chunk rides its decode step, one program
   (:func:`_build_decode_chunk_step`), so the weights are read once a tick.
 - **Zero-recompile decode** — the steady-state decode step is ONE jitted
-  ``(params, cache, slot_state) -> (cache, slot_state, tokens, bad)``
-  program with donated cache buffers (``bad`` is the nonfinite-logits
-  sentinel below); its executable count is watched every tick
+  ``(params, cache, slot_state) -> (cache, slot_state, tokens, emitted,
+  done, bad)`` program with donated cache buffers (``bad`` is the
+  nonfinite-logits sentinel below); its executable count is watched every tick
   (``stats()["steady_recompiles"]``, cross-checked by the telemetry
   recompile watchdog when a recorder is attached).
 
@@ -286,7 +286,7 @@ def _advance_live_rows(cache, new_cache, state, logits, live, *, temperature,
     """A decode step of one token a slot after its forward (``logits``
     (N, V), ``new_cache`` the forward's): sample every live row, advance
     its length, count and stream, and flag what is done or nonfinite.
-    Returns the decode program's 5-tuple. Shared by ``decode`` (k = 0) and
+    Returns the decode program's 6-tuple. Shared by ``decode`` (k = 0) and
     ``decode_chunk``."""
     # fwd advanced every row's write offset; only live rows really did.
     lengths = jnp.where(live, new_cache.length, cache.length)
@@ -324,7 +324,7 @@ def _advance_live_rows(cache, new_cache, state, logits, live, *, temperature,
         history=state.history,
     )
     return (new_cache._replace(length=lengths), new_state,
-            tok[:, None], live.astype(jnp.int32), bad)
+            tok[:, None], live.astype(jnp.int32), new_state.done, bad)
 
 
 def _arm_chunk_slot(cache, state, last_logits, chunk, slot, valid, budget, rng,
@@ -393,10 +393,13 @@ def _build_decode_step(fwd, cfg, temperature, top_k, top_p, eos_token_id,
     scatters each slot's new rows into it in place (``kv_cache.cache_step``),
     so a step copies no cache and holds no second one beside it.
 
-    Both modes return the same 5-tuple
-    ``(cache, state, toks (N, k+1) int32, emitted (N,) int32, bad (N,))`` —
-    ``toks[slot, :emitted[slot]]`` are the tokens the slot really produced
-    this tick (k=0 returns ``(N, 1)`` with emitted == live).
+    Both modes return the same 6-tuple
+    ``(cache, state, toks (N, k+1) int32, emitted (N,) int32, done (N,),
+    bad (N,))`` — ``toks[slot, :emitted[slot]]`` are the tokens the slot
+    really produced this tick (k=0 returns ``(N, 1)`` with emitted == live),
+    and ``done`` is the state's flag after it, an output of its own: the
+    state is donated to the next step, which the engine dispatches before it
+    fetches this one's outputs.
 
     Speculation: an n-gram self-draft proposes ``k`` tokens per slot from
     the slot's token history; the target model scores all ``k+1`` window
@@ -523,7 +526,7 @@ def _build_decode_step(fwd, cfg, temperature, top_k, top_p, eos_token_id,
             history=hist,
         )
         return (new_cache._replace(length=lengths), new_state,
-                out, e, bad)
+                out, e, new_state.done, bad)
 
     return jax.jit(decode, donate_argnums=(1, 2))
 
@@ -559,7 +562,7 @@ def _build_decode_chunk_step(fwd, cfg, temperature, top_k, top_p, eos_token_id):
     nothing (it is not live: its request is still prefilling) and the chunk
     is then committed as :func:`_build_prefill_step` commits it; a request
     it arms decodes from the next tick. Each ladder rung is one executable.
-    Returns the decode program's 5-tuple and the chunk's ``(tok, done0)``:
+    Returns the decode program's 6-tuple and the chunk's ``(tok, done0)``:
     one fetch brings all of them."""
     sampling = dict(temperature=temperature, top_k=top_k, top_p=top_p,
                     eos_token_id=eos_token_id)
@@ -572,12 +575,12 @@ def _build_decode_chunk_step(fwd, cfg, temperature, top_k, top_p, eos_token_id):
         bound = {"attn_bound": jnp.where(live, cache.length + 1, 0)} if bounded else {}
         logits, new_cache = fwd(cfg, params, state.last_token[:, None], cache,
                                 chunk=PromptChunk(chunk, slot, start, valid), **bound)
-        cache, state, toks, emitted, bad = _advance_live_rows(
+        cache, state, toks, emitted, done, bad = _advance_live_rows(
             cache, new_cache, state, logits[:-1], live, **sampling)
         cache, state, tok, done0 = _arm_chunk_slot(
             cache, state, lambda: logits[-1], chunk, slot, valid, budget, rng,
             is_first, is_final, start, **sampling)
-        return cache, state, toks, emitted, bad, tok, done0
+        return cache, state, toks, emitted, done, bad, tok, done0
 
     return jax.jit(decode_chunk, donate_argnums=(1, 2))
 
@@ -643,9 +646,9 @@ TICK_PHASES = (
     "serving.admit",              # preemption latch, deadlines, admission, queue sample
     "serving.prefill",            # a prompt chunk: host build, and dispatch where it runs alone
     "serving.first_token_fetch",  # the blocking fetch of a lone final chunk's token
-    "serving.decode_dispatch",    # version groups and the decode call (a riding chunk's too)
-    "serving.decode_fetch",       # the fused device_get: host blocked on the device
-    "serving.bookkeeping",        # per-slot loop after the fetch, retire, compile watch
+    "serving.decode_dispatch",    # version groups, the decode call (a riding chunk's too), compile watch
+    "serving.decode_fetch",       # the last step's fused device_get: host blocked on the device
+    "serving.bookkeeping",        # per-slot loop over the settled step's record, retire
     "serving.end_tick",           # journal, chaos draw, hang guard, SDC canary
 )
 _DEVICE_WAIT_PHASES = ("serving.first_token_fetch", "serving.decode_fetch")
@@ -777,6 +780,21 @@ class _Chunk(NamedTuple):
     is_first: bool
     is_final: bool
     t0: Optional[float]  # perf_counter at its build, where a tracer or TTFT needs it
+
+
+class _Step(NamedTuple):
+    """A dispatched decode step, as the host knew it at dispatch: settled
+    from this record (:meth:`ServingEngine._settle_step`), not from the
+    engine's state when its outputs come back."""
+
+    rows: dict                 # slot -> the request the step advanced there
+    version: Any               # the weights version it ran under
+    ride: Optional[_Chunk]     # the prompt chunk that rode it, or None
+    flip_slot: Optional[int]   # a chaos bit flip to apply to its tokens
+    out: tuple                 # device outputs: toks, emitted, done, bad[, tok, done0]
+    tick: int
+    t0: Optional[float]        # perf_counter at dispatch, where timed
+    read_block: Optional[int]  # the decode kernel's block of rows, or None
 
 
 TTFT_TERMS = ("queue_wait_s", "prefill_blocked_s", "prefill_own_s")
@@ -999,6 +1017,8 @@ class ServingEngine:
         self._queue_depth_window: deque[int] = deque(maxlen=wn)
         self._stats = {
             "submitted": 0, "completed": 0, "ticks": 0, "decode_steps": 0,
+            # decode steps dispatched while the one before was unsettled
+            "steps_overlapped": 0,
             "prefill_chunks": 0, "prefill_chunks_fused": 0,
             "prefill_pad_tokens": 0, "tokens_out": 0,
             "prompt_tokens_in": 0,
@@ -1030,6 +1050,9 @@ class ServingEngine:
         self._spoil_op = None        # lazily jitted draft_mismatch program
         self._draining = False
         self._idle_ticks = 0
+        # The decode step dispatched and not yet settled (tick()'s one-deep
+        # pipeline), or None.
+        self._outstanding: Optional[_Step] = None
         # The phases open right now (the tick at the bottom), the last
         # boundary's clock read, and the running tick's seconds per phase,
         # folded into _stats when the tick closes (_Phase, _fold_tick).
@@ -1224,12 +1247,17 @@ class ServingEngine:
     def tick(self) -> None:
         """One scheduler round: sweep deadlines (and the preemption latch),
         admit into free slots, advance one prompt chunk (up to
-        ``prefill_chunks_per_tick``), then one decode step for every live
-        slot. The tick's last chunk rides the decode step, one program for
-        both, where :meth:`_rides` says it can; the others run alone before
-        it. Raises :class:`ServingStalledError` via the hang guard if
-        ``max_idle_ticks`` rounds pass with pending requests and zero
-        progress."""
+        ``prefill_chunks_per_tick``), dispatch one decode step for every
+        live slot, then settle the step the tick before dispatched: fetch
+        its tokens and book them. The device finds this tick's step queued
+        behind the last one, so it runs while the host fetches and books
+        (:meth:`_pipelined_step`); a step's tokens reach ``poll()`` one tick
+        after its dispatch. A mixed-version tick settles first and then runs
+        its groups in turn. The tick's last chunk rides the decode step, one
+        program for both, where :meth:`_rides` says it can; the others run
+        alone before it. Raises :class:`ServingStalledError` via the hang
+        guard if ``max_idle_ticks`` rounds pass with pending requests and
+        zero progress."""
         with self._phase(TICK):
             with self._phase("serving.admit"):
                 snap = self._begin_tick()
@@ -1246,8 +1274,14 @@ class ServingEngine:
                     ride = self._chunk_ready(req)
                     break
                 self._prefill_one(req)
-            if ride is not None or self._decoding:
-                self._decode_tick(ride)
+            if len(self._decode_groups()) > 1:
+                # which slots each version's dispatch masks is read off the
+                # rows the last step left
+                self._settle()
+                if self._decoding:
+                    self._decode_tick()
+            else:
+                self._pipelined_step(ride)
             with self._phase("serving.end_tick"):
                 self._end_tick(snap)
 
@@ -1458,6 +1492,7 @@ class ServingEngine:
                 req.first_token_t = time.perf_counter()
                 with self._phase("serving.first_token_fetch"):
                     first = int(tok)
+                self._armed(req)
                 self._first_token(req, first, bool(done0))
 
     def _prefill_phase(self, req: _Request) -> _Phase:
@@ -1500,10 +1535,15 @@ class ServingEngine:
                 size=size, valid=ch.valid, lane=req.lane, slot=req.slot,
                 index=req.next_chunk - 1, final=ch.is_final)
 
-    def _first_token(self, req: _Request, first: int, done0: bool) -> None:
-        """A final chunk's token has been fetched: the request leaves the
-        prefill queue, its first token is out, and it decodes or is done."""
+    def _armed(self, req: _Request) -> None:
+        """A final chunk has been dispatched and armed ``req``'s slot: the
+        request leaves the prefill queue and the next step decodes it."""
         self._prefilling.remove(req)
+        self._decoding[req.slot] = req
+
+    def _first_token(self, req: _Request, first: int, done0: bool) -> None:
+        """An armed request's first token has been fetched: it is out, and
+        the request decodes on or is done."""
         self._emit(req, (first,), req.first_token_t)
         # noted here and not at the finish, so that a request still
         # decoding when a window closes counts
@@ -1512,9 +1552,8 @@ class ServingEngine:
             self.tracing.first_token(req.id, self._stats["ticks"],
                                      req.first_token_t)
         if done0:
+            del self._decoding[req.slot]
             self._retire(req)
-        else:
-            self._decoding[req.slot] = req
 
     def _rides(self, req: _Request) -> bool:
         """Whether ``req``'s next chunk can ride this tick's decode step: the
@@ -1605,135 +1644,195 @@ class ServingEngine:
             groups.append((v, mask))
         return groups
 
-    def _decode_tick(self, ride: Optional[_Chunk] = None) -> None:
-        """One decode step for every live slot; with ``ride`` (a chunk that
-        :meth:`_rides` admitted) the step is the fused program, and the one
-        fetch brings the chunk's first token beside the decode tokens."""
+    def _decode_rows(self) -> dict:
+        """``slot -> request`` of the decoding slots a step dispatched now
+        would advance: those owed a token past what the outstanding step
+        brings (a step emits at least one a live slot; a final chunk that
+        rode it, the first). A slot the outstanding step finishes by its
+        budget is left out; one it finishes by EOS the device masks."""
+        step = self._outstanding
+
+        def owed(slot, req):
+            n = len(req.out)
+            if step is not None and (step.rows.get(slot) is req or (
+                    step.ride is not None and step.ride.req is req)):
+                n += 1
+            return n < req.budget
+
+        return {s: r for s, r in self._decoding.items() if owed(s, r)}
+
+    def _decode_faults(self, rows: dict) -> Optional[int]:
+        """The tick's chaos draws at ``decode_tick`` (and ``draft_mismatch``
+        under speculation) over the slots it advances: a poison is written
+        into the cache before the dispatch; a bit flip's slot is returned,
+        to be applied to the fetched tokens."""
         flip_slot = None
-        if self.chaos is not None and self._decoding:
-            fault = self.chaos.draw("decode_tick", self._stats["ticks"])
+        if self.chaos is None or not rows:
+            return None
+        fault = self.chaos.draw("decode_tick", self._stats["ticks"])
+        if fault is not None and fault.kind == "poison":
+            self._poison_slot(min(rows))
+        elif fault is not None and fault.kind == "bit_flip":
+            # Silent decode corruption: the emitted token is XOR'd AFTER
+            # the host fetch — device state untouched, output finite and
+            # wrong. Only the decode canary (sdc.py) can see it.
+            flip_slot = int((fault.extra or {}).get("slot", min(rows)))
+        if self._speculate_k > 0:
+            fault = self.chaos.draw("draft_mismatch", self._stats["ticks"])
             if fault is not None and fault.kind == "poison":
-                self._poison_slot(min(self._decoding))
-            elif fault is not None and fault.kind == "bit_flip":
-                # Silent decode corruption: the emitted token is XOR'd AFTER
-                # the host fetch — device state untouched, output finite and
-                # wrong. Only the decode canary (sdc.py) can see it.
-                flip_slot = int((fault.extra or {}).get(
-                    "slot", min(self._decoding)))
-            if self._speculate_k > 0:
-                fault = self.chaos.draw("draft_mismatch",
-                                        self._stats["ticks"])
-                if fault is not None and fault.kind == "poison":
-                    # Spoil one slot's n-gram history: its drafts degenerate
-                    # (repeat-last-token fallback) so acceptance collapses,
-                    # but verification keeps the OUTPUT bit-equal — the
-                    # property the chaos smoke asserts.
-                    self._spoil_history(min(self._decoding))
-        # a tick whose chunk rides with no slot decoding is a prefill: it
-        # counts as no decode step
-        live = len(self._decoding)
-        if live:
-            self._stats["occupancy_sum"] += live
-            self._stats["peak_occupancy"] = max(self._stats["peak_occupancy"], live)
+                # Spoil one slot's n-gram history: its drafts degenerate
+                # (repeat-last-token fallback) so acceptance collapses,
+                # but verification keeps the OUTPUT bit-equal — the
+                # property the chaos smoke asserts.
+                self._spoil_history(min(rows))
+        return flip_slot
+
+    def _pipelined_step(self, ride: Optional[_Chunk]) -> None:
+        """A one-group tick: dispatch its step where it can do work (a
+        riding chunk, or a slot owed a token), queued on the device behind
+        the outstanding one, and only then settle that one. Every input of
+        the new step is an output of the last on the device, so the host
+        fetches and books while the device runs."""
+        before = self._outstanding
+        rows = self._decode_rows()
+        self._outstanding = None
+        if ride is not None or rows:
+            flip_slot = self._decode_faults(rows)
+            (version, mask), = self._decode_groups()
+            self._outstanding = self._dispatch(version, mask, rows, ride, flip_slot)
+            if before is not None and rows:
+                self._stats["steps_overlapped"] += 1
+        if before is not None:
+            self._settle_step(before)
+
+    def _settle(self) -> None:
+        """Settle the outstanding step, if there is one: wherever the host's
+        next choice depends on it (a mixed-version tick, a swap or canary,
+        recovery, the end of warm-up)."""
+        step, self._outstanding = self._outstanding, None
+        if step is not None:
+            self._settle_step(step)
+
+    def _decode_tick(self) -> None:
+        """One decode step for every live slot, each version group's
+        dispatched and settled in turn: the disaggregated router's tick, and
+        a mixed-version one (no chunk rides either)."""
+        rows = self._decode_rows()
+        flip_slot = self._decode_faults(rows)
+        for version, mask in self._decode_groups():
+            group = {s: r for s, r in rows.items()
+                     if r.weights_version == version and mask[s]}
+            flip = flip_slot if flip_slot is not None and mask[flip_slot] else None
+            if flip is not None:
+                flip_slot = None  # one flip per tick, not per version group
+            self._settle_step(self._dispatch(version, mask, group, None, flip))
+
+    def _dispatch(self, version, mask, rows: dict, ride: Optional[_Chunk],
+                  flip_slot: Optional[int]) -> _Step:
+        """The dispatch half of a decode step over ``rows`` (the fused
+        program where ``ride`` is given, and the one fetch then brings the
+        chunk's first token beside the decode tokens). Returns the step's
+        record: what :meth:`_settle_step` books its outputs against."""
         tr = self.tracing
         k_spec = self._speculate_k
-        # rows of a block where the step's attention reads by each slot's
-        # bound, None where it reads every row of every slot
-        read_block = decode_reads(self._cache) if self._bounded and k_spec == 0 else None
-        first = ()  # a riding chunk's (tok, done0), fetched with the step's tokens
-        for version, mask in self._decode_groups():
-            with self._phase("serving.decode_dispatch"):
-                t0 = time.perf_counter() if (tr is not None
-                                             or k_spec > 0) else None
-                if tr is not None:
-                    group_rids = [r.id for s, r in self._decoding.items()
-                                  if r.weights_version == version and mask[s]]
-                if ride is None:
-                    self._cache, self._state, toks, emitted, bad = self._decode(
-                        self._params_for(version), self._cache, self._state, mask
-                    )
-                else:
-                    req = ride.req
-                    (self._cache, self._state, toks, emitted, bad,
-                     *first) = self._decode_chunk(
-                        self._params_for(version), self._cache, self._state, mask,
-                        ride.ids, np.int32(req.slot), np.int32(ride.valid),
-                        np.int32(req.budget), req.rng, ride.is_first, ride.is_final,
-                    )
-                    self._stats["prefill_chunks_fused"] += 1
-                    self._chunk_sent(ride)
-                    if ride.is_final:
-                        # the TTFT moment: the final chunk's dispatch, as ever
-                        req.first_token_t = time.perf_counter()
-                if live:
-                    self._stats["decode_steps"] += 1
-                    if read_block is None:
-                        self._stats["read_rows_sum"] += self.n_slots * self.t_max
-                if self.telemetry is not None and ride is None:
+        with self._phase("serving.decode_dispatch"):
+            t0 = time.perf_counter() if (tr is not None or k_spec > 0) else None
+            # rows of a block where the step's attention reads by each
+            # slot's bound, None where it reads every row of every slot
+            read_block = decode_reads(self._cache) if self._bounded and k_spec == 0 else None
+            if ride is None:
+                self._cache, self._state, *out = self._decode(
+                    self._params_for(version), self._cache, self._state, mask)
+            else:
+                req = ride.req
+                self._cache, self._state, *out = self._decode_chunk(
+                    self._params_for(version), self._cache, self._state, mask,
+                    ride.ids, np.int32(req.slot), np.int32(ride.valid),
+                    np.int32(req.budget), req.rng, ride.is_first, ride.is_final,
+                )
+                self._stats["prefill_chunks_fused"] += 1
+                self._chunk_sent(ride)
+                if ride.is_final:
+                    # the TTFT moment: the final chunk's dispatch, as ever
+                    req.first_token_t = time.perf_counter()
+                    self._armed(req)
+            # a step whose chunk rides with no slot decoding is a prefill:
+            # it counts as no decode step
+            if rows:
+                self._stats["decode_steps"] += 1
+                self._stats["occupancy_sum"] += len(rows)
+                self._stats["peak_occupancy"] = max(self._stats["peak_occupancy"], len(rows))
+                if read_block is None:
+                    self._stats["read_rows_sum"] += self.n_slots * self.t_max
+            if ride is None:
+                if self.telemetry is not None:
                     # PR-1 recompile-watchdog cross-check: sample the decode
                     # step's executable cache exactly like a train step's —
                     # any mid-flight growth lands as a "recompile" event in
                     # the telemetry JSONL.
                     try:
-                        self.telemetry._watch_recompiles(self._decode, toks)
+                        self.telemetry._watch_recompiles(self._decode, out[0])
                     except Exception:
                         pass
-            # The per-tick host sync: fetch this round's tokens (a (N, k+1)
-            # block under speculation) + per-slot emitted counts + done
-            # flags + the nonfinite sentinel (one fused device_get — no
-            # extra stall). Under a mixed-version tick this runs once per
-            # group, reading only the rows that group's mask advanced.
-            with self._phase("serving.decode_fetch"):
-                toks_np, emitted_np, done_np, bad_np, *first = jax.device_get(
-                    (toks, emitted, self._state.done, bad, *first))
-            with self._phase("serving.bookkeeping"):
-                t_fetch = time.perf_counter()  # this fetch's tokens' stamp
-                if flip_slot is not None and mask[flip_slot]:
-                    toks_np = np.array(toks_np)
-                    toks_np[flip_slot, 0] ^= 1
-                    flip_slot = None  # one flip per tick, not per version group
-                group_drafted = group_accepted = 0
-                for slot, req in list(self._decoding.items()):
-                    if req.weights_version != version or not mask[slot]:
-                        continue
-                    if bool(bad_np[slot]):
-                        self._on_poisoned_slot(slot, req)
-                        continue
-                    cnt = int(emitted_np[slot])
-                    # rows this step attended over: the prompt and every token
-                    # written so far, the one it wrote among them
-                    live_rows = req.tokens.size + len(req.out)
-                    self._stats["live_rows_sum"] += live_rows
-                    if read_block is not None:
-                        self._stats["read_rows_sum"] += rows_read(live_rows, read_block)
-                    self._emit(req, toks_np[slot, :cnt], t_fetch)
-                    if k_spec > 0:
-                        req.spec_drafted += k_spec
-                        req.spec_accepted += max(cnt - 1, 0)
-                        group_drafted += k_spec
-                        group_accepted += max(cnt - 1, 0)
-                        self._stats["spec_decode_tokens"] += cnt
-                    if bool(done_np[slot]):
-                        del self._decoding[slot]
-                        self._retire(req)
+                self._watch_decode_recompiles()
+        return _Step(rows, version, ride, flip_slot, tuple(out),
+                     self._stats["ticks"], t0, read_block)
+
+    def _settle_step(self, step: _Step) -> None:
+        """The settle half: one fetch of the step's tokens, emitted counts,
+        done and nonfinite flags (and a riding chunk's ``(tok, done0)``),
+        then the books, kept against the record of what the step advanced.
+        A slot whose request has left it since (retired, quarantined,
+        expired, or granted anew) is skipped."""
+        tr = self.tracing
+        k_spec = self._speculate_k
+        with self._phase("serving.decode_fetch"):
+            toks_np, emitted_np, done_np, bad_np, *first = jax.device_get(step.out)
+        with self._phase("serving.bookkeeping"):
+            t_fetch = time.perf_counter()  # this fetch's tokens' stamp
+            if step.flip_slot is not None:
+                toks_np = np.array(toks_np)
+                toks_np[step.flip_slot, 0] ^= 1
+            group_drafted = group_accepted = 0
+            for slot, req in step.rows.items():
+                if self._decoding.get(slot) is not req:
+                    continue
+                if bool(bad_np[slot]):
+                    self._on_poisoned_slot(slot, req)
+                    continue
+                cnt = int(emitted_np[slot])
+                # rows this step attended over: the prompt and every token
+                # written so far, the one it wrote among them
+                live_rows = req.tokens.size + len(req.out)
+                self._stats["live_rows_sum"] += live_rows
+                if step.read_block is not None:
+                    self._stats["read_rows_sum"] += rows_read(live_rows, step.read_block)
+                self._emit(req, toks_np[slot, :cnt], t_fetch)
                 if k_spec > 0:
-                    self._stats["spec_drafted"] += group_drafted
-                    self._stats["spec_accepted"] += group_accepted
-                    # Per-tick verify-time attribution: the whole speculative
-                    # dispatch IS the k+1-position verification forward.
-                    self._stats["spec_verify_s"] += time.perf_counter() - t0
-                if tr is not None and live:
-                    tr.decode_tick(self._stats["ticks"], t0,
-                                   time.perf_counter(),
-                                   weights_version=version, occupancy=live,
-                                   n_slots=self.n_slots,
-                                   request_ids=group_rids,
-                                   drafted=group_drafted,
-                                   accepted=group_accepted)
-                if ride is None:
-                    self._watch_decode_recompiles()
-                elif ride.is_final:
-                    self._first_token(ride.req, int(first[0]), bool(first[1]))
+                    req.spec_drafted += k_spec
+                    req.spec_accepted += max(cnt - 1, 0)
+                    group_drafted += k_spec
+                    group_accepted += max(cnt - 1, 0)
+                    self._stats["spec_decode_tokens"] += cnt
+                if bool(done_np[slot]):
+                    del self._decoding[slot]
+                    self._retire(req)
+            if k_spec > 0:
+                self._stats["spec_drafted"] += group_drafted
+                self._stats["spec_accepted"] += group_accepted
+                # Verify-time attribution: the whole speculative dispatch
+                # IS the k+1-position verification forward.
+                self._stats["spec_verify_s"] += time.perf_counter() - step.t0
+            if tr is not None and step.rows:
+                tr.decode_tick(step.tick, step.t0, time.perf_counter(),
+                               weights_version=step.version,
+                               occupancy=len(step.rows), n_slots=self.n_slots,
+                               request_ids=[r.id for r in step.rows.values()],
+                               drafted=group_drafted, accepted=group_accepted)
+            ride = step.ride
+            if ride is not None and ride.is_final and \
+                    self._decoding.get(ride.req.slot) is ride.req:
+                self._first_token(ride.req, int(first[0]), bool(first[1]))
 
     def _watch_decode_recompiles(self) -> None:
         size = _cache_size(self._decode)
@@ -2044,6 +2143,7 @@ class ServingEngine:
         The decode executable census is untouched — recovery is pure host
         bookkeeping feeding the existing admission path. Returns a summary
         dict (recovered counts + journal scan stats)."""
+        self._settle()
         if self._journal is None and journal_dir is not None:
             from .journal import RequestJournal
 
@@ -2342,6 +2442,7 @@ class ServingEngine:
         until they drain); every admission from now on binds the new one.
         Zero downtime, zero decode recompiles (params are a non-donated
         argument of the ONE decode executable)."""
+        self._settle()
         v = self._check_new_version(weights_version)
         self._validate_params_tree(params)
         self._install_params(params, v)
@@ -2361,6 +2462,7 @@ class ServingEngine:
         untouched; per-cohort SLO samples accumulate until
         :meth:`promote_canary` or :meth:`rollback_canary` ends the window
         (publish.py's ``WeightPublisher`` drives that decision)."""
+        self._settle()
         if not 0.0 < float(fraction) <= 1.0:
             raise ValueError(f"canary fraction must be in (0, 1], got {fraction}")
         v = self._check_new_version(weights_version)
@@ -2381,6 +2483,7 @@ class ServingEngine:
         """End the canary window by making the candidate PRIMARY. In-flight
         old-version requests drain on the old buffers (then they are GC'd);
         all new admissions bind the promoted version."""
+        self._settle()
         c = self._require_canary()
         self._canary = None
         self._weights_version = c["version"]
@@ -2400,6 +2503,7 @@ class ServingEngine:
         bind the (never unbound) primary again — bit-equal to never having
         published. Candidate-bound in-flight requests finish on the
         candidate buffers, which are GC'd once they drain."""
+        self._settle()
         c = self._require_canary()
         self._canary = None
         self._fstats["rolled_back"] += 1
@@ -2562,6 +2666,7 @@ class ServingEngine:
             if fused is not None and per_tick > 1:
                 self._decode_chunk = None
                 self.run([prompt], max_new_tokens=2)
+            self._settle()
         finally:
             self._journal = jr
             self._decode_chunk, self._chunks_per_tick = fused, per_tick
@@ -2656,6 +2761,8 @@ class ServingEngine:
             "tpot_mean_s": float(tpot.mean()) if tpot.size else None,
             "ticks": s["ticks"],
             "decode_steps": s["decode_steps"],
+            # of them, those dispatched while the step before was unsettled
+            "steps_overlapped": s["steps_overlapped"],
             "prefill_chunks": s["prefill_chunks"],
             # of them, those that rode a decode step (one program a tick)
             "prefill_chunks_fused": s["prefill_chunks_fused"],

@@ -296,15 +296,18 @@ def test_a_decode_step_reads_the_cache_through_the_kernel(cell_programs, cell_na
 # on the commit before ``_forward_cached`` gained its loop over passes. With one pass
 # the traced program is that one. A PR that means to change one of these programs
 # replaces its line, and says which; one that does not has touched their path.
-# The two decode lines are PR 35's (attention through ``kv_cache.cache_attend``); the
-# two prefill lines are still that day's: a prompt chunk never reaches the kernel.
+# The two decode lines were taken anew when the step came to return its ``done`` flags
+# as an output of their own (the state is donated to the next step before the engine
+# fetches this one's outputs): the text differs from the one before by that output
+# alone. The two prefill lines are still that day's: a prompt chunk never reaches the
+# kernel.
 LOWERED_BEFORE_PASSES = {
     ("mistral_serve_steady", "decode"):
-        "0b59a7366456bad7d9e258f8aa799a614251623bf2f93add9f66796667c29a64",
+        "6240b0c0a91d7be1af5b764737f92303b053ea85d07090fce3d901168a7e126b",
     ("mistral_serve_steady", "prefill"):
         "f48f7bc1f733a4f268b3a7c1d4db0ce99a7444efa761acc805fc5560f209de64",
     ("mixtral_serve_decode", "decode"):
-        "d7dfd881202f36f25c173352c45756da81d386d1a9a9e7d34a4a767fbcea7759",
+        "bcd923d87af89ee55f91e63afa8411546cb647135fb6ce7920c211e3ceeb9d46",
     ("mixtral_serve_decode", "prefill"):
         "33421fcc02a5ec145869358ad6519b4bbae66b3eab354af47b1d3f58f488f859",
 }

@@ -53,7 +53,8 @@ SERVING_STATS_KEYS = {
     "ttft_p50_s", "ttft_p95_s", "ttft_queue_wait_mean_s",
     "ttft_prefill_mean_s", "tpot_mean_s",
     "ttft_terms", "token_gap", "tick_phases",
-    "ticks", "decode_steps", "prefill_chunks", "prefill_chunks_fused", "prefill_pad_tokens",
+    "ticks", "decode_steps", "steps_overlapped", "prefill_chunks", "prefill_chunks_fused",
+    "prefill_pad_tokens",
     "prefill_ladder", "n_slots", "mean_occupancy", "peak_occupancy",
     "cache", "passes", "mean_queue_depth", "slot_allocs", "slot_reuses", "steady_recompiles",
     "prefill_steady_recompiles", "decode_executables", "prefill_executables",

@@ -137,8 +137,9 @@ def test_chunked_prefill_matches_oneshot_prefill(llama):
         model, ServingConfig(n_slots=2, max_len=32, prefill_chunks=[4, 8])
     )
     engine.submit(prompt, max_new_tokens=1)
-    # Drive prefill only: tick until the request's first token exists.
-    while engine._prefilling or engine._queue:
+    # Drive prefill only: with a budget of one token the request is done with
+    # its first, which is settled a tick after the final chunk's dispatch.
+    while engine.pending:
         engine.tick()
     slot_cache = engine._cache
     slot = 0  # first alloc takes slot 0
