@@ -73,8 +73,8 @@ from .logging import get_logger
 from .planner import (BandwidthTable, PlannerError, kv_bytes_per_token,
                       plan_disagg_slices)
 from .resharding import ReshardExecutor
-from .serving import (TICK, ServingEngine, SlotState, _cache_size,
-                      _release_step, init_slot_state, plan_chunks)
+from .serving import (TICK, ServingEngine, SlotState, _cache_size, _release_step,
+                      _template_of, init_slot_state, plan_chunks)
 
 logger = get_logger(__name__)
 
@@ -150,6 +150,8 @@ class DisaggServingEngine(ServingEngine):
     split. ``stats()`` gains a ``"disagg"`` block: the slice plan, handoff
     bytes/latency, and measured FLOP ratio for re-planning.
     """
+
+    _fuses_qkv = False  # the decode mesh and every lane hold the model's layout
 
     def __init__(self, model, config=None, *, disagg=None, devices=None,
                  forward_cached=None, compile_manager=None, telemetry=None,
@@ -227,6 +229,7 @@ class DisaggServingEngine(ServingEngine):
             self._state, SlotState(*([vec_s] * len(SlotState._fields))))
         self._params_decode = jax.device_put(model.params, self._decode_sharding)
         self._params = self._params_decode  # what the decode hook dispatches
+        self._params_template = _template_of(self._params_decode)
         # Version 0's buffers are the decode-mesh copy, not the model's own
         # placement — keep the publication double-buffer consistent with
         # what the dispatch hooks actually feed the programs.
@@ -864,6 +867,7 @@ class DisaggServingEngine(ServingEngine):
         self._params_by_version = new_params_by_version
         self._params = new_params_by_version[self._weights_version]
         self._params_decode = self._params
+        self._params_template = _template_of(self._params)
         self._lane_params = new_lane_params
         self._lanes = new_lanes
         self._free_lanes = deque(new_lanes)

@@ -133,10 +133,14 @@ def _embed_tokens(cfg, embed, ids):
     return x
 
 
-def _qkv_proj(attn, hn, cos, sin, rotary_dim=None):
+def _qkv_proj(attn, hn, cos, sin, rotary_dim=None, heads=None):
     """q/k (roped) + v projections for one Llama-family layer; carries
     Qwen2-style attention biases when present. ``rotary_dim`` < head_dim
-    rotates only the leading dims (StableLM-style partial rotary)."""
+    rotates only the leading dims (StableLM-style partial rotary); ``cos``
+    None rotates nothing (OPT). A layer in the serving layout
+    (:func:`fuse_qkv_params`) holds one ``qkv_proj`` kernel
+    ``(H, (Hq + 2·Hkv)·D)``: one dot, whose output is cut into q, k and v;
+    ``heads`` = ``(Hq, D)`` says where, as the fused width alone does not."""
     def proj(name):
         y = _proj(hn, attn[name]["kernel"])
         if "bias" in attn[name]:
@@ -144,10 +148,113 @@ def _qkv_proj(attn, hn, cos, sin, rotary_dim=None):
         return y
 
     def rope(y):
+        if cos is None:
+            return y
         rd = y.shape[-1] if rotary_dim is None else rotary_dim
         return apply_partial_rope(y, cos, sin, rd)
 
+    if "qkv_proj" in attn:
+        if heads is None:
+            raise ValueError("_qkv_proj: a fused qkv_proj kernel needs heads=(Hq, D) "
+                             "to be cut into q, k and v")
+        hq, d = heads
+        y = _dense(attn["qkv_proj"], hn)
+        hkv = (y.shape[-1] // d - hq) // 2
+        q, k, v = (a.reshape(*y.shape[:-1], -1, d)
+                   for a in jnp.split(y, [hq * d, (hq + hkv) * d], axis=-1))
+        return rope(q), rope(k), v
     return rope(proj("q_proj")), rope(proj("k_proj")), proj("v_proj")
+
+
+# ---------------------------------------------------------------------------
+# The serving layout of the attention input projections
+# ---------------------------------------------------------------------------
+
+_QKV = ("q_proj", "k_proj", "v_proj")
+
+
+def _fusable(attn) -> bool:
+    """An attention dict whose q, k and v are plain stacked ``(L, H, n, D)``
+    kernels of one dtype and leading shape, with a bias on all three or on
+    none. ``DecodeQuant`` kernels are not: they keep the three leaves."""
+    if not isinstance(attn, dict) or not all(isinstance(attn.get(n), dict) for n in _QKV):
+        return False
+    ps = [attn[n] for n in _QKV]
+    ks = [p.get("kernel") for p in ps]
+    if any(isinstance(k, DecodeQuant) or getattr(k, "ndim", None) != 4
+           or set(p) - {"kernel", "bias"} for k, p in zip(ks, ps)):
+        return False
+    return (len({(k.shape[:2], k.dtype) for k in ks}) == 1
+            and sum("bias" in p for p in ps) in (0, 3))
+
+
+def _fuse_qkv(qkv):
+    """q, k and v of one attention as ``{"kernel": (L, H, (Hq + 2·Hkv)·D),
+    "bias": (L, (Hq + 2·Hkv)·D)}``: each reshaped, then concatenated along
+    the output axis."""
+    ps = [qkv[n] for n in _QKV]
+    fused = {"kernel": jnp.concatenate(
+        [p["kernel"].reshape(*p["kernel"].shape[:2], -1) for p in ps], axis=-1)}
+    if "bias" in ps[0]:
+        fused["bias"] = jnp.concatenate(
+            [p["bias"].reshape(p["bias"].shape[0], -1) for p in ps], axis=-1)
+    return fused
+
+
+def _qkv_layout(params, fuse):
+    """``params`` with ``fuse(q, k and v)`` in place of each fusable
+    attention's three leaves (``fuse`` returns None: the site keeps them),
+    and whether any site was fused. Every other leaf is the same array, and
+    a subtree with nothing fused is the same object."""
+    if _fusable(params):
+        qkv = fuse({n: params[n] for n in _QKV})
+        if qkv is None:
+            return params, False
+        return {**{n: p for n, p in params.items() if n not in _QKV}, "qkv_proj": qkv}, True
+    if not isinstance(params, dict):
+        return params, False
+    out = {key: _qkv_layout(sub, fuse) for key, sub in params.items()}
+    if not any(fused for _, fused in out.values()):
+        return params, False
+    return {key: tree for key, (tree, _) in out.items()}, True
+
+
+def _fuse_in_place(qkv):
+    """One site's fused leaves on its kernels' one placement, where they are
+    whole on each device; None (the site keeps three leaves) for host
+    arrays, kernels placed apart, or kernels split over devices."""
+    shardings = {getattr(qkv[n]["kernel"], "sharding", None) for n in _QKV}
+    if len(shardings) != 1:
+        return None
+    (s,) = shardings
+    if s is None or not s.is_fully_replicated:
+        return None
+    if isinstance(s, jax.sharding.NamedSharding):  # its spec names the old rank
+        s = jax.sharding.NamedSharding(s.mesh, jax.sharding.PartitionSpec(),
+                                       memory_kind=s.memory_kind)
+    return jax.jit(_fuse_qkv, out_shardings=s)(qkv)
+
+
+def fuse_qkv_params(params):
+    """The layout ``ServingEngine`` installs for a plan in
+    :data:`FUSED_QKV_PLANS`: every Llama-family (or OPT) attention's
+    ``q_proj``, ``k_proj`` and ``v_proj`` kernels, stacked ``(L, H, n, D)``,
+    become one ``qkv_proj`` kernel ``(L, H, (Hq + 2·Hkv)·D)`` (biases
+    alike), and the three leaves leave the tree. Returns the tree and
+    whether any attention was fused.
+
+    Why: ``_proj``'s ``(H, n, D)`` contraction makes XLA copy each layer's
+    slice out of the stack and lay it out by head before the dot (or copy
+    the whole stacks, once a step, in a looped model); a 2-D kernel's slice
+    fuses into its dot, which reads it where the stack holds it, as the
+    MLP's kernels are read. The mathematics is the same, column for column.
+
+    One jitted call a site over its q, k and v leaves alone (every other
+    leaf is the same array), so a version costs one copy of those at install
+    and nothing in a step; the fused leaves keep the version's sharding.
+    Idempotent; ``DecodeQuant`` kernels, host arrays and kernels split over
+    devices keep the three leaves (:func:`_qkv_proj` reads either layout)."""
+    return _qkv_layout(params, _fuse_in_place)
 
 
 @jax.named_scope("attn")
@@ -353,13 +460,14 @@ def _llama_decoder(cfg, params, input_ids, pos_ids):
     rd = getattr(cfg, "rotary_dim", None) or cfg.head_dim
     cos, sin = rotary_embedding(pos_ids, rd, cfg.rope_theta, x.dtype)
 
+    heads = (cfg.num_attention_heads, cfg.head_dim)
     attn_mult = getattr(cfg, "attention_multiplier", None)
     res_mult = getattr(cfg, "residual_multiplier", 1.0)
 
     def block(p, h, attend):
         attn = p["self_attn"]
         hn = _chassis_norm(cfg, p["input_layernorm"], h)
-        q, k_new, v_new = _qkv_proj(attn, hn, cos, sin, rotary_dim=rd)
+        q, k_new, v_new = _qkv_proj(attn, hn, cos, sin, rotary_dim=rd, heads=heads)
         if attn_mult is not None:  # same q-folding trick as LlamaAttention
             q = q * jnp.asarray(attn_mult * np.sqrt(cfg.head_dim), q.dtype)
         out = _out_proj(attend(q, k_new, v_new), attn["o_proj"]["kernel"])
@@ -481,9 +589,8 @@ def _opt_decoder(cfg, params, input_ids, pos_ids):
     def block(p, h, attend):
         attn = p["self_attn"]
         hn = _layer_norm(h, p["self_attn_layer_norm"], cfg.layer_norm_eps)
-        q = _proj(hn, attn["q_proj"]["kernel"]) + attn["q_proj"]["bias"].astype(hn.dtype)
-        k_new = _proj(hn, attn["k_proj"]["kernel"]) + attn["k_proj"]["bias"].astype(hn.dtype)
-        v_new = _proj(hn, attn["v_proj"]["kernel"]) + attn["v_proj"]["bias"].astype(hn.dtype)
+        q, k_new, v_new = _qkv_proj(attn, hn, None, None,
+                                    heads=(cfg.num_attention_heads, cfg.head_dim))
         out = attend(q, k_new, v_new)
         h = h + _out_proj(out, attn["out_proj"]["kernel"]) + attn["out_proj"]["bias"].astype(h.dtype)
         hn = _layer_norm(h, p["final_layer_norm"], cfg.layer_norm_eps)
@@ -763,6 +870,14 @@ GENERATION_PLANS: dict[str, Callable] = {
 
 def register_generation_plan(module_class_name: str, fn: Callable) -> None:
     GENERATION_PLANS[module_class_name] = fn
+
+
+# The built-in plans whose decoders read q, k and v through ``_qkv_proj``,
+# which takes the serving layout (:func:`fuse_qkv_params`) as well as the
+# model's: ``ServingEngine`` installs that layout for these alone. A plan
+# registered later, or a caller's own ``forward_cached``, is handed the
+# model's layout.
+FUSED_QKV_PLANS = (_llama_forward_cached, GENERATION_PLANS["OPTForCausalLM"])
 
 
 @dataclasses.dataclass

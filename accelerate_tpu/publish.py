@@ -460,7 +460,7 @@ class WeightPublisher:
         loaded = load_sharded_safetensors(
             ckpt_dir, weights_name=self.config.weights_name)
         flat, treedef = jax.tree_util.tree_flatten_with_path(
-            self.engine._params)
+            self.engine._params_template)
         names = [_path_to_name(p) for p, _ in flat]
         missing = [n for n in names if n not in loaded]
         if missing:
@@ -494,7 +494,7 @@ class WeightPublisher:
         import jax
         from jax.sharding import Mesh, NamedSharding
 
-        dst = jax.tree.map(lambda leaf: leaf.sharding, self.engine._params)
+        dst = jax.tree.map(lambda leaf: leaf.sharding, self.engine._params_template)
         mesh = None
         for s in jax.tree_util.tree_leaves(
                 dst, is_leaf=lambda x: hasattr(x, "device_set")):
@@ -502,7 +502,7 @@ class WeightPublisher:
                 mesh = s.mesh
                 break
         if mesh is None:
-            leaves = jax.tree_util.tree_leaves(self.engine._params)
+            leaves = jax.tree_util.tree_leaves(self.engine._params_template)
             dev = next(iter(leaves[0].sharding.device_set))
             mesh = Mesh(np.asarray([dev]), ("publish",))
         return dst, mesh

@@ -99,9 +99,11 @@ import numpy as np
 from .chaos import InjectedFaultError
 from .generation import (
     ENCDEC_GENERATION_PLANS,
+    FUSED_QKV_PLANS,
     GENERATION_PLANS,
     PromptChunk,
     _filter_logits,
+    fuse_qkv_params,
     sample_logits,
 )
 from .kv_cache import KVCache, cache_spec, decode_reads, init_slot_cache, kv_bytes_per_token
@@ -221,6 +223,14 @@ def _commit_params(params):
         return leaf
 
     return jax.tree.map(commit, params)
+
+
+def _template_of(params):
+    """Each leaf's shape, dtype and sharding: what a published tree is held
+    to (``ServingEngine._validate_params_tree``) and placed onto."""
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding)
+        if isinstance(a, jax.Array) else a, params)
 
 
 def _single_device_sharding_of(params):
@@ -834,6 +844,11 @@ class ServingEngine:
     policy, and ``telemetry`` to stream per-request TTFT/TPOT events and the
     serving summary into the PR-1 recorder.
 
+    Where the plan is a built-in one that reads it
+    (``generation.FUSED_QKV_PLANS``), each weights version is installed with
+    the attention input projections fused (``generation.fuse_qkv_params``);
+    a caller's own ``forward_cached`` is handed the model's layout.
+
     Robustness knobs: ``fault_tolerance`` (a
     :class:`~accelerate_tpu.fault_tolerance.FaultToleranceManager`) arms the
     preemption drain; ``chaos`` (a
@@ -842,13 +857,16 @@ class ServingEngine:
     None`` check per site.
     """
 
+    # Whether a built-in plan's versions are installed with q, k and v fused
+    # (the disagg router keeps the model's layout on its decode mesh and lanes)
+    _fuses_qkv = True
+
     def __init__(self, model, config=None, *, forward_cached: Optional[Callable] = None,
                  compile_manager=None, telemetry=None, fault_tolerance=None,
                  chaos=None, tracing=None, journal=None, profiler=None):
         from .utils.dataclasses import ServingConfig
 
         self.config = config if config is not None else ServingConfig()
-        self.model = model
         self.telemetry = telemetry
         self.fault_tolerance = fault_tolerance
         # Request-scoped tracing (tracing.py). Defaults to the telemetry
@@ -951,10 +969,20 @@ class ServingEngine:
         # Published versions (device_put through the reshard executor) also
         # always arrive committed — an uncommitted initial tree would cost
         # one spurious decode recompile at the first hot swap.
-        # The param tree the dispatch hooks feed the jitted programs. The
-        # disaggregated router (disagg.py) repoints this at the decode-mesh
-        # copy; the colocated engine uses the model's own placement.
-        self._params = _commit_params(model.params)
+        # The param tree the dispatch hooks feed the jitted programs, with
+        # q, k and v fused where the plan reads that layout (one kernel a
+        # stack: no step copies or lays out a projection weight). The
+        # engine keeps no reference to the model's own tree, so a caller
+        # that lets it go holds q, k and v once. The disaggregated router
+        # (disagg.py) repoints this at the decode-mesh copy; the colocated
+        # engine uses the model's own placement. A published version
+        # arrives in the model's layout and is held to _params_template.
+        params = _commit_params(model.params)
+        self._params_template = _template_of(params)
+        fuse = self._fuses_qkv and any(fwd is plan for plan in FUSED_QKV_PLANS)
+        self._params, fused = fuse_qkv_params(params) if fuse else (params, False)
+        # Static facts of the installed layout (stats()["layout"]).
+        self._layout = {"qkv_fused": fused}
         # Cache and slot state are built in the params' own sharding form.
         # Params prepared by an Accelerator carry a NamedSharding over its
         # mesh even on one chip; next to them a default-placed cache comes
@@ -2351,10 +2379,14 @@ class ServingEngine:
         return self._weights_version
 
     def _install_params(self, params, version: int) -> None:
-        """Placement hook: bind ``params`` (already validated) as ``version``.
-        The disagg router overrides this to place the decode-mesh copy and
-        the per-lane prefill copies."""
-        self._params_by_version[int(version)] = _commit_params(params)
+        """Placement hook: bind ``params`` (already validated) as ``version``,
+        in the installed layout: one jitted call per version, never inside a
+        step. The disagg router overrides this to place the decode-mesh copy
+        and the per-lane prefill copies."""
+        params = _commit_params(params)
+        if self._layout["qkv_fused"]:
+            params, _ = fuse_qkv_params(params)
+        self._params_by_version[int(version)] = params
 
     def _drop_params(self, version: int) -> None:
         """Placement hook: release a retired version's buffers."""
@@ -2376,15 +2408,15 @@ class ServingEngine:
             self._drop_params(v)
 
     def _validate_params_tree(self, params) -> None:
-        """The guarded swap seam: the incoming tree must match the serving
-        tree leaf-for-leaf in structure, shape, dtype, AND sharding, and
-        every leaf must already be a committed device array — anything else
-        would silently recompile the decode step (new avals/shardings) or
-        crash mid-tick, so it is rejected here with the offending leaf
-        named."""
+        """The guarded swap seam: the incoming tree, in the model's layout,
+        must match the serving template (``_params_template``) leaf-for-leaf
+        in structure, shape, dtype, AND sharding, and every leaf must already
+        be a committed device array — anything else would silently recompile
+        the decode step (new avals/shardings) or crash mid-tick, so it is
+        rejected here with the offending leaf named."""
         from .parallel.sharding import _path_to_name
 
-        cur = self._params
+        cur = self._params_template
         ref = jax.tree_util.tree_structure(cur)
         got = jax.tree_util.tree_structure(params)
         if ref != got:
@@ -2793,6 +2825,8 @@ class ServingEngine:
                 ),
             },
             "passes": cache_spec(self.cfg).passes,
+            # The installed weights' layout: q, k and v as one kernel a stack
+            "layout": dict(self._layout),
             "mean_queue_depth": (
                 round(s["queue_depth_sum"] / s["queue_samples"], 3)
                 if s["queue_samples"] else None

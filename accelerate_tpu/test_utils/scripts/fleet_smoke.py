@@ -120,7 +120,7 @@ def _run_round(model, root, chaos_schedule=None):
     # -- leg 4 after the loss: replace the capacity, publish fleet-wide ----
     surviving = [n for n, s in router.cell_states().items() if s == "healthy"]
     router.scale_up("c2", engine=_mk_cell(model, root, 2))
-    params = router._cells[surviving[0]].engine._params
+    params = model.params
     router.publish(params, weights_version=PUBLISH_VERSION)
     filler = np.random.default_rng(13)
     decided = False

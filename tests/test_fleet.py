@@ -416,7 +416,7 @@ def test_publish_canaries_one_cell_then_promotes_fleetwide(llama, tmp_path):
     # Baseline traffic on the non-canary cell.
     _pump(router, cfg, c1, 2, cid_prefix="b")
     _drain_fleet(router)
-    params = router._cells["c0"].engine._params
+    params = model.params
     out = router.publish(params, weights_version=7)
     assert out == {"version": 7, "canary_cell": "c0"}
     with pytest.raises(ValueError, match="already in flight"):
@@ -445,7 +445,7 @@ def test_publish_rollback_quarantines_the_version(llama, tmp_path):
     # Healthy baseline on c1.
     _pump(router, cfg, c1, 3, cid_prefix="b")
     _drain_fleet(router)
-    params = router._cells["c0"].engine._params
+    params = model.params
     router.publish(params, weights_version=9)
     # A candidate that blows the SLO: the canary cohort's terminal events
     # are all timeouts (seeded into the engine's real cohort store — the

@@ -11,10 +11,11 @@ And that a decode step's attention is the kernel of ``ops/decode_attention.py``
 over the whole cache stack: the Mosaic call is in every cell's decode program,
 no layer's K or V slice and no array of logits over ``T_max`` is; the prefill
 programs hold no such call and have not changed by a byte.
-The four programs of the two cells that were there before the layer loop
-learned to run a stack more than once are held to their lowered text of that
-day, and the looped cell's programs to one cache, one layer body and no copy
-of the cache.
+The four programs of the two one-pass cells are held to their lowered text,
+and the looped cell's programs to one cache, one layer body and no copy
+of the cache. Every program is compiled over the weights in the layout the engine
+installs, q, k and v as one kernel a stack, and none copies or stages a projection
+weight before its dot.
 
 The topology is described in a fixture (never while a module is imported);
 ``tests/chipbench/test_chipbench_aot.py`` is the other file that does so."""
@@ -80,15 +81,17 @@ _KERNEL_BODY = re.compile(r'(\\22body\\22: \\22)[A-Za-z0-9+/=]+')
 @pytest.fixture(scope="module")
 def cell_programs():
     """``get(cell_name) -> (cell, {"decode": compiled, "prefill": compiled})``, each
-    cell compiled once for a described v5e chip; ``get.lowered[cell_name]`` holds
-    the sha256 of each program's lowered StableHLO text, taken on the way."""
+    cell's programs as its engine runs them (:func:`_engine_program`), compiled once
+    for a described v5e chip; ``get.lowered[cell_name]`` holds the sha256 of each
+    program's lowered StableHLO text, and ``get.fused(cell_name)`` the decode step
+    a chunk rides."""
     import os
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
-    from chipbench import aot, spec
+    from chipbench import spec
 
     try:
         topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
@@ -98,32 +101,22 @@ def cell_programs():
     before = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    compiled, texts = {}, []
-    compile_lowered = jax.stages.Lowered.compile
-
-    def noting_the_text(lowered, *args, **kwargs):
-        texts.append(lowered.as_text())
-        return compile_lowered(lowered, *args, **kwargs)
+    compiled, fused = {}, {}
 
     def get(name):
         if name not in compiled:
             cell = spec.load_cell(name)
-            del texts[:]
-            jax.stages.Lowered.compile = noting_the_text
-            try:
-                compiled[name] = cell, aot.serving_programs(cell, topo.devices[0])
-            finally:
-                jax.stages.Lowered.compile = compile_lowered
+            lowered = {program: _engine_program(cell, topo.devices[0], program)
+                       for program in ("decode", "prefill")}
             get.lowered[name] = {program: hashlib.sha256(
-                _KERNEL_BODY.sub(r"\1", text).encode()).hexdigest()
-                for program, text in zip(compiled[name][1], texts)}
+                _KERNEL_BODY.sub(r"\1", low.as_text()).encode()).hexdigest()
+                for program, low in lowered.items()}
+            compiled[name] = cell, {program: low.compile() for program, low in lowered.items()}
         return compiled[name]
-
-    fused = {}
 
     def get_fused(name):
         if name not in fused:
-            fused[name] = _decode_chunk_program(get(name)[0], topo.devices[0])
+            fused[name] = _engine_program(get(name)[0], topo.devices[0], "decode_chunk").compile()
         return fused[name]
 
     get.lowered = {}
@@ -133,14 +126,16 @@ def cell_programs():
     compilation_cache.reset_cache()
 
 
-def _decode_chunk_program(cell, device):
-    """The cell's decode step with its largest prefill rung riding it
-    (``serving._build_decode_chunk_step``), compiled for ``device`` from shapes
-    alone, as ``chipbench.aot.serving_programs`` compiles the other two."""
+def _engine_program(cell, device, program):
+    """One of the cell's engine programs, lowered for ``device`` from shapes alone:
+    ``decode`` over every slot, ``prefill`` at the largest rung, or ``decode_chunk``,
+    the decode step that rung rides. ``chipbench.aot.serving_programs`` lowers the
+    first two over the weights in the model's layout; here they are in the layout
+    ``ServingEngine`` installs (``generation.fuse_qkv_params``, here over shapes)."""
     from jax.sharding import SingleDeviceSharding
 
     from accelerate_tpu import serving
-    from accelerate_tpu.generation import GENERATION_PLANS, init_slot_cache
+    from accelerate_tpu.generation import GENERATION_PLANS, _fuse_qkv, _qkv_layout, init_slot_cache
     from chipbench import weights
 
     place = SingleDeviceSharding(device)
@@ -148,39 +143,55 @@ def _decode_chunk_program(cell, device):
     def shape(s, dtype):
         return jax.ShapeDtypeStruct(tuple(s), dtype, sharding=place)
 
-    def abstract(fn):
-        return jax.tree.map(lambda x: shape(x.shape, x.dtype), jax.eval_shape(fn))
+    def abstract(fn, *args):
+        return jax.tree.map(lambda x: shape(x.shape, x.dtype), jax.eval_shape(fn, *args))
 
     eng = cell.workload["engine"]
     n_slots, max_len = int(eng["n_slots"]), int(eng["max_len"])
     module = cell.family.program_module(cell.config, max_len)
-    params = weights.nest({k: shape(s, jnp.bfloat16)
-                           for k, (s, _) in cell.family.weight_specs(cell.config).items()})
-    step = serving._build_decode_chunk_step(GENERATION_PLANS[type(module).__name__],
-                                            module.config, 0.0, None, None, None)
+    params = abstract(lambda tree: _qkv_layout(tree, _fuse_qkv)[0], weights.nest(
+        {k: shape(s, jnp.bfloat16) for k, (s, _) in cell.family.weight_specs(cell.config).items()}))
+    fwd, cfg = GENERATION_PLANS[type(module).__name__], module.config
+    sampling = (0.0, None, None, None)   # greedy, no EOS: as drivers/serve.py builds the engine
+    cache = abstract(lambda: init_slot_cache(cfg, n_slots, max_len, dtype=jnp.bfloat16))
+    state = abstract(lambda: serving.init_slot_state(n_slots, seed=0, history=16))
+    live = shape((n_slots,), jnp.bool_)
+    chunk = shape((1, max(serving.default_prefill_ladder(max_len))), jnp.int32)
     scalar, flag = shape((), jnp.int32), shape((), jnp.bool_)
-    return step.lower(
-        params, abstract(lambda: init_slot_cache(module.config, n_slots, max_len, jnp.bfloat16)),
-        abstract(lambda: serving.init_slot_state(n_slots, seed=0, history=16)),
-        shape((n_slots,), jnp.bool_), shape((1, max(serving.default_prefill_ladder(max_len))),
-                                            jnp.int32),
-        scalar, scalar, scalar, abstract(lambda: jax.random.key(0)), flag, flag).compile()
+    key = abstract(lambda: jax.random.key(0))
+    if program == "decode":
+        return serving._build_decode_step(fwd, cfg, *sampling, speculate_k=0).lower(
+            params, cache, state, live)
+    if program == "prefill":
+        return serving._build_prefill_step(fwd, cfg, *sampling).lower(
+            params, cache, state, chunk, scalar, scalar, scalar, key, flag, flag)
+    return serving._build_decode_chunk_step(fwd, cfg, *sampling).lower(
+        params, cache, state, live, chunk, scalar, scalar, scalar, key, flag, flag)
 
 
-_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%(\S+) = \w+\[([0-9,]*)\]\S* ([\w\-]+)\(")
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%(\S+) = \w+\[([0-9,]*)\](\S*) ([\w\-]+)\(")
+
+
+def _instructions(hlo_text):
+    """(dims, opcode, name, layout, inside a fusion) of every array-valued
+    instruction; the layout (``{...}``) holds the tiling and the memory space."""
+    inside_fusion = False
+    for line in hlo_text.splitlines():
+        if line.startswith(("%", "ENTRY")):
+            inside_fusion = line.startswith("%fused_computation")
+        m = _INSTRUCTION.match(line)
+        if m:
+            dims = tuple(int(n) for n in m.group(2).split(",") if n)
+            yield dims, m.group(4), m.group(1), m.group(3), inside_fusion
 
 
 def _arrays(hlo_text, scheduled_only=False):
     """(elements, opcode, name) of every array-valued instruction; with
     ``scheduled_only`` those a fusion holds inside itself are left out, since
     they never reach memory as a buffer of their own."""
-    inside_fusion = False
-    for line in hlo_text.splitlines():
-        if line.startswith(("%", "ENTRY")):
-            inside_fusion = line.startswith("%fused_computation")
-        m = _INSTRUCTION.match(line)
-        if m and not (scheduled_only and inside_fusion):
-            yield math.prod(int(n) for n in m.group(2).split(",") if n), m.group(3), m.group(1)
+    for dims, op, name, _, inside_fusion in _instructions(hlo_text):
+        if not (scheduled_only and inside_fusion):
+            yield math.prod(dims), op, name
 
 
 @pytest.mark.parametrize("case", ["decode_no_repeated_array", "prefill_no_repeated_array",
@@ -292,24 +303,22 @@ def test_a_decode_step_reads_the_cache_through_the_kernel(cell_programs, cell_na
 
 # -- a stack run more than once: the programs that were there stay, to the byte ------
 
-# sha256 of the lowered StableHLO text (no locations in it) of the four programs, taken
-# on the commit before ``_forward_cached`` gained its loop over passes. With one pass
-# the traced program is that one. A PR that means to change one of these programs
-# replaces its line, and says which; one that does not has touched their path.
-# The two decode lines were taken anew when the step came to return its ``done`` flags
-# as an output of their own (the state is donated to the next step before the engine
-# fetches this one's outputs): the text differs from the one before by that output
-# alone. The two prefill lines are still that day's: a prompt chunk never reaches the
-# kernel.
+# sha256 of the lowered StableHLO text (no locations in it) of the four programs. With
+# one pass the traced program is the one the layer loop traced before it learned to run
+# a stack more than once. A PR that means to change one of these programs replaces its
+# line, and says which; one that does not has touched their path. All four were taken
+# anew when the engine came to install q, k and v as one ``qkv_proj`` kernel a stack:
+# one dot a layer, its output cut in three, where three contractions over ``(H, n, D)``
+# kernels stood.
 LOWERED_BEFORE_PASSES = {
     ("mistral_serve_steady", "decode"):
-        "6240b0c0a91d7be1af5b764737f92303b053ea85d07090fce3d901168a7e126b",
+        "a104d746590c073766910130dddeecff0cbe058bf75a2ed1cf645a0e61e68a66",
     ("mistral_serve_steady", "prefill"):
-        "f48f7bc1f733a4f268b3a7c1d4db0ce99a7444efa761acc805fc5560f209de64",
+        "4941305375d73451fded0f7586ace3984758bccf7f1cefb53b53d0fffaa582da",
     ("mixtral_serve_decode", "decode"):
-        "bcd923d87af89ee55f91e63afa8411546cb647135fb6ce7920c211e3ceeb9d46",
+        "68682633519de7c9f43f5f15d258b7b10607e69322636fb1fc1fb42881ed1508",
     ("mixtral_serve_decode", "prefill"):
-        "33421fcc02a5ec145869358ad6519b4bbae66b3eab354af47b1d3f58f488f859",
+        "745b56ef0f8b8de5c8cab6ffe2a20a60c1e27b2ff15c05094b4cfab116e1181f",
 }
 
 
@@ -320,10 +329,10 @@ def test_the_one_pass_cells_lower_to_the_programs_they_were(cell_programs, cell_
 
 
 # The looped cell: 48 layers run 4 times over 192 cache planes. Beside its arguments
-# (5.34 GB of weights and the 6.44 GB slot cache) a decode step holds the q, k and v
-# projection stacks a second time, 0.40 GB each: XLA lays them out by head once a
-# step, outside both loops, where the one-pass programs copy a layer's slice out in
-# every layer (``_proj``'s spelling; PERF.md, section 5). Nothing else of that size.
+# (5.34 GB of weights and the 6.44 GB slot cache) a decode step holds less than a
+# megabyte. Over the q, k and v kernels in the model's layout it held the three
+# projection stacks a second time, 0.40 GB each (1.21 GB): XLA laid them out by head
+# once a step, outside both loops; the engine installs them as one 2-D kernel a stack.
 @pytest.mark.parametrize("case", ["holds_the_cache_once", "no_cache_sized_copy_or_put_back",
                                   "one_layer_body_not_four"])
 def test_the_looped_cell_s_decode_program(cell_programs, case):
@@ -340,8 +349,7 @@ def test_the_looped_cell_s_decode_program(cell_programs, case):
         assert planes == 192 and 2 * 2 * side == 6_442_450_944
         assert m["aliased"] >= 2 * 2 * side                       # donated, written in place
         assert 11.7e9 < m["arguments"] < 11.9e9                   # the weights once, the cache once
-        qkv = 3 * 2 * cfg["num_hidden_layers"] * cfg["hidden_size"] ** 2
-        assert m["temporaries"] < qkv + 2**24                     # 1.21 GB: those three, no more
+        assert m["temporaries"] < 2**26                           # 64 MB: no stack copied
         assert m["device_bytes"] < 13.1e9
     elif case == "no_cache_sized_copy_or_put_back":
         moved = [name for n, op, name in _arrays(decode.as_text(), scheduled_only=True)
@@ -419,3 +427,33 @@ def test_the_decode_step_a_chunk_rides_reads_the_experts_where_they_lie(cell_pro
     moved = [name for n, op, name in _arrays(text, scheduled_only=True)
              if n == experts and any(word in op or word in name for word in moving)]
     assert not moved
+
+
+# -- q, k and v read where the stack holds them ------------------------------------
+
+
+@pytest.mark.parametrize("program", ["decode", "decode_chunk"])
+@pytest.mark.parametrize("cell_name", ["mistral_serve_steady", "mixtral_serve_decode",
+                                       "ouro_serve_reason"])
+def test_the_projection_weights_are_read_where_they_lie(cell_programs, cell_name, program):
+    """Over the ``(H, n, D)`` kernels of the model's layout XLA staged each layer's q,
+    k and v slice into the chip's scratch memory (``S(1)``) and copied it by head
+    inside the dot (steady and decode cells), or copied the three whole stacks by
+    head once a step (the looped cell): neither is left over the one ``(L, H, (Hq +
+    2·Hkv)·D)`` kernel the engine installs. No copy, of a layer's kernel or of a
+    stack, and no staging of one, whatever its width."""
+    cell, programs = cell_programs(cell_name)
+    text = (programs["decode"] if program == "decode" else cell_programs.fused(cell_name)).as_text()
+    cfg = cell.config
+    h, d, layers = cfg["hidden_size"], cfg["head_dim"], cfg["num_hidden_layers"]
+    hq, hkv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
+    # a layer's kernel or the stack, by head or flat, axes of one dropped
+    kernels = {s for heads in (hq, hkv, hq + 2 * hkv) for s in ((h, heads, d), (h, heads * d))}
+    kernels |= {(layers,) + s for s in kernels}
+    found = [(op, name, layout, inside_fusion)
+             for dims, op, name, layout, inside_fusion in _instructions(text)
+             if tuple(n for n in dims if n != 1) in kernels]
+    copied = [name for op, name, _, _ in found if "copy" in op + name]
+    staged = [name for op, name, layout, inside_fusion in found
+              if not inside_fusion and "S(1)" in layout and op != "parameter"]
+    assert not copied and not staged

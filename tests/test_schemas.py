@@ -56,7 +56,7 @@ SERVING_STATS_KEYS = {
     "ticks", "decode_steps", "steps_overlapped", "prefill_chunks", "prefill_chunks_fused",
     "prefill_pad_tokens",
     "prefill_ladder", "n_slots", "mean_occupancy", "peak_occupancy",
-    "cache", "passes", "mean_queue_depth", "slot_allocs", "slot_reuses", "steady_recompiles",
+    "cache", "passes", "layout", "mean_queue_depth", "slot_allocs", "slot_reuses", "steady_recompiles",
     "prefill_steady_recompiles", "decode_executables", "prefill_executables",
     "decode_chunk_executables", "weights_version",
     "canary", "window", "faults", "journal", "sdc", "speculation",
@@ -215,6 +215,7 @@ def test_serving_stats_schema(llama):
     assert set(stats["speculation"]) == SPECULATION_KEYS
     assert stats["speculation"]["k"] == 0  # speculation is off by default
     assert stats["speculation"]["acceptance_rate"] is None
+    assert stats["layout"] == {"qkv_fused": True}
 
 
 def test_speculation_stats_and_hub_series(llama):
@@ -284,6 +285,7 @@ def test_disagg_stats_schema(llama):
     stats = engine.stats()
     assert set(stats) == SERVING_STATS_KEYS | {"disagg"}
     assert set(stats["disagg"]) == DISAGG_KEYS
+    assert stats["layout"] == {"qkv_fused": False}  # the decode mesh keeps the model's layout
 
 
 def test_autoscale_stats_schema(llama):
